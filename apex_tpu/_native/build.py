@@ -23,12 +23,14 @@ _OUT = os.path.join(os.path.dirname(__file__), "_apex_tpu_native.so")
 
 
 def _compile() -> str | None:
+    """The library built from ``_csrc/`` (rebuilt when the source is
+    newer), or None for the Python paths. A ``.so`` whose source is
+    missing is NOT accepted: it could not be told apart from a stale
+    build of other code."""
     try:
-        if os.path.exists(_OUT) and (not os.path.exists(_SRC)
-                                     or os.path.getmtime(_OUT)
-                                     >= os.path.getmtime(_SRC)):
+        if os.path.getmtime(_OUT) >= os.path.getmtime(_SRC):
             return _OUT
-    except OSError:
+    except OSError:  # no .so yet (build it) or no source (the build fails)
         pass
     try:
         subprocess.run(
@@ -36,7 +38,7 @@ def _compile() -> str | None:
              _SRC, "-o", _OUT],
             check=True, capture_output=True, timeout=120)
         return _OUT
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return None
 
 
@@ -80,3 +82,10 @@ def get_lib():
 
 def native_available() -> bool:
     return get_lib() is not None
+
+
+def native_status() -> str:
+    """Which implementation this process uses, for run logs."""
+    if native_available():
+        return f"built from {os.path.relpath(_SRC, os.path.dirname(_OUT))}"
+    return "python path (no g++, no source, or the build failed)"

@@ -13,8 +13,8 @@ baseline to gate perf claims in CI (docs/observability.md).
 
 ``apex-tpu-bench --kernels fused_adam_1b,layer_norm [--emit-baseline
 [PATH]]`` runs just that subset of the bench suite against the
-already-selected backend (no relay probing / cache polling — this is the
-per-kernel path of the perf gate, docs/performance.md). With
+already-selected backend (the per-kernel path of the perf gate,
+docs/performance.md). With
 ``--emit-baseline`` the capture is written as a suite-format JSON
 (default ``BENCH_BASELINE.json``) ready to commit and enforce with
 ``tools/check_regression.py CURRENT --suite BENCH_BASELINE.json`` —
@@ -1110,8 +1110,10 @@ def main() -> None:
     # a structured record instead of a stack trace mid-measurement; there is
     # no step boundary to poll, so the guard raises to unwind immediately
     from apex_tpu.resilience import PreemptionGuard
+    from apex_tpu.utils.env import enable_compile_cache
     from apex_tpu.utils.logging import is_rank_zero, publish_event
 
+    enable_compile_cache()
     with PreemptionGuard(raise_on_signal=True) as guard:
         # --flight-recorder selects this mode too: silently dropping the
         # flag would mean the requested postmortem recorder never armed —

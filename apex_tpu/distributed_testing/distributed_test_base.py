@@ -16,7 +16,7 @@ import unittest
 from typing import Optional, Sequence
 
 import jax
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.parallel.mesh import make_mesh

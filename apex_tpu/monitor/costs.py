@@ -473,9 +473,8 @@ def walk_module(text: str) -> Dict[str, Any]:
 def stablehlo_debug_text(lowered, large_elements_limit: int = 8) -> str:
     """The lowered module's StableHLO text WITH MLIR debug info — scope
     paths appear only in ``loc(...)`` metadata, which the default
-    ``as_text()`` strips. ``large_elements_limit`` elides baked-in param
-    constants (a decode lowering with closed-over weights is ~15 MB of
-    hex without it)."""
+    ``as_text()`` strips. ``large_elements_limit`` elides any large
+    constant's payload (the walk never needs one)."""
     try:
         ir = lowered.compiler_ir()
         return ir.operation.get_asm(
@@ -485,6 +484,25 @@ def stablehlo_debug_text(lowered, large_elements_limit: int = 8) -> str:
         # no debug info available: the walk still totals correctly,
         # every op just lands in the "other" phase
         return lowered.as_text()
+
+
+def module_facts(stablehlo_text: str) -> Dict[str, int]:
+    """What a lowered module carries by value versus by argument:
+    ``module_chars`` (text size), ``main_args`` (arguments of the public
+    ``main``) and ``max_literal_chars`` (the longest ``dense<...>``
+    payload). A program that closes over its weights shows up as few
+    arguments, a literal the size of the model, and a text that grows
+    with the parameter count; the serving programs must show none of
+    the three (tier-1 asserts on ``tiny``, chip_smoke.py prints XL's)."""
+    start = stablehlo_text.index("func.func public @main(")
+    sig = stablehlo_text[start:stablehlo_text.index(") -> ", start)]
+    return {
+        "module_chars": len(stablehlo_text),
+        "main_args": len(re.findall(r"%arg\d+:", sig)),
+        "max_literal_chars": max(
+            (len(m) for m in re.findall(r"dense<([^>]*)>",
+                                        stablehlo_text)), default=0),
+    }
 
 
 def collective_counts(stablehlo_text: str) -> Dict[str, int]:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from apex_tpu.ops.pallas.layer_norm_kernel import ln_bwd_pallas, ln_fwd_pallas
+from apex_tpu.utils.logging import one_time_warning
 
 _f32 = jnp.float32
 
@@ -41,8 +42,24 @@ def _norm_size(normalized_shape) -> int:
     return out
 
 
+def pallas_route(hidden: int) -> Optional[str]:
+    """None when the Pallas kernel takes this hidden size, else why the
+    pure-jnp path runs instead (chip_smoke.py prints it per kernel)."""
+    if hidden % 128:
+        return f"hidden {hidden} is not a multiple of the 128-lane tile"
+    if hidden > 65536:
+        return f"hidden {hidden} exceeds the kernel's 65536 row budget"
+    return None
+
+
 def _pallas_ok(hidden: int) -> bool:
-    return hidden % 128 == 0 and hidden <= 65536
+    reason = pallas_route(hidden)
+    if reason is not None:
+        # the route is kept, but never silently: GPT-2 XL (hidden 1600)
+        # was "fused Pallas" in name only
+        one_time_warning(
+            f"fused layer/rms norm takes the jnp path: {reason}")
+    return reason is None
 
 
 # ----------------------------------------------------------- jnp reference

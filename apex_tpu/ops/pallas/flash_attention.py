@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams as _CompilerParams
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.tune.api import pow2_bucket, tuned_params
@@ -59,8 +59,8 @@ _f32 = jnp.float32
 # buffering. The only measured datapoint is the OLD (256, 512) defaults:
 # ~10% MXU on v5e at s=2048, overhead-bound. (512, 1024) is the sizing
 # hypothesis that per-iteration overhead amortizes to noise — it has NOT
-# been measured on chip yet; tools/chipq/q030 sweeps and q080 applies the
-# winner (docs/performance.md tracks measurement status).
+# been measured on chip yet; `apex-tpu-tune --kernels flash_attention`
+# sweeps it (docs/performance.md tracks measurement status).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams as _CompilerParams
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.pallas.tiling import groupnorm_hw_block

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 from apex_tpu.utils.env import interpret_default
 

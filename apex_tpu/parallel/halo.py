@@ -21,7 +21,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 
 def left_right_halo_exchange(left_output_halo: jax.Array,

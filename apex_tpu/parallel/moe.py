@@ -18,7 +18,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 _f32 = jnp.float32
 

@@ -35,7 +35,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,

@@ -31,7 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.utils.compat import axis_size
+from jax.lax import axis_size
 
 from apex_tpu.ops.pallas.flash_attention import flash_attention
 

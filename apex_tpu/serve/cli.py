@@ -13,7 +13,9 @@ prompt_tokens, new_tokens, generated, ttft_s, latency_s, tokens_per_s}``
 (load-shed requests additionally carry ``"retriable": true`` — a healthy
 or less-loaded replica can serve them); the final line is the aggregate
 summary (tokens/s, p50/p99 per-step latency, TTFT, plus the SLO fields
-``rejected`` / ``deadline_exceeded`` / ``shed_rate`` / ``restarts``).
+``rejected`` / ``deadline_exceeded`` / ``shed_rate`` / ``restarts``) with
+a ``device`` block — platform, ``device_kind``, device count and whether
+Pallas ran interpreted — so no record outlives the device it ran on.
 ``serve_*`` lifecycle events ride the telemetry bus —
 ``--telemetry-jsonl PATH`` mirrors them (and nothing else crosses the
 host boundary per step beyond the sampled tokens).
@@ -167,6 +169,7 @@ def _run_fleet(args, cfg, max_len: int, prompts, slo) -> int:
     from apex_tpu.serve.fleet import (EngineReplica, FleetController,
                                       FleetTraceHarness)
     from apex_tpu.serve.scheduler import Request
+    from apex_tpu.utils.env import device_block
 
     roles = _parse_roles(args.roles)
     if roles:
@@ -387,7 +390,8 @@ def _run_fleet(args, cfg, max_len: int, prompts, slo) -> int:
              "decode_compiles": [h.engine.decode_traces
                                  for h in handles],
              "prefill_compiles": [h.engine.prefill_traces
-                                  for h in handles]}
+                                  for h in handles],
+             "device": device_block()}
     if harness is not None:
         # sampling provenance: how many journeys streamed, how many the
         # tail capture promoted, how many happy-path ones were dropped
@@ -585,6 +589,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "scheduler error, the last events + open spans "
                          "+ memory snapshot land here atomically")
     args = ap.parse_args(argv)
+
+    from apex_tpu.utils.env import device_block, enable_compile_cache
+
+    enable_compile_cache()
 
     import jax.numpy as jnp
 
@@ -999,9 +1007,12 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     for rec in stats.requests:
         print(json.dumps(rec, sort_keys=True))
+    # the device rides the record: a run that fell onto the CPU (a busy
+    # chip under an unpinned platform) must never read as a chip run
     final = {"summary": stats.summary(),
              "decode_compiles": engine.decode_traces,
-             "prefill_compiles": engine.prefill_traces}
+             "prefill_compiles": engine.prefill_traces,
+             "device": device_block()}
     if engine.tp > 1:
         # mesh provenance + the per-step collective contract: one
         # compile per MESH SHAPE is the invariant decode_compiles

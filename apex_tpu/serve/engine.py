@@ -89,6 +89,7 @@ request ever pays a trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Sequence
 
 import jax
@@ -105,11 +106,11 @@ from apex_tpu.serve.attention import resolve_block_k
 from apex_tpu.serve.kv_cache import (init_cache, init_paged_cache,
                                      shard_cache, tp_cache_specs)
 from apex_tpu.serve.paging import PagePool, PrefixIndex
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 # bound at module import, NOT function-locally (the scheduler's
-# precedent): a sys.modules purge-and-reimport mid-process (the
-# test_chip_worker pattern) would otherwise make engine builds publish
-# to a FRESH event bus that collection-time subscribers never see
+# precedent): a sys.modules purge-and-reimport mid-process would
+# otherwise make engine builds publish to a FRESH event bus that
+# collection-time subscribers never see
 from apex_tpu.utils.logging import publish_event
 
 
@@ -251,6 +252,11 @@ class Engine:
         else:
             self.mesh = None
             self._tp_params = self._tp_param_specs = None
+            # the weights ride every compiled call as an argument: a
+            # restored checkpoint's numpy leaves would be re-uploaded
+            # per step, so place them once (device arrays pass through
+            # untouched — fleet replicas keep sharing one pytree)
+            self.params = jax.tree_util.tree_map(jnp.asarray, params)
         # resolve the tuned geometry ONCE at engine build (cache lookups
         # at trace time inside scan would re-announce per position);
         # paged mode validates block_k against page_size here — a tuned
@@ -360,10 +366,18 @@ class Engine:
             scaled = jnp.where(scaled < kth, jnp.float32(-1e30), scaled)
         return jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
 
-    def _token_step(self, cache, tokens, positions, mask, *,
+    @property
+    def _weights(self):
+        """The param pytree the compiled calls take as their FIRST jit
+        argument (the head-sharded tree under tensor parallelism). Never
+        closed over: a closed-over array is lowered as a dense constant,
+        which at GPT-2 XL is the whole model baked into every program."""
+        return self.params if self.mesh is None else self._tp_params
+
+    def _token_step(self, weights, cache, tokens, positions, mask, *,
                     final_scope: str = "sampling"):
         if self.mesh is None:
-            return gpt2_token_forward(self.model_cfg, self.params, cache,
+            return gpt2_token_forward(self.model_cfg, weights, cache,
                                       tokens, positions, mask,
                                       block_k=self.block_k,
                                       kv_quant=self._kv_quant,
@@ -388,13 +402,14 @@ class Engine:
                        in_specs=(self._tp_param_specs, specs, P(), P(),
                                  P()),
                        out_specs=(P(), specs), check_vma=False)
-        return fn(self._tp_params, cache, tokens, positions, mask)
+        return fn(weights, cache, tokens, positions, mask)
 
-    def _decode_fn(self, cache, last_tokens, active, rng, pol=None):
+    def _decode_fn(self, weights, cache, last_tokens, active, rng,
+                   pol=None):
         self.decode_traces += 1          # python side effect: trace count
         positions = cache.lengths
-        logits, cache = self._token_step(cache, last_tokens, positions,
-                                         active)
+        logits, cache = self._token_step(weights, cache, last_tokens,
+                                         positions, active)
         with jax.named_scope("sampling"):
             rng, sub = jax.random.split(rng)
             next_tokens = self._sample(logits, sub, pol)
@@ -404,8 +419,8 @@ class Engine:
     def _make_prefill(self, bucket: int):
         keep = self.config.keep_prefill_logits
 
-        def prefill_fn(cache, tokens, admit, start, tail_lens, rng,
-                       pol=None):
+        def prefill_fn(weights, cache, tokens, admit, start, tail_lens,
+                       rng, pol=None):
             self.prefill_traces += 1
             cache = kv_cache.reset_slots(cache, admit)
 
@@ -418,7 +433,7 @@ class Engine:
                 # slot path — bit-identical to the pre-paging scan)
                 positions = jnp.where(write, start + p, cache.lengths)
                 logits, cache = self._token_step(
-                    cache, tokens[:, p], positions, write)
+                    weights, cache, tokens[:, p], positions, write)
                 last_logits = jnp.where(write[:, None], logits,
                                         last_logits)
                 return (cache, last_logits), (logits if keep else None)
@@ -457,8 +472,8 @@ class Engine:
         k = self._spec_k
         width = k + 1
 
-        def verify_fn(cache, last_tokens, drafts, draft_lens, active,
-                      rng, pol=None):
+        def verify_fn(weights, cache, last_tokens, drafts, draft_lens,
+                      active, rng, pol=None):
             self.verify_traces += 1      # python side effect: trace count
             start = cache.lengths
 
@@ -470,7 +485,7 @@ class Engine:
                     p == 0, last_tokens,
                     drafts[:, jnp.maximum(p - 1, 0)])
                 logits, cache = self._token_step(
-                    cache, tokens, positions, write,
+                    weights, cache, tokens, positions, write,
                     final_scope="verify")
                 return cache, logits
 
@@ -515,14 +530,16 @@ class Engine:
                 "min_ps": jnp.asarray(self._pol_min_ps)}
 
     def _decode_args(self):
-        args = (self.cache, jnp.zeros((self.config.num_slots,), jnp.int32),
+        args = (self._weights, self.cache,
+                jnp.zeros((self.config.num_slots,), jnp.int32),
                 jnp.zeros((self.config.num_slots,), bool), self.rng)
         return args + ((self._policy_args(),)
                        if self._policy is not None else ())
 
     def _prefill_args(self, bucket: int):
         b = self.config.num_slots
-        args = (self.cache, jnp.zeros((b, bucket), jnp.int32),
+        args = (self._weights, self.cache,
+                jnp.zeros((b, bucket), jnp.int32),
                 jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b,), jnp.int32), self.rng)
         return args + ((self._policy_args(),)
@@ -530,7 +547,7 @@ class Engine:
 
     def _verify_args(self):
         b = self.config.num_slots
-        args = (self.cache, jnp.zeros((b,), jnp.int32),
+        args = (self._weights, self.cache, jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b, self._spec_k), jnp.int32),
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
                 self.rng)
@@ -544,8 +561,11 @@ class Engine:
         Each fresh compile publishes its static XLA memory reservation as
         an ``hbm_snapshot`` event (``apex_tpu.monitor.memory``) — the
         serving AOT points are where the engine's HBM budget is decided,
-        and the paged-vs-slot capacity comparison reads them.
+        and the paged-vs-slot capacity comparison reads them — together
+        with the lowered module's size and ``main`` argument count
+        (``monitor.costs.module_facts``: the weights must be arguments).
         """
+        from apex_tpu.monitor.costs import module_facts
         from apex_tpu.monitor.memory import publish_compiled_memory
 
         if self._decode_aot is None:
@@ -559,7 +579,8 @@ class Engine:
                 "serve_decode", self._decode_aot,
                 num_slots=self.config.num_slots, max_len=self.max_len,
                 page_size=self.config.page_size or 0,
-                kv_cache_bytes=self.kv_cache_bytes)
+                kv_cache_bytes=self.kv_cache_bytes,
+                **module_facts(self._decode_lowered.as_text()))
         for bucket in prompt_buckets:
             bucket = pow2_ceil(int(bucket))
             if bucket not in self._prefill_aot:
@@ -574,7 +595,8 @@ class Engine:
                 publish_compiled_memory(
                     "serve_prefill", self._prefill_aot[bucket],
                     bucket=bucket, num_slots=self.config.num_slots,
-                    max_len=self.max_len)
+                    max_len=self.max_len,
+                    **module_facts(lowered.as_text()))
         if self._spec_k and self._verify_aot is None:
             # retained like _decode_lowered: cost_ledger() prices the
             # verify step from the saved lowering after reset()
@@ -585,7 +607,8 @@ class Engine:
                 "serve_verify", self._verify_aot,
                 draft_len=self._spec_k,
                 num_slots=self.config.num_slots, max_len=self.max_len,
-                page_size=self.config.page_size or 0)
+                page_size=self.config.page_size or 0,
+                **module_facts(self._verify_lowered.as_text()))
         return self
 
     def _init_state(self, seed: int) -> None:
@@ -648,7 +671,7 @@ class Engine:
     def reset(self, seed: int = 0, *,
               keep_prefix_cache: bool = False) -> "Engine":
         """Drop all serving state — empty cache, fresh PRNG stream — while
-        keeping every compiled artifact (the jits close over params only).
+        keeping every compiled artifact (the weights are a jit argument).
         A server drain/restart costs zero recompiles; tests reuse one
         compiled engine across scenarios.
 
@@ -887,8 +910,9 @@ class Engine:
         if fn is None:
             fn = self._prefill_jits.setdefault(
                 bucket, self._make_prefill(bucket))
-        args = (self.cache, jnp.asarray(tokens), jnp.asarray(admit),
-                jnp.asarray(starts), jnp.asarray(lens), self.rng)
+        args = (self._weights, self.cache, jnp.asarray(tokens),
+                jnp.asarray(admit), jnp.asarray(starts),
+                jnp.asarray(lens), self.rng)
         if self._policy is not None:
             args += (self._policy_args(),)
         self.cache, first, last_logits, all_logits, self.rng = fn(*args)
@@ -937,7 +961,7 @@ class Engine:
         fn = self._decode_aot or self._decode
         lt = jnp.asarray(np.asarray(last_tokens, np.int32))
         act = jnp.asarray(act_np)
-        args = (self.cache, lt, act, self.rng)
+        args = (self._weights, self.cache, lt, act, self.rng)
         if self._policy is not None:
             args += (self._policy_args(),)
         next_tokens, logits, self.cache, self.rng = fn(*args)
@@ -1022,7 +1046,8 @@ class Engine:
                 f"the draft or evict before speculating further")
         fn = self._verify_aot or self._verify
         b = self.config.num_slots
-        args = (self.cache, jnp.asarray(np.asarray(last_tokens, np.int32)),
+        args = (self._weights, self.cache,
+                jnp.asarray(np.asarray(last_tokens, np.int32)),
                 jnp.asarray(np.asarray(drafts, np.int32).reshape(
                     b, self._spec_k)),
                 jnp.asarray(dl_np.astype(np.int32)), jnp.asarray(act_np),
@@ -1318,12 +1343,21 @@ class Engine:
         return total
 
 
-def init_gpt2_params(cfg: GPT2Config, seed: int = 0):
-    """Random GPT-2 params for smoke/bench serving (real deployments load
-    a checkpoint). Init runs the training forward once at a short length.
-    """
+@functools.lru_cache(maxsize=8)
+def _jitted_init(cfg: GPT2Config):
+    """One jitted ``model.init`` per config: a second engine's weights
+    (a fleet's replicas, a restart, the next test) reuse the compile."""
     from apex_tpu.models.gpt2 import GPT2
 
-    model = GPT2(cfg)
+    return jax.jit(GPT2(cfg).init)
+
+
+def init_gpt2_params(cfg: GPT2Config, seed: int = 0):
+    """Random GPT-2 params for smoke/bench serving (real deployments load
+    a checkpoint). ``model.init`` traces the training forward at a short
+    length to discover the param shapes; under ``jit`` only the
+    initializers survive (the params do not depend on the forward), so
+    a 48-layer model pays one compile, not an eager forward.
+    """
     dummy = jnp.zeros((1, min(8, cfg.n_positions)), jnp.int32)
-    return model.init(jax.random.PRNGKey(seed), dummy)
+    return _jitted_init(cfg)(jax.random.PRNGKey(seed), dummy)

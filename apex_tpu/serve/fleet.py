@@ -118,9 +118,9 @@ from typing import Any, Dict, List, Optional, Sequence, Set
 from apex_tpu.monitor.export import percentile
 from apex_tpu.monitor.flight import FlightRecorder
 # module-level on purpose (flight too): a function-local import inside
-# FleetTraceHarness would RE-import monitor.trace after
-# test_chip_worker's sys.modules purge, binding a fresh module whose
-# bus the collection-time scheduler modules never publish to — the
+# FleetTraceHarness would RE-import monitor.trace after a sys.modules
+# purge of apex_tpu.*, binding a fresh module whose bus the
+# already-imported scheduler modules never publish to — the
 # tail-capture router would then miss every lifecycle event (the
 # test_serve_resilience subscribe-at-collection precedent)
 from apex_tpu.monitor.trace import (ChromeTraceWriter, TailCaptureRouter,

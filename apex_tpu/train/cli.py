@@ -154,6 +154,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "may block before a collective_stall event")
     args = ap.parse_args(argv)
 
+    from apex_tpu.utils.env import device_block, enable_compile_cache
+
+    enable_compile_cache()
+
     # ---- the usage-error matrix: refuse contradictions loudly BEFORE
     # ---- any params are built or anything compiles (fleet precedent).
     # ---- Geometry/range rules live in ONE place — TrainConfig.validate,
@@ -253,6 +257,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         config, injector=injector, max_restarts=args.max_restarts,
         world_schedule=worlds).install_signals()
     report = supervisor.run()
+    report["device"] = device_block()
     print(json.dumps(report, sort_keys=True))
     return 0
 

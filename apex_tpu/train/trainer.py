@@ -49,7 +49,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.amp.grad_scaler import DynamicGradScaler, ScalerState
@@ -240,7 +240,7 @@ def _make_shard_grads_tp(loss_fn: Callable, scaler: DynamicGradScaler,
 
     sm = shard_map(body, mesh=mesh,
                    in_specs=(specs, sstate_spec, P()),
-                   out_specs=(specs, P()), check_rep=False)
+                   out_specs=(specs, P()), check_vma=False)
 
     def shard_grads(params, sstate, tokens):
         counts["shard_grads"] += 1

@@ -9,8 +9,8 @@ so CPU tests and virtual meshes never depend on cache state.
 
 The cache is warmed by timing real compiled calls
 (:func:`~apex_tpu.tune.search.autotune_kernel`, the ``apex-tpu-tune``
-CLI) and persists as one JSON file (``APEX_TPU_TUNE_CACHE`` /
-``~/.cache/apex_tpu/tune_cache.json``). Selections and search results
+CLI) and persists as the one JSON file ``APEX_TPU_TUNE_CACHE`` names
+(unset: no cache, heuristics only). Selections and search results
 publish ``kernel_autotune`` events on the monitor event bus, so tuning
 provenance lands in the telemetry JSONL. The committed
 ``BENCH_BASELINE.json`` + ``tools/check_regression.py --suite`` close the

@@ -86,8 +86,11 @@ def tuned_params(kernel: str, shape_key, defaults: Dict[str, Any], *,
         # would name the HOST, so a stray cache file could silently change
         # the committed AOT artifacts. Heuristics only.
         return dict(defaults)
+    cache = default_cache()
+    if cache is None:
+        return dict(defaults)
     key = cache_key(kernel, shape_key, dtype, device_key())
-    entry = default_cache().get(key)
+    entry = cache.get(key)
     if entry is None:
         return dict(defaults)
     params = entry.get("params", {})
@@ -110,6 +113,9 @@ def record_tuned(kernel: str, shape_key, params: Dict[str, Any], *,
     key = cache_key(kernel, shape_key, dtype, device or device_key(),
                     code_version(kernel))
     cache = default_cache()
+    if cache is None:
+        raise ValueError(
+            "record_tuned needs a tune cache: set APEX_TPU_TUNE_CACHE")
     cache.put(key, params, meta=meta)
     if save:
         cache.save()

@@ -18,9 +18,11 @@ Durability rules (mirroring ``apex_tpu.resilience``'s conventions):
   no floats) so two processes tuning the same workload produce identical
   keys and can share one file.
 
-The default location is ``~/.cache/apex_tpu/tune_cache.json``; override
-with ``APEX_TPU_TUNE_CACHE`` (tests point it at a tmpdir; CI can point it
-at a committed warm cache).
+The file is the one ``APEX_TPU_TUNE_CACHE`` names (tests point it at a
+tmpdir; CI can point it at a committed warm cache). With the variable
+unset there is NO cache: lookups return the heuristics, so block sizes
+depend only on what the checkout holds — never on a file outside it
+that two commits under comparison would share.
 """
 
 from __future__ import annotations
@@ -61,34 +63,28 @@ def code_version(kernel: str) -> int:
     return CODE_VERSIONS.get(kernel, 0)
 
 
-def default_cache_path() -> str:
-    env = os.environ.get("APEX_TPU_TUNE_CACHE")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "apex_tpu",
-                        "tune_cache.json")
+def default_cache_path() -> Optional[str]:
+    """The tune-cache file ``APEX_TPU_TUNE_CACHE`` names, or None."""
+    return os.environ.get("APEX_TPU_TUNE_CACHE") or None
 
 
 def device_key(devices=None) -> str:
     """Stable chip identifier for cache keys: the detected generation
-    (``v5e``/``v5p``/``v6e``), else the raw ``device_kind`` slug, else
-    ``cpu``. Never raises — keys must be computable backend-less."""
+    (``v5e``/``v5p``/``v6e``), else the raw ``device_kind`` slug of a
+    TPU not in the peak table, else ``cpu``."""
+    from apex_tpu.utils.prof import detect_chip
+
+    if devices is None:
+        import jax
+
+        devices = jax.devices()
+    if not devices or getattr(devices[0], "platform", None) != "tpu":
+        return "cpu"
     try:
-        from apex_tpu.utils.prof import detect_chip
-
-        gen = detect_chip(devices)
-        if gen:
-            return gen
-        if devices is None:
-            import jax
-
-            devices = jax.devices()
-        if devices and getattr(devices[0], "platform", None) == "tpu":
-            kind = str(getattr(devices[0], "device_kind", "tpu"))
-            return kind.lower().replace(" ", "-") or "tpu"
-    except Exception:
-        pass
-    return "cpu"
+        return detect_chip(devices)
+    except ValueError:  # a key needs an identity, not published peaks
+        kind = str(getattr(devices[0], "device_kind", "tpu"))
+        return kind.lower().replace(" ", "-") or "tpu"
 
 
 def cache_key(kernel: str, shape_key, dtype, device: str,
@@ -130,6 +126,9 @@ class TuneCache:
 
     def __init__(self, path: Optional[str] = None):
         self.path = path or default_cache_path()
+        if not self.path:
+            raise ValueError(
+                "no tune cache: set APEX_TPU_TUNE_CACHE (or pass a path)")
         self._lock = threading.Lock()
         self.entries: Dict[str, Dict[str, Any]] = {}
         self.load()
@@ -198,9 +197,12 @@ _default: Tuple[Optional[str], Optional[TuneCache]] = (None, None)
 _default_lock = threading.Lock()
 
 
-def default_cache() -> TuneCache:
+def default_cache() -> Optional[TuneCache]:
+    """The cache ``APEX_TPU_TUNE_CACHE`` names, or None when unset."""
     global _default
     path = default_cache_path()
+    if path is None:
+        return None
     with _default_lock:
         cached_path, cache = _default
         if cache is None or cached_path != path:

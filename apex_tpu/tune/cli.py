@@ -10,8 +10,8 @@ Usage::
 ``--spec`` points at a JSON workload description — a list of
 ``{"kernel": ..., "shape": {...}, "dtype": "bfloat16"}`` entries; without
 it, each selected kernel tunes its registry ``default_shapes`` (the bench
-shapes). ``--cache`` overrides the cache file (else
-``APEX_TPU_TUNE_CACHE`` / ``~/.cache/apex_tpu/tune_cache.json``).
+shapes). ``--cache`` names the cache file (else ``APEX_TPU_TUNE_CACHE``;
+one of the two is required — there is no default location).
 
 Every search publishes ``kernel_autotune`` events on the process event
 bus; ``--telemetry-jsonl`` attaches a :class:`apex_tpu.monitor.Telemetry`
@@ -23,8 +23,7 @@ summary ``{"tuned": N, "cache": PATH, ...}``.
 Off-TPU the kernels run in interpret mode — the timings are meaningless
 for real tuning (the CLI says so on stderr) but the full pipeline
 (search → cache write → events) runs, which is what the CPU smoke test
-exercises. Real warming happens on the chip, typically via the
-background chip worker (docs/performance.md).
+exercises. Real warming happens on the chip (docs/performance.md).
 """
 
 from __future__ import annotations
@@ -72,8 +71,8 @@ def main(argv=None) -> int:
     ap.add_argument("--spec", default=None,
                     help="JSON workload file: [{kernel, shape, dtype?}]")
     ap.add_argument("--cache", default=None,
-                    help="cache file (default: APEX_TPU_TUNE_CACHE or "
-                         "~/.cache/apex_tpu/tune_cache.json)")
+                    help="cache file (default: APEX_TPU_TUNE_CACHE; one "
+                         "of the two is required)")
     ap.add_argument("--iters", type=int, default=None,
                     help="timed steps per candidate (default: 10 on TPU, "
                          "2 off-TPU)")
@@ -86,6 +85,10 @@ def main(argv=None) -> int:
 
     if args.cache:
         os.environ["APEX_TPU_TUNE_CACHE"] = args.cache
+    if not os.environ.get("APEX_TPU_TUNE_CACHE"):
+        print("[apex-tpu-tune] no cache file: pass --cache PATH or set "
+              "APEX_TPU_TUNE_CACHE", file=sys.stderr)
+        return 2
 
     from apex_tpu.tune import cache as tune_cache
     from apex_tpu.tune.search import warm_cache

@@ -3,11 +3,10 @@ geometry and persist the winner.
 
 Timing rides :func:`apex_tpu.utils.benchtime.timed_steps` — K chained
 steps inside one jitted ``fori_loop`` with a data-dependent host fetch —
-the same methodology as ``bench.py`` (per-dispatch wall clock is
-meaningless on tunneled/async runtimes; see docs/performance.md). On a
+the same methodology as ``bench.py`` (see docs/performance.md). On a
 CPU host the kernels run in interpret mode, which only exercises the
-machinery (the CLI smoke test); real tuning needs the chip (typically
-via the background chip worker). ``APEX_TPU_FORCE_COMPILED`` is NOT a
+machinery (the CLI smoke test); real tuning needs the chip.
+``APEX_TPU_FORCE_COMPILED`` is NOT a
 tuning path: under it ``tuned_params`` deliberately skips the cache
 (deviceless AOT has no trustworthy device identity), so entries warmed
 that way would be dead on arrival.

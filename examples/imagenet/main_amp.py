@@ -14,7 +14,7 @@ import time
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models.resnet import ResNet18ish, ResNet50

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 import torch
 
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.contrib.clip_grad import clip_grad_norm_

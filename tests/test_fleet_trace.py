@@ -44,7 +44,7 @@ from apex_tpu.serve.fleet import (EngineReplica, FleetController,
 from apex_tpu.serve.metrics import ServeMetrics
 from apex_tpu.serve.resilience import AdmissionController
 from apex_tpu.serve.scheduler import Request, ServeScheduler
-# bound at collection time: test_chip_worker purges apex_tpu.* from
+# bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session (see test_serve_resilience for the history)
 from apex_tpu.utils.logging import publish_event, subscribe_events
 
@@ -158,7 +158,7 @@ def test_reject_at_submit_journey_is_promotable(engines, tmp_path):
     (the file would silently miss exactly the requests being shed).
     Scheduler + admission are bound at collection time like every other
     import here — a function-local import would re-bind them to a fresh
-    bus after test_chip_worker's purge and the router would never hear
+    bus after a sys.modules purge and the router would never hear
     the rejection."""
     path = str(tmp_path / "reject.json")
     tracer = Tracer()

@@ -186,7 +186,7 @@ class TestL1DistributedMatrix:
     def test_distributed_cell_trains(self, opt_level, loss_scale):
         import functools
 
-        from apex_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from apex_tpu.models.resnet import ResNet18ish

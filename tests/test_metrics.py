@@ -49,7 +49,7 @@ from apex_tpu.monitor.slo import SLObjective, SLOTracker, parse_slo_specs
 from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
 from apex_tpu.serve.metrics import ServeMetrics
 from apex_tpu.serve.scheduler import Request, ServeScheduler, ServeStats
-# bound at collection time: test_chip_worker purges apex_tpu.* from
+# bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session, and a function-local re-import after that
 # would subscribe to a FRESH bus the (old) modules never publish to
 from apex_tpu.utils.logging import subscribe_events
@@ -382,7 +382,7 @@ def test_write_snapshot_atomic_and_bus_event(tmp_path):
     # function-local import, DELIBERATELY inverted from the module-level
     # idiom above: export.py publishes through a deferred call-time
     # import (it must stay stdlib-only at import time), so after
-    # test_chip_worker's mid-session sys.modules purge it publishes to
+    # a mid-session sys.modules purge it publishes to
     # the FRESH bus — the subscription must resolve at call time too
     from apex_tpu.utils.logging import subscribe_events as _sub
     unsub = _sub(events.append)

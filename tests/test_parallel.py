@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from apex_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from apex_tpu.parallel import (DistributedDataParallel, SyncBatchNorm,
                                bucketed_allreduce, get_mesh,

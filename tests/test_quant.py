@@ -54,7 +54,7 @@ from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
 from apex_tpu.serve.fleet import EngineReplica
 from apex_tpu.serve.kv_cache import init_cache, write_token
 from apex_tpu.serve.scheduler import Request, ServeScheduler
-# bound at collection time: test_chip_worker purges apex_tpu.* from
+# bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session (see test_serve for the history)
 from apex_tpu.utils.logging import subscribe_events
 

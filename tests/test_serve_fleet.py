@@ -36,7 +36,7 @@ from apex_tpu.serve.fleet import (REPLICA_DEAD, REPLICA_HEALTHY,
                                   FleetController, ReplicaRegistry)
 from apex_tpu.serve.metrics import ServeMetrics
 from apex_tpu.serve.scheduler import Request, ServeScheduler
-# bound at collection time: test_chip_worker purges apex_tpu.* from
+# bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session (see test_serve_resilience for the history)
 from apex_tpu.utils.logging import subscribe_events
 

@@ -31,7 +31,7 @@ from apex_tpu.serve.resilience import (SHED_POLICIES, AdmissionController,
                                        ServeSupervisor, TickJournal)
 from apex_tpu.serve.scheduler import (TERMINAL_STATES, Request,
                                       ServeScheduler)
-# bound at collection time: test_chip_worker purges apex_tpu.* from
+# bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session, and a function-local re-import after that
 # would subscribe to a FRESH bus while the (old) scheduler module keeps
 # publishing to the original one

@@ -44,7 +44,7 @@ from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
 from apex_tpu.serve.scheduler import Request, ServeScheduler
 from apex_tpu.serve.tp import (count_collectives, expected_collectives,
                                serving_mesh)
-# bound at collection time (test_chip_worker purges apex_tpu.* from
+# bound at collection time (a test that purges apex_tpu.* from
 # sys.modules mid-session; a function-local re-import would subscribe
 # to a FRESH bus the old engine module never publishes to)
 from apex_tpu.utils.logging import subscribe_events

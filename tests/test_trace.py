@@ -170,7 +170,7 @@ def test_spans_by_trace_groups():
 
 def test_annotate_mirrors_to_enabled_tracer():
     # annotate resolves the trace module BY NAME at call time, so this
-    # test must too (test_chip_worker's purge can split identities)
+    # test must too (a sys.modules purge can split identities)
     import importlib
 
     prof_mod = importlib.import_module("apex_tpu.utils.prof")
@@ -315,7 +315,7 @@ def test_flight_ring_bounded_under_overflow_storm(tmp_path):
 def test_flight_dump_schema_and_atomicity(tmp_path, monkeypatch):
     import sys
 
-    # resolve the module BACKING the class: test_chip_worker's purge can
+    # resolve the module BACKING the class: a sys.modules purge can
     # leave a reimported apex_tpu.monitor.flight coexisting with the
     # collection-time one these tests hold — patch the one in use
     flight_mod = sys.modules[FlightRecorder.__module__]

@@ -480,6 +480,10 @@ def test_train_cli_chaos_smoke_end_to_end(tmp_path, capsys):
     assert report["final_step"] == 7 and not report["preempted"]
     assert report["restarts"] == 1 and report["preempt_drains"] == 1
     assert report["goodput"]["steps"] == 8  # exactly-once via the CLI too
+    # the report names what ran it: the harness's virtual CPU devices
+    assert report["device"] == {
+        "platform": "cpu", "device_kind": "cpu",
+        "device_count": len(jax.devices()), "interpret_mode": True}
 
 
 # ------------------------------------------------ bench + gate wiring

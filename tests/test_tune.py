@@ -144,6 +144,26 @@ class TestTunedParams:
                                 interpret=True)
         assert y.shape == (16, 128)
 
+    def test_no_cache_unless_the_variable_names_one(self, monkeypatch):
+        # block sizes must depend only on what the checkout holds: with
+        # APEX_TPU_TUNE_CACHE unset no file is consulted (the old default,
+        # ~/.cache/apex_tpu/tune_cache.json, is a file outside the
+        # checkout that two commits under comparison would share)
+        monkeypatch.delenv("APEX_TPU_TUNE_CACHE", raising=False)
+        tune.invalidate()
+        assert tune.default_cache_path() is None
+        assert tune.default_cache() is None
+        monkeypatch.setattr(
+            os.path, "expanduser",
+            lambda p: (_ for _ in ()).throw(AssertionError(p)))
+        got = tuned_params("layer_norm", (("rows", 64), ("hidden", 128)),
+                           {"block_rows": 8}, interpret=False)
+        assert got == {"block_rows": 8}
+        with pytest.raises(ValueError, match="APEX_TPU_TUNE_CACHE"):
+            tune.record_tuned("layer_norm", (("rows", 64),), {"b": 1})
+        with pytest.raises(ValueError, match="APEX_TPU_TUNE_CACHE"):
+            tune.TuneCache()
+
     def test_force_compiled_aot_skips_cache(self, tmp_cache, monkeypatch):
         # deviceless AOT (APEX_TPU_FORCE_COMPILED=1) must not consult the
         # cache: device_key() would name the host, not the compile target,

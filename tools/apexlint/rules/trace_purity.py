@@ -53,6 +53,8 @@ from ..core import LintContext, Rule, SourceFile, Violation, register
 # names ends the traversal — their internals are host code by design.
 BOUNDARY_FUNCS = frozenset({
     "tuned_params",     # tune.api: cache lookup + autotune provenance
+    "_pallas_ok",       # normalization: kernel-or-jnp route from the
+    #                     static hidden size, announced once per process
 })
 
 EFFECT_NAME_CALLS = frozenset({

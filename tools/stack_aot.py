@@ -36,7 +36,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from apex_tpu.utils.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 OUT_PATH = os.environ.get("STACK_AOT_OUT",
                           os.path.join(ROOT, "STACK_AOT.json"))
