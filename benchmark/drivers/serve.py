@@ -288,18 +288,20 @@ def score(cfg, mix, params, reference, chosen, control=None) -> dict:
 
 
 def longest_stalls(obs) -> str:
-    """The window's longest scheduler step, engine call and stretch between
-    two steps, each with the second of the window it began in: a run that
-    reads far off says here where it stood still."""
+    """The window's longest scheduler step, prefill call, decode step and
+    stretch between two steps, each with the second of the window it began
+    in: a run that reads far off says here where it stood still."""
     lo, hi = obs["window"]
-    steps = [s for s in obs["spans"] if s[0] == "step" and lo <= s[1] < hi]
-    calls = [s for s in obs["spans"] if s[0] != "step" and lo <= s[1] < hi]
-    between = [("between steps", a[2], b[1], None)
-               for a, b in zip(steps, steps[1:])]
+    groups = {name: [s for s in obs["spans"]
+                     if s[0] == name and lo <= s[1] < hi]
+              for name in ("step", "prefill", "decode_step")}
+    groups["between steps"] = [
+        ("between steps", a[2], b[1], None)
+        for a, b in zip(groups["step"], groups["step"][1:])]
     return ", ".join(
         f"{name} {(t1 - t0) * 1e3:.1f} ms at {t0 - lo:.1f} s"
-        for name, t0, t1, _ in (max(group, key=lambda s: s[2] - s[1])
-                                for group in (steps, calls, between) if group))
+        for name, group in groups.items() if group
+        for _, t0, t1, _ in [max(group, key=lambda s: s[2] - s[1])])
 
 
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
@@ -315,6 +317,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     for r in obs["requests"]:
         del r["logits"]
     ended = [r for r in obs["requests"] if r["ended"]]
+    shape = stamps.ttft_shape(obs["requests"], obs["window"])
     failed = sum(r["failed"] for r in ended)
     obs.update(
         end_to_end={"setup_s": obs["setup_s"],
@@ -331,7 +334,13 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
               f"{len(obs['requests']) - len(ended)}), failed {failed}; "
               f"compilations inside the window {obs['compiles_in_window']}; "
               f"allocator peak_bytes_in_use {obs['memory_peak_bytes']}; "
-              f"engine calls {obs['calls']}; reference scored "
+              f"engine calls {obs['calls']}; time to first token over the "
+              f"{shape['count']} requests submitted and first served inside "
+              f"the window: mean {shape['mean_ms']:.1f}, median "
+              f"{shape['median_ms']:.1f}, 90th {shape['p90_ms']:.1f}, longest "
+              f"{shape['longest_ms']:.1f} ms (the former ttft_p90_ms, over "
+              f"the {shape['former_count']} first served inside it: "
+              f"{shape['former_p90_ms']:.1f}); reference scored "
               f"{scored['tokens']} tokens of {len(chosen)} requests in "
               f"{now() - t0:.1f} s"
               + (f" WITH THE CONTROL {control} IN THE PROGRAM'S PLACE"

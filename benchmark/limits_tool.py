@@ -14,7 +14,18 @@ only sleeps 10 ms at a time says how late it woke at worst: if an engine
 call stands still and the thread does not, the host was not what stood
 still. ``--dump`` keeps each seed's stamps, from the window's opening, for
 a look at other window lengths and at the phase in which a window closes.
-The benchmark's own runs never run this.
+
+    python3 benchmark/limits_tool.py --replay <dir> [<dir> ...]
+
+needs no chip: it reads the dumps of each directory again and prints, for
+each window, what ``readers/stamps.py`` makes of time to first token (the
+count, mean, median, 90th percentile and longest of the sample that
+``ttft_mean_ms`` is taken over, and the former ``ttft_p90_ms`` over its own
+sample), and for each directory the spread of each of them over its
+windows: the distance between the quartiles over the median, as a bound is
+set from it. ``recorded/pr27`` keeps the twelve windows the bound of
+``ttft_mean_ms`` was looked at on, without their token stamps. The
+benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -23,11 +34,13 @@ import argparse
 import gc
 import json
 import os
+import statistics
 import sys
 import threading
 import time
 
 import run
+from readers import stamps
 
 
 def ticker(late: list, stop: threading.Event):
@@ -37,15 +50,66 @@ def ticker(late: list, stop: threading.Event):
         late.append((time.perf_counter() - t - 0.01, t))
 
 
+def dumped(requests, window) -> dict:
+    """What ``--dump`` keeps of one window: the request stamps, counted
+    from its opening."""
+    lo, hi = window
+    return {"seconds": hi - lo, "requests": [
+        {k: (r[k] - lo if r[k] is not None else None)
+         for k in ("submit_t", "admit_t", "first_token_t", "done_t")}
+        | {"token_t": [t - lo for t in r["token_t"]], "failed": r["failed"]}
+        for r in requests]}
+
+
+def spread(values) -> float:
+    """The distance between the quartiles over the median."""
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    return (q3 - q1) / statistics.median(values)
+
+
+def replay(directory: str) -> dict:
+    """Print one line for each dumped window of ``directory`` and one for
+    the directory; returns the latter."""
+    shapes = []
+    for name in sorted(os.listdir(directory)):
+        if not name.endswith(".json"):
+            continue
+        with open(os.path.join(directory, name)) as f:
+            dump = json.load(f)
+        shapes.append(stamps.ttft_shape(dump["requests"],
+                                        (0.0, dump["seconds"])))
+        print(json.dumps({"window": name[:-len(".json")], **shapes[-1]}),
+              flush=True)
+    if len(shapes) < 2:
+        raise SystemExit(f"{directory}: {len(shapes)} dumped window(s), and "
+                         f"a spread needs two")
+    over = {"directory": directory, "windows": len(shapes)}
+    for key in ("mean_ms", "median_ms", "p90_ms", "longest_ms",
+                "former_p90_ms"):
+        column = [s[key] for s in shapes]
+        over[key] = {"median": statistics.median(column),
+                     "least": min(column), "most": max(column),
+                     "spread": spread(column)}
+    print(json.dumps(over), flush=True)
+    return over
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--replay", nargs="+", metavar="DIR", default=None)
+    ap.add_argument("--workload")
+    ap.add_argument("--seeds")
     ap.add_argument("--seconds", type=float, default=40.0)
     ap.add_argument("--dump", default=None)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--root", default=run.ROOT)
     args = ap.parse_args(argv)
+    if args.replay:
+        for directory in args.replay:
+            replay(directory)
+        return 0
+    if not (args.workload and args.seeds):
+        ap.error("--workload and --seeds are needed, or --replay")
     cell = run.resolve(args.root, args.workload)
     device = run.find_device(cell.chips, args.rehearse)
 
@@ -87,13 +151,7 @@ def main(argv=None) -> int:
             os.makedirs(args.dump, exist_ok=True)
             with open(os.path.join(args.dump, f"{cell.name}.{seed}.json"),
                       "w") as f:
-                json.dump({"seconds": args.seconds, "requests": [
-                    {k: (r[k] - lo if r[k] is not None else None)
-                     for k in ("submit_t", "admit_t", "first_token_t",
-                               "done_t")}
-                    | {"token_t": [t - lo for t in r["token_t"]],
-                       "failed": r["failed"]}
-                    for r in obs["requests"]]}, f)
+                json.dump(dumped(obs["requests"], obs["window"]), f)
         for r in obs["requests"]:
             del r["logits"]
         print(json.dumps({

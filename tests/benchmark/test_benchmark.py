@@ -7,9 +7,15 @@ from shapes, the peaks table, the refusals, the names in BENCHMARK.json, and
 a rehearsal of one whole run through ``run.py`` from a temporary root (which
 also shows that a cell is added with files and entries alone), once honest,
 once with a token altered where it is produced, and once with the control,
-the reference in int8, in the program's place.
+the reference in int8, in the program's place. The first cell's schedule is
+replayed with no engine (``_replay``: counts and arithmetic, no time is
+measured), to hold ``ttft_mean_ms`` to what it was chosen for: it reads a
+uniform slowdown as it is, a slower ramp not at all and one stalled call by
+a fraction of its bound, where the nearest-rank 90th percentile it replaced
+moved by nothing or by more than its bound.
 """
 
+import collections
 import json
 import os
 import re
@@ -28,6 +34,7 @@ for _p in (ROOT, BENCH):
         sys.path.insert(0, _p)
 
 import generator  # noqa: E402
+import limits_tool  # noqa: E402
 import run  # noqa: E402
 from readers import device_trace, spans, stamps, work  # noqa: E402
 
@@ -79,7 +86,7 @@ def tiny_root(tmp_path_factory):
 TINY_LIMIT = 1e-4
 
 
-def _rehearse(root, capsys, seed=7, trace=0, seconds=1.5, extra=()):
+def _rehearse(root, capsys, seed=7, trace=0, seconds=2.5, extra=()):
     assert run.main(["--workload", CELL, "--seed", str(seed), "--seconds",
                      str(seconds), "--trace", str(trace), "--rehearse",
                      *extra], root=root) == 0
@@ -152,18 +159,238 @@ def test_window_and_percentile_arithmetic_on_hand_made_stamps():
     assert stamps.tokens_per_s(stalled, window) == pytest.approx(8.0)
     assert stamps.percentile(stamps.gaps_ms(stalled, window), 0.99) \
         == pytest.approx(2100.0)
-    # first tokens outside the window do not count; a failure is the worst
+    # first tokens outside the window do not count; a failure is the worst;
+    # a wait that began before the window opened (in the ramp) does not
+    # count either, be it served or failed inside the window
     reqs = [_request([11.0], submit=10.5), _request([25.0], submit=12.0),
-            _request([], submit=12.0, failed=True, done=13.0)]
+            _request([], submit=12.0, failed=True, done=13.0),
+            _request([10.5], submit=4.0, admit=9.9),
+            _request([], submit=9.0, failed=True, done=11.0)]
     assert sorted(stamps.ttft_ms(reqs, window)) == pytest.approx(
         [500.0, 10000.0])
+    assert sorted(stamps.ttft_ms(reqs, window, ramp_too=True)) \
+        == pytest.approx([500.0, 6500.0, 10000.0, 10000.0])
+    shape = stamps.ttft_shape(reqs, window)
+    assert shape == pytest.approx({
+        "count": 2, "mean_ms": 5250.0, "median_ms": 500.0,
+        "p90_ms": 10000.0, "longest_ms": 10000.0, "former_count": 4,
+        "former_p90_ms": 10000.0})
+    assert stamps.end_to_end(steady + reqs, window)["ttft_mean_ms"] \
+        == pytest.approx(5250.0)
     obs = {"window": window, "requests": [
         _request([12.0], submit=10.0, admit=11.0),
         _request([13.0], submit=10.0, admit=12.5),
+        _request([12.0], submit=4.0, admit=11.0),
+        _request([12.0], submit=9.9, admit=11.0),
         _request([30.0], submit=10.0, admit=29.0)]}
     spec = {"args": {"quantity": "queue_wait_p50"}}
     assert stamps.read(spec, obs) == pytest.approx(1000.0)
     assert stamps.read(spec, dict(obs, requests=[])) is None
+    assert stamps.read(spec, dict(obs, requests=obs["requests"][2:])) is None
+
+
+def test_with_no_request_of_the_windows_own_the_ttft_reader_raises():
+    window = (10.0, 20.0)
+    reqs = [_request([10.5], submit=4.0), _request([25.0], submit=12.0),
+            _request([12.5], submit=9.0)]
+    with pytest.raises(ValueError, match="of 3 requests, 1 were submitted "
+                                         "inside it and 2 got their first"):
+        stamps.ttft_shape(reqs, window)
+    with pytest.raises(ValueError, match="no request was submitted and"):
+        stamps.end_to_end(reqs, window)
+
+
+# the first cell's schedule, as PERF.md, section 6 (PR 27), replays it: the
+# chip's median prefill call, decode step and scheduler tick (ledger, PR 26),
+# and a moment for the driver's own loop between a tick and the next look at
+# the clock: a reply's follow-up is submitted before that look, so the one
+# that follows the ramp's last reply is submitted before the window opens
+CALL_S, STEP_S, TICK_S, LOOP_S = 0.7526, 0.0230, 0.00011, 0.00002
+XL_MIX = dict(callers=8, ramp_requests=4, prompt_tokens=[33, 64],
+              answer_tokens=[64, 128])
+
+
+def _replay(slow=0.0, ramp_extra_s=0.0, stall_at_s=None, stall_s=0.080,
+            seconds=40.0, mix=XL_MIX, slots=4):
+    """``drivers/serve.py:drive`` over ``ServeScheduler.step()`` with a
+    clock that is counted, not read: the callers submit at once, a tick
+    admits into every free slot with one prefill call (``CALL_S``, first
+    tokens at its end), then one decode step gives every running request a
+    token (``STEP_S``); a reply is followed by its caller's next request;
+    the window opens at the next look at the clock once ``ramp_requests``
+    are done. The answers' order is
+    the generator's own, so this is every seed's schedule. ``slow``: every
+    time longer by that share. ``ramp_extra_s``: the first call, which on
+    the chip is the programs' first execution, takes that much longer.
+    ``stall_at_s``: the first engine call to begin that many seconds into
+    the window stands still for ``stall_s``. Returns the requests as
+    ``readers/stamps.py`` takes them, and the window."""
+    call_s, step_s, tick_s = (x * (1 + slow) for x in (CALL_S, STEP_S, TICK_S))
+    stream = generator.requests(mix, 1, 50257)
+    sent, queue, running = [], collections.deque(), [None] * slots
+    t, opened, completed, stall_due = 0.0, None, 0, stall_at_s is not None
+    extra = ramp_extra_s
+
+    def submit():
+        sent.append(dict(_request([], submit=t), answer=next(stream)[1]))
+        queue.append(sent[-1])
+
+    def stalled():
+        nonlocal stall_due
+        if stall_due and opened is not None and t >= opened + stall_at_s:
+            stall_due = False
+            return stall_s
+        return 0.0
+
+    for _ in range(mix["callers"]):
+        submit()
+    while True:
+        t += LOOP_S * (1 + slow)
+        if opened is None and completed >= mix["ramp_requests"]:
+            opened = t
+        if opened is not None and t >= opened + seconds:
+            return sent, (opened, opened + seconds)
+        batch = []
+        for slot, held in enumerate(running):
+            if held is None and queue:
+                running[slot] = queue.popleft()
+                running[slot]["admit_t"] = t
+                batch.append(running[slot])
+        if batch:
+            t += call_s + extra + stalled()
+            extra = 0.0
+            for r in batch:
+                r["first_token_t"] = t
+                r["token_t"].append(t)
+        t += step_s + stalled()
+        done = 0
+        for slot, r in enumerate(running):
+            r["token_t"].append(t)
+            if len(r["token_t"]) >= r["answer"]:
+                r["done_t"], running[slot] = t, None
+                done += 1
+        t += tick_s
+        for _ in range(done):
+            completed += 1
+            submit()
+
+
+def _moved(shape, base, key):
+    return shape[key] / base[key] - 1.0
+
+
+@pytest.fixture(scope="module")
+def undisturbed():
+    return stamps.ttft_shape(*_replay())
+
+
+def test_the_replayed_schedule_is_the_one_the_chip_ran(undisturbed):
+    """What the ledger read (PR 24, PR 26): the window opens 5.56 s after
+    the first submit, 2 950 tokens and 30 first tokens fall in 40 s, the
+    former ``ttft_p90_ms`` 6 512-6 520 ms, ``itl_p99_ms`` 777.2 ms."""
+    requests, window = _replay()
+    assert window[0] == pytest.approx(5.5547, abs=1e-4)
+    assert stamps.tokens_per_s(requests, window) == pytest.approx(73.75)
+    assert undisturbed["former_count"] == 30 and undisturbed["count"] == 25
+    assert 6510 < undisturbed["former_p90_ms"] < 6522
+    # my chip run, PR 27, the runs with no call over 760 ms: 6 001.6-6 025.4
+    assert undisturbed["mean_ms"] == pytest.approx(6009.41, abs=0.01)
+    assert 775 < stamps.end_to_end(requests, window)["itl_p99_ms"] < 778
+    # the comb: the waits differ by whole decode steps, and the former
+    # reading's neighbours lie 23 ms below it and 70 and 93 ms above
+    top = sorted(stamps.ttft_ms(requests, window, ramp_too=True))[-5:]
+    assert [round(b - a) for a, b in zip(top, top[1:])] == [23, 69, 23, 0]
+    # five of the 30 waits began before the window opened: the eighth
+    # caller's is the whole ramp, and the last was submitted on the ramp's
+    # last reply, a moment before the look at the clock that opened it
+    ramp = [r for r in requests if r["submit_t"] < window[0]
+            and r["first_token_t"] >= window[0]]
+    assert len(ramp) == 5 and ramp[0]["submit_t"] == 0.0
+    assert ramp[0]["admit_t"] == pytest.approx(window[0])
+    assert ramp[-1]["submit_t"] == pytest.approx(window[0] - LOOP_S)
+
+
+def test_the_windows_recorded_on_the_chip_read_as_perf_md_says(capsys):
+    """``benchmark/recorded/pr27``: twelve windows of the first cell on the
+    chip (PERF.md, section 6, PR 27), each within 0.7 % of the replay."""
+    over = limits_tool.replay(os.path.join(BENCH, "recorded", "pr27"))
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert over["windows"] == 12 and len(lines) == 13
+    assert all(x["count"] == 25 and x["former_count"] == 30
+               for x in lines[:12])
+    assert 5996 < over["mean_ms"]["least"] and over["mean_ms"]["most"] < 6051
+    assert over["mean_ms"]["spread"] == pytest.approx(0.00249, abs=1e-5)
+    assert over["former_p90_ms"]["spread"] == pytest.approx(0.00298, abs=1e-5)
+
+
+@pytest.mark.parametrize("slow", [0.005, 0.01, 0.02])
+def test_ttft_mean_reads_a_uniform_slowdown_as_it_is(undisturbed, slow):
+    shape = stamps.ttft_shape(*_replay(slow=slow))
+    assert shape["count"] == undisturbed["count"]
+    assert _moved(shape, undisturbed, "mean_ms") == pytest.approx(
+        slow, abs=0.001)
+
+
+@pytest.mark.parametrize("ramp_extra_s", [0.1, 0.2, 0.4])
+def test_ttft_mean_does_not_move_with_the_ramp(undisturbed, ramp_extra_s):
+    requests, window = _replay(ramp_extra_s=ramp_extra_s)
+    assert window[0] == pytest.approx(5.5547 + ramp_extra_s, abs=1e-4)
+    shape = stamps.ttft_shape(requests, window)
+    assert shape["count"] == undisturbed["count"]
+    assert shape["mean_ms"] == pytest.approx(undisturbed["mean_ms"],
+                                             rel=1e-9)
+    # the former statistic held the eighth caller's wait, the whole ramp:
+    # past 0.21 s it crossed the rank read and the reading stepped up
+    assert _moved(shape, undisturbed, "former_p90_ms") == pytest.approx(
+        0.0107 if ramp_extra_s > 0.21 else 0.0, abs=0.0002)
+
+
+STALL_SECONDS = [2.5, 7.5, 12.5, 17.5, 22.5, 27.5, 32.5, 37.5]
+
+
+@pytest.mark.parametrize("at_s", STALL_SECONDS)
+def test_one_stalled_call_moves_ttft_mean_by_a_fraction_of_its_bound(
+        undisturbed, at_s):
+    shape = stamps.ttft_shape(*_replay(stall_at_s=at_s))
+    assert shape["count"] == undisturbed["count"]
+    assert 0.0 <= _moved(shape, undisturbed, "mean_ms") < 0.0035
+    # the former statistic, one order statistic on a comb: not at all, or
+    # by a step about as wide as its bound of 1 %
+    former = _moved(shape, undisturbed, "former_p90_ms")
+    assert former == pytest.approx(0.0, abs=1e-9) or former > 0.008
+
+
+def test_one_stalled_call_moved_the_former_ttft_p90_by_more_than_its_bound(
+        undisturbed):
+    """Why ``ttft_p90_ms`` went: one 80 ms stall, 0.2 % of the window."""
+    moves = [_moved(stamps.ttft_shape(*_replay(stall_at_s=at_s)),
+                    undisturbed, "former_p90_ms") for at_s in STALL_SECONDS]
+    assert max(moves) > 0.01 and min(moves) == pytest.approx(0.0, abs=1e-9)
+
+
+def test_replaying_dumped_windows_prints_both_statistics(tmp_path, capsys):
+    """``limits_tool.py --replay`` over dumps as ``--dump`` writes them:
+    stamps counted from the window's opening."""
+    for i, at_s in enumerate([None, 7.5, 27.5]):
+        dump = limits_tool.dumped(*_replay(stall_at_s=at_s))
+        assert dump["seconds"] == pytest.approx(40.0)
+        (tmp_path / f"cell.{i}.json").write_text(json.dumps(dump))
+    assert limits_tool.main(["--replay", str(tmp_path)]) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert [x["window"] for x in lines[:3]] == ["cell.0", "cell.1", "cell.2"]
+    assert all(x["count"] == 25 and x["former_count"] == 30
+               for x in lines[:3])
+    assert lines[0]["mean_ms"] == pytest.approx(6009.41, abs=0.01)
+    over = lines[3]
+    assert over["windows"] == 3 and over["directory"] == str(tmp_path)
+    assert over["mean_ms"]["spread"] == pytest.approx(
+        (lines[1]["mean_ms"] - lines[0]["mean_ms"]) / lines[1]["mean_ms"])
+    assert over["mean_ms"]["spread"] < 0.0025 \
+        < 0.01 < over["former_p90_ms"]["spread"]
+    (tmp_path / "one").mkdir()
+    shutil.copy(tmp_path / "cell.0.json", tmp_path / "one")
+    with pytest.raises(SystemExit, match="a spread needs two"):
+        limits_tool.main(["--replay", str(tmp_path / "one")])
 
 
 def test_span_self_time_and_median():
@@ -348,12 +575,21 @@ def test_rehearsal_of_a_whole_run_from_a_root_with_a_new_cell(tiny_root,
     assert list(line)[-1] == "checks" and line["rehearsal"] is True
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 4
-    assert set(line["metrics"]) == {"tokens_per_s", "ttft_p90_ms",
+    assert set(line["metrics"]) == {"tokens_per_s", "ttft_mean_ms",
                                     "itl_p99_ms", "setup_s"}
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["device"]["platform"] == "cpu"
     assert line["checks"]["compiles_in_window"] == {"value": 0, "limit": 0}
     assert "compilations inside the window 0" in out.splitlines()[-2]
+    shape = re.search(r"time to first token over the (\d+) requests "
+                      r"submitted and first served inside the window: mean "
+                      r"([\d.]+), median ([\d.]+), 90th ([\d.]+), longest "
+                      r"([\d.]+) ms", out.splitlines()[-2])
+    assert int(shape.group(1)) >= 1
+    mean, median, p90, longest = map(float, shape.groups()[1:])
+    assert mean == pytest.approx(line["metrics"]["ttft_mean_ms"]["value"],
+                                 abs=0.06)
+    assert median <= p90 <= longest and mean <= longest
     assert err.strip().splitlines()[-1].startswith("compared ")
 
 
