@@ -205,7 +205,11 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
          + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt))
     # phase markers: trace-safe jax.named_scope only (scope names ride
     # the MLIR loc(...) metadata — monitor/costs.py attributes the cost
-    # ledger per phase on them; no traced effect, APX001-quiet)
+    # ledger per phase on them; no traced effect, APX001-quiet). Inside
+    # "attention", "kv_write" (serve/kv_cache.py) and "attn_proj" name
+    # the cache append and the output projection for a device trace's
+    # reader; the ledger knows neither name, so both stay "attention"
+    # there
     for i in range(c.n_layer):
         blk = p[f"h_{i}"]
         with jax.named_scope("ln_qkv"):
@@ -237,9 +241,11 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
                              else cache.k_scale[i]),
                     v_scale=(None if kv_quant is None
                              else cache.v_scale[i]))
-            o = o.reshape(-1, c.n_embd)
-            x = x + (o.astype(dt) @ blk["attn_out"]["kernel"].astype(dt)
-                     + blk["attn_out"]["bias"].astype(dt))
+            with jax.named_scope("attn_proj"):
+                o = o.reshape(-1, c.n_embd)
+                x = x + (o.astype(dt)
+                         @ blk["attn_out"]["kernel"].astype(dt)
+                         + blk["attn_out"]["bias"].astype(dt))
         with jax.named_scope("mlp"):
             y = _affine_layer_norm(x, blk["ln_2"]["weight"],
                                    blk["ln_2"]["bias"])
@@ -375,14 +381,16 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
                 with jax.named_scope("collective"):
                     o_full = jax.lax.all_gather(o, axis_name, axis=1,
                                                 tiled=True)
-                o_full = o_full.reshape(-1, c.n_embd)
-                x = x + (o_full.astype(dt)
-                         @ blk["attn_out"]["kernel"].astype(dt) + out_b)
+                with jax.named_scope("attn_proj"):
+                    o_full = o_full.reshape(-1, c.n_embd)
+                    x = x + (o_full.astype(dt)
+                             @ blk["attn_out"]["kernel"].astype(dt) + out_b)
             else:
                 # row-parallel output projection: this rank's heads hit
                 # its rows of the kernel — a PARTIAL [num_slots, e] sum
-                attn_part = (o.reshape(-1, h_loc * d).astype(dt)
-                             @ blk["attn_out"]["kernel"].astype(dt))
+                with jax.named_scope("attn_proj"):
+                    attn_part = (o.reshape(-1, h_loc * d).astype(dt)
+                                 @ blk["attn_out"]["kernel"].astype(dt))
         with jax.named_scope("mlp"):
             if sync == "exact":
                 y = _affine_layer_norm(x, blk["ln_2"]["weight"],

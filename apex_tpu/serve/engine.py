@@ -112,6 +112,7 @@ from jax import shard_map
 # otherwise make engine builds publish to a FRESH event bus that
 # collection-time subscribers never see
 from apex_tpu.utils.logging import publish_event
+from apex_tpu.utils.prof import annotate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -791,6 +792,20 @@ class Engine:
         return plan["new_pages"]
 
     # ------------------------------------------------------------- calls
+    def _occupancy(self, act_np: np.ndarray) -> Dict[str, int]:
+        """What a decode step's span carries: slots fed and held, tokens
+        resident before the step, and (paged) pool pages out of the free
+        list. Host ints the engine already keeps; nothing is read from
+        the device."""
+        attrs = {"active": int(act_np.sum()),
+                 "slots": self.config.num_slots,
+                 "resident": self.resident_tokens}
+        if self._paged:
+            attrs["pages_in_use"] = (self.pool.capacity
+                                     - self.pool.free_count)
+            attrs["pages"] = self.pool.capacity
+        return attrs
+
     def prefill(self, prompts: Dict[int, Sequence[int]], *,
                 budgets: Optional[Dict[int, int]] = None,
                 cacheable: Optional[Dict[int, int]] = None):
@@ -829,10 +844,70 @@ class Engine:
                     f"prompt of {len(toks)} tokens exceeds max_len="
                     f"{self.max_len}")
 
-        starts = np.zeros((b,), np.int32)
+        with annotate("apex.prefill", admitted=len(prompts), slots=b):
+            with annotate("apex.prefill.plan"):
+                starts, tails, new_pages = self._plan_prefill(prompts,
+                                                              budgets)
+            bucket = pow2_ceil(max(len(t) for t in tails.values()))
+            with annotate("apex.prefill.launch", bucket=bucket, slots=b,
+                          real_positions=sum(len(t) for t in tails.values()),
+                          hit_tokens=int(starts.sum()), new_pages=new_pages):
+                tokens = np.zeros((b, bucket), np.int32)
+                admit = np.zeros((b,), bool)
+                lens = np.zeros((b,), np.int32)
+                for slot, toks in tails.items():
+                    tokens[slot, :len(toks)] = np.asarray(toks, np.int32)
+                    admit[slot] = True
+                    lens[slot] = len(toks)
+
+                fn = self._prefill_aot.get(bucket)
+                if fn is None:
+                    fn = self._prefill_jits.setdefault(
+                        bucket, self._make_prefill(bucket))
+                args = (self._weights, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(admit), jnp.asarray(starts),
+                        jnp.asarray(lens), self.rng)
+                if self._policy is not None:
+                    args += (self._policy_args(),)
+                self.cache, first, last_logits, all_logits, self.rng = \
+                    fn(*args)
+            self.prefill_calls += 1
+            self.prefill_requests += len(prompts)
+            self.prefill_scanned_tokens += int(bucket)
+            with annotate("apex.prefill.fetch"):
+                first_np = np.asarray(first)
+            self.last_tokens = np.where(admit, first_np, self.last_tokens)
+            full_lens = starts + lens
+            self._host_lengths = np.where(admit, full_lens,
+                                          self._host_lengths)
+            if self._paged and self.prefix is not None:
+                ps = int(self.config.page_size)
+                with annotate("apex.prefill.index"):
+                    for slot, toks in prompts.items():
+                        upto = (cacheable or {}).get(slot, len(toks))
+                        row = self._slot_pages[slot]
+                        for i, h in enumerate(
+                                paging.chunk_hashes(list(toks[:upto]), ps)):
+                            self.prefix.insert(h, row[i], self.pool)
+            if self._paged and self._kv_quant is not None:
+                # quantized-capacity provenance: these pages now hold
+                # codec bytes + scales, not fp32 rows — counted so a bench
+                # capture can prove its resident_tokens_per_hbm_byte came
+                # from a quantized pool, not a mislabeled fp32 one
+                publish_event("serve_kv_quantized_pages", pages=new_pages,
+                              codec=self._kv_quant)
+            return first_np, last_logits, all_logits
+
+    def _plan_prefill(self, prompts: Dict[int, Sequence[int]],
+                      budgets: Optional[Dict[int, int]]):
+        """The host half of an admission, before anything is launched:
+        release, plan, evict, allocate, copy-on-write, and the page-table
+        upload. Returns ``(starts [num_slots], {slot: tail to scan},
+        fresh pages taken)`` and leaves ``last_prefill_stats``."""
+        starts = np.zeros((self.config.num_slots,), np.int32)
         tails: Dict[int, Sequence[int]] = dict(prompts)
         self.last_prefill_stats = {}
-        quant_pages = 0
+        new_pages = 0
         if self._paged:
             ps = int(self.config.page_size)
             for slot in prompts:
@@ -866,7 +941,7 @@ class Engine:
                         plan["new_pages"] - self.pool.free_count,
                         protect=protect_all)
                 fresh = self.pool.alloc(plan["new_pages"])
-                quant_pages += len(fresh)
+                new_pages += len(fresh)
                 for pg in shared:
                     self.pool.retain(pg)
                 if plan["cow_src"] is not None:
@@ -896,49 +971,7 @@ class Engine:
             for slot, toks in prompts.items():
                 self.last_prefill_stats[slot] = {
                     "hit_tokens": 0, "hit_pages": 0, "scanned": len(toks)}
-
-        bucket = pow2_ceil(max(len(t) for t in tails.values()))
-        tokens = np.zeros((b, bucket), np.int32)
-        admit = np.zeros((b,), bool)
-        lens = np.zeros((b,), np.int32)
-        for slot, toks in tails.items():
-            tokens[slot, :len(toks)] = np.asarray(toks, np.int32)
-            admit[slot] = True
-            lens[slot] = len(toks)
-
-        fn = self._prefill_aot.get(bucket)
-        if fn is None:
-            fn = self._prefill_jits.setdefault(
-                bucket, self._make_prefill(bucket))
-        args = (self._weights, self.cache, jnp.asarray(tokens),
-                jnp.asarray(admit), jnp.asarray(starts),
-                jnp.asarray(lens), self.rng)
-        if self._policy is not None:
-            args += (self._policy_args(),)
-        self.cache, first, last_logits, all_logits, self.rng = fn(*args)
-        self.prefill_calls += 1
-        self.prefill_requests += len(prompts)
-        self.prefill_scanned_tokens += int(bucket)
-        first_np = np.asarray(first)
-        self.last_tokens = np.where(admit, first_np, self.last_tokens)
-        full_lens = starts + lens
-        self._host_lengths = np.where(admit, full_lens,
-                                      self._host_lengths)
-        if self._paged and self.prefix is not None:
-            for slot, toks in prompts.items():
-                upto = (cacheable or {}).get(slot, len(toks))
-                row = self._slot_pages[slot]
-                for i, h in enumerate(
-                        paging.chunk_hashes(list(toks[:upto]), ps)):
-                    self.prefix.insert(h, row[i], self.pool)
-        if self._paged and self._kv_quant is not None:
-            # quantized-capacity provenance: these pages now hold codec
-            # bytes + scales, not fp32 rows — counted so a bench capture
-            # can prove its resident_tokens_per_hbm_byte came from a
-            # quantized pool, not a mislabeled fp32 one
-            publish_event("serve_kv_quantized_pages", pages=quant_pages,
-                          codec=self._kv_quant)
-        return first_np, last_logits, all_logits
+        return starts, tails, new_pages
 
     def decode_step(self, last_tokens, active):
         """One decode step for every slot: feed each active slot its last
@@ -946,30 +979,33 @@ class Engine:
         ``active`` ``[num_slots]`` bool. Returns ``(next_tokens
         np.ndarray, logits [num_slots, vocab] device array)``."""
         act_np = np.asarray(active, bool)
-        full = act_np & (self._host_lengths >= self._slot_capacity)
-        if full.any():
-            # the cache write would silently clip (slot cache) or land in
-            # an unreserved page (paged) and corrupt the newest K/V row —
-            # refuse instead; the scheduler terminates at context-full /
-            # budget before ever reaching this
-            raise ValueError(
-                f"slot(s) {np.flatnonzero(full).tolist()} are at their "
-                f"admitted capacity "
-                f"{self._slot_capacity[full].tolist()} (max_len="
-                f"{self.max_len}); evict or raise max_len before "
-                f"decoding further")
-        fn = self._decode_aot or self._decode
-        lt = jnp.asarray(np.asarray(last_tokens, np.int32))
-        act = jnp.asarray(act_np)
-        args = (self._weights, self.cache, lt, act, self.rng)
-        if self._policy is not None:
-            args += (self._policy_args(),)
-        next_tokens, logits, self.cache, self.rng = fn(*args)
-        self.decode_calls += 1
-        next_np = np.asarray(next_tokens)
-        self.last_tokens = np.where(act_np, next_np, self.last_tokens)
-        self._host_lengths = self._host_lengths + act_np
-        return next_np, logits
+        with annotate("apex.decode_step", **self._occupancy(act_np)):
+            full = act_np & (self._host_lengths >= self._slot_capacity)
+            if full.any():
+                # the cache write would silently clip (slot cache) or land
+                # in an unreserved page (paged) and corrupt the newest K/V
+                # row — refuse instead; the scheduler terminates at
+                # context-full / budget before ever reaching this
+                raise ValueError(
+                    f"slot(s) {np.flatnonzero(full).tolist()} are at their "
+                    f"admitted capacity "
+                    f"{self._slot_capacity[full].tolist()} (max_len="
+                    f"{self.max_len}); evict or raise max_len before "
+                    f"decoding further")
+            with annotate("apex.decode_step.launch"):
+                fn = self._decode_aot or self._decode
+                lt = jnp.asarray(np.asarray(last_tokens, np.int32))
+                act = jnp.asarray(act_np)
+                args = (self._weights, self.cache, lt, act, self.rng)
+                if self._policy is not None:
+                    args += (self._policy_args(),)
+                next_tokens, logits, self.cache, self.rng = fn(*args)
+            self.decode_calls += 1
+            with annotate("apex.decode_step.fetch"):
+                next_np = np.asarray(next_tokens)
+            self.last_tokens = np.where(act_np, next_np, self.last_tokens)
+            self._host_lengths = self._host_lengths + act_np
+            return next_np, logits
 
     # ------------------------------------------------ speculative decode
     @property
@@ -1026,42 +1062,47 @@ class Engine:
                 "spec_decode_step needs EngineConfig(spec_draft_len >= "
                 "1); use decode_step on the one-token engine")
         act_np = np.asarray(active, bool)
-        dl_np = np.asarray(draft_lens, np.int64)
-        if ((dl_np < 0) | (dl_np > self._spec_k)).any():
-            raise ValueError(
-                f"draft_lens {dl_np.tolist()} must lie in "
-                f"[0, spec_draft_len={self._spec_k}]")
-        # capacity backstop, mirroring decode_step's refusal: the verify
-        # scan writes positions length..length+draft_len, and commits up
-        # to draft_len + 1 tokens — an overrun would clip (slot cache)
-        # or land in an unreserved page (paged) and corrupt K/V rows
-        need = self._host_lengths + np.where(act_np, dl_np + 1, 0)
-        over = act_np & (need > self._slot_capacity)
-        if over.any():
-            raise ValueError(
-                f"slot(s) {np.flatnonzero(over).tolist()} would overrun "
-                f"their admitted capacity "
-                f"{self._slot_capacity[over].tolist()} at draft_lens="
-                f"{dl_np[over].tolist()} (max_len={self.max_len}); clamp "
-                f"the draft or evict before speculating further")
-        fn = self._verify_aot or self._verify
-        b = self.config.num_slots
-        args = (self._weights, self.cache,
-                jnp.asarray(np.asarray(last_tokens, np.int32)),
-                jnp.asarray(np.asarray(drafts, np.int32).reshape(
-                    b, self._spec_k)),
-                jnp.asarray(dl_np.astype(np.int32)), jnp.asarray(act_np),
-                self.rng)
-        if self._policy is not None:
-            args += (self._policy_args(),)
-        committed, counts, next_tokens, self.cache, self.rng = fn(*args)
-        self.decode_calls += 1
-        committed_np = np.asarray(committed)
-        counts_np = np.asarray(counts)
-        self.last_tokens = np.where(act_np, np.asarray(next_tokens),
-                                    self.last_tokens)
-        self._host_lengths = self._host_lengths + counts_np
-        return committed_np, counts_np
+        with annotate("apex.spec_decode_step", **self._occupancy(act_np)):
+            dl_np = np.asarray(draft_lens, np.int64)
+            if ((dl_np < 0) | (dl_np > self._spec_k)).any():
+                raise ValueError(
+                    f"draft_lens {dl_np.tolist()} must lie in "
+                    f"[0, spec_draft_len={self._spec_k}]")
+            # capacity backstop, mirroring decode_step's refusal: the
+            # verify scan writes positions length..length+draft_len, and
+            # commits up to draft_len + 1 tokens — an overrun would clip
+            # (slot cache) or land in an unreserved page (paged) and
+            # corrupt K/V rows
+            need = self._host_lengths + np.where(act_np, dl_np + 1, 0)
+            over = act_np & (need > self._slot_capacity)
+            if over.any():
+                raise ValueError(
+                    f"slot(s) {np.flatnonzero(over).tolist()} would overrun "
+                    f"their admitted capacity "
+                    f"{self._slot_capacity[over].tolist()} at draft_lens="
+                    f"{dl_np[over].tolist()} (max_len={self.max_len}); "
+                    f"clamp the draft or evict before speculating further")
+            with annotate("apex.spec_decode_step.launch"):
+                fn = self._verify_aot or self._verify
+                b = self.config.num_slots
+                args = (self._weights, self.cache,
+                        jnp.asarray(np.asarray(last_tokens, np.int32)),
+                        jnp.asarray(np.asarray(drafts, np.int32).reshape(
+                            b, self._spec_k)),
+                        jnp.asarray(dl_np.astype(np.int32)),
+                        jnp.asarray(act_np), self.rng)
+                if self._policy is not None:
+                    args += (self._policy_args(),)
+                committed, counts, next_tokens, self.cache, self.rng = \
+                    fn(*args)
+            self.decode_calls += 1
+            with annotate("apex.spec_decode_step.fetch"):
+                committed_np = np.asarray(committed)
+                counts_np = np.asarray(counts)
+                next_np = np.asarray(next_tokens)
+            self.last_tokens = np.where(act_np, next_np, self.last_tokens)
+            self._host_lengths = self._host_lengths + counts_np
+            return committed_np, counts_np
 
     def evict(self, slots) -> None:
         """Free the given slot indices (mask-shaped op, compiled once);

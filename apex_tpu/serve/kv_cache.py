@@ -93,6 +93,7 @@ def init_cache(n_layer: int, num_slots: int, max_len: int, heads: int,
         v_scale=jnp.zeros(shape[:-1], jnp.float32))
 
 
+@jax.named_scope("kv_write")
 def write_token(cache: KVCache, layer: int, k_tok: jax.Array,
                 v_tok: jax.Array, positions: jax.Array,
                 mask: jax.Array, codec: Optional[str] = None) -> KVCache:
@@ -258,6 +259,7 @@ def init_paged_cache(n_layer: int, num_slots: int, max_len: int,
         v_scale=jnp.zeros(shape[:-1], jnp.float32))
 
 
+@jax.named_scope("kv_write")
 def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
                       v_tok: jax.Array, positions: jax.Array,
                       mask: jax.Array,

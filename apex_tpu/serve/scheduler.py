@@ -75,6 +75,15 @@ span code runs at all, and tracing never touches the device either way:
 the one-compile invariant holds with it on (asserted in tier-1).
 ``flight_recorder=`` arms a crash dump around :meth:`run`;
 ``memory_accountant=`` samples HBM per decode tick.
+
+**Host spans** (:func:`apex_tpu.utils.prof.annotate`, no switch): a tick
+is ``apex.sched.step`` (``queued``), holding ``apex.sched.admit`` (the
+page probes to the batch's last first token, around the engine's
+``apex.prefill``), the engine's ``apex.decode_step``, and
+``apex.sched.accept`` (accept loop, eviction flush, metrics and journal
+tick). Under a profiler session they land on the host plane of the device
+trace, on its clock; with a process tracer installed they nest in its
+span tree; with neither each costs an inactive annotation.
 """
 
 from __future__ import annotations
@@ -92,6 +101,7 @@ from apex_tpu.monitor.export import percentile
 from apex_tpu.serve.engine import Engine
 from apex_tpu.serve.spec import NGramDrafter
 from apex_tpu.utils.logging import publish_event
+from apex_tpu.utils.prof import annotate
 
 # a request in one of these states has reached its exactly-one terminal
 # status; recovery and the drain path must never touch it again
@@ -443,8 +453,14 @@ class ServeScheduler:
         prefill below can never fail allocation mid-batch."""
         # caller holds self._lock (step())
         free = [i for i, r in enumerate(self.slots) if r is None]
-        if not free or not self.queue:
-            return
+        if free and self.queue:
+            with annotate("apex.sched.admit"):
+                self._admit_into(free)
+
+    def _admit_into(self, free: List[int]) -> None:
+        """:meth:`_admit` once there is a free slot and a queued request:
+        the page probes, the batched prefill, the first tokens."""
+        # caller holds self._lock (_admit())
         prior_stall = self._alloc_stall_t0
         batch: Dict[int, Request] = {}
         pending_pages = 0
@@ -888,7 +904,8 @@ class ServeScheduler:
         Returns False when idle (no running or queued work). Holds the
         scheduler lock for the whole tick — a cross-thread submit/abort
         lands between ticks, never mid-tick."""
-        with self._lock:
+        with self._lock, annotate("apex.sched.step",
+                                  queued=len(self.queue)):
             if self._t0 is None:
                 self._t0 = time.perf_counter()
             if self.journal is not None and self.journal.snapshot is None:
@@ -982,25 +999,27 @@ class ServeScheduler:
                 self.memory.tick("serve_decode", step=self.decode_steps)
             publish_event("serve_decode_step", seconds=dt,
                           active=int(active.sum()))
-            if spec_k and self.drafter is not None:
-                self.decode_tokens += self._accept_spec(
-                    committed, counts, draft_lens)
-            else:
-                self.decode_tokens += int(active.sum())
-                for slot, req in enumerate(self.slots):
-                    if req is not None:
-                        self._accept_token(req, int(next_tokens[slot]))
-            self._flush_evictions()
-            # AFTER the accept loop: completions landing on this tick
-            # feed the SLO windows before this tick's evaluate() — a
-            # breach crossed by the final tick's events must publish
-            # before run() exits, and the exit snapshot's burn gauges
-            # must reflect this tick, not the previous one
-            self._metrics_tick(dt, int(active.sum()))
-            if self.journal is not None:
-                # end-of-tick: the state is consistent again — this is
-                # the snapshot a crash in the NEXT tick rolls back to
-                self._journal_tick()
+            with annotate("apex.sched.accept"):
+                if spec_k and self.drafter is not None:
+                    self.decode_tokens += self._accept_spec(
+                        committed, counts, draft_lens)
+                else:
+                    self.decode_tokens += int(active.sum())
+                    for slot, req in enumerate(self.slots):
+                        if req is not None:
+                            self._accept_token(req, int(next_tokens[slot]))
+                self._flush_evictions()
+                # AFTER the accept loop: completions landing on this tick
+                # feed the SLO windows before this tick's evaluate() — a
+                # breach crossed by the final tick's events must publish
+                # before run() exits, and the exit snapshot's burn gauges
+                # must reflect this tick, not the previous one
+                self._metrics_tick(dt, int(active.sum()))
+                if self.journal is not None:
+                    # end-of-tick: the state is consistent again — this
+                    # is the snapshot a crash in the NEXT tick rolls back
+                    # to
+                    self._journal_tick()
             return any(r is not None
                        for r in self.slots) or bool(self.queue)
 

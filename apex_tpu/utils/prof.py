@@ -6,7 +6,7 @@ ad-hoc NVTX ranges (``torch.cuda.nvtx``) and ``cudaProfilerStart`` in tests,
 
 - ``profile(logdir)``: context manager over ``jax.profiler`` producing a
   TensorBoard-loadable device trace (the nsys/nvtx equivalent).
-- ``annotate(name)`` / ``annotate_function``: named trace ranges (the NVTX
+- ``annotate(name, **attrs)``: named trace ranges (the NVTX
   ``range_push/pop`` analog) that show up in the trace viewer.
 - ``StepTimer``: the examples' AverageMeter, with proper device sync.
 """
@@ -48,28 +48,35 @@ def profile(logdir: str = "/tmp/apex_tpu_trace"):
         jax.profiler.stop_trace()
 
 
+# bound on annotate()'s first call, not at import: monitor/__init__ imports
+# telemetry, which imports this module
+_get_tracer = None
+
+
 def annotate(name: str, **attrs):
     """Named range inside a trace (≈ nvtx.range_push/pop).
 
-    Always opens a ``jax.profiler.TraceAnnotation`` (visible in the
-    device-trace viewer). When the process span tracer is enabled
-    (:func:`apex_tpu.monitor.trace.set_tracer`, or
-    ``Telemetry(trace_jsonl=...)``), the range ALSO opens a span in the
-    trace tree with ``attrs`` attached — host annotations and the span
-    timeline stay in lockstep because they are the same call.
+    Always opens a ``jax.profiler.TraceAnnotation`` carrying ``attrs``
+    (visible in the device-trace viewer, on the host plane of the same
+    ``.xplane.pb`` as the device's operations; with no profiler session
+    running it costs about half a microsecond). When the process span
+    tracer is enabled (:func:`apex_tpu.monitor.trace.set_tracer`, or
+    ``Telemetry(trace_jsonl=...)``), the range opens a span in the
+    trace tree instead, with ``attrs`` attached, and that span enters
+    the annotation itself — host annotations and the span timeline stay
+    in lockstep because they are the same call.
+
+    ``attrs`` are values the host already holds (ints, short strings):
+    an annotation takes them when it opens, so an attribute rides the
+    first range that opens after its value is known.
     """
-    from apex_tpu.monitor.trace import get_tracer
-
-    tracer = get_tracer()
+    global _get_tracer
+    if _get_tracer is None:
+        from apex_tpu.monitor.trace import get_tracer as _get_tracer
+    tracer = _get_tracer()
     if tracer.enabled:
-        # the tracer's span ctx enters the TraceAnnotation itself
         return tracer.span(name, **attrs)
-    return jax.profiler.TraceAnnotation(name)
-
-
-def annotate_function(fn, name: Optional[str] = None):
-    """Decorator form (≈ nvtx.annotate)."""
-    return jax.profiler.annotate_function(fn, name=name)
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 class StepTimer:

@@ -22,9 +22,11 @@ serving contract); the one-jit acceptance tests get fresh engines so
 their trace counters stay airtight.
 """
 
+import collections
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -436,6 +438,126 @@ def test_untraced_scheduler_publishes_no_spans(greedy3):
         unsub()
     assert not seen
     assert sched.tracer is None and not sched._req_spans
+
+
+# ------------------------------------- host spans and device scopes
+
+def _spanned_run(eng, tracer=None):
+    """A short mixed run; with ``tracer`` installed as the process
+    tracer for its length. Returns the greedy streams by request."""
+    from apex_tpu.monitor.trace import set_tracer
+
+    prev = set_tracer(tracer) if tracer is not None else None
+    try:
+        sched = ServeScheduler(eng.reset())
+        reqs = _mixed_requests()
+        for r in reqs:
+            sched.submit(r)
+        sched.run()
+    finally:
+        if tracer is not None:
+            set_tracer(prev)
+    return {r.request_id: list(r.generated) for r in reqs}
+
+
+def test_apex_spans_count_the_engine_calls_and_hold_their_children(paged3):
+    """With a process tracer installed the hot path's ``apex.*`` ranges
+    become spans: one ``apex.decode_step`` per decode call and one
+    ``apex.prefill`` per prefill call, each with its children in order
+    and inside it, under the tick that made the call; the occupancy they
+    carry stays inside what the engine has."""
+    from apex_tpu.monitor import Tracer
+
+    tracer = Tracer()
+    _spanned_run(paged3, tracer)
+    recs = [r for r in tracer.completed_records()
+            if r["name"].startswith("apex.")]
+    by_id = {r["span_id"]: r for r in recs}
+    named = collections.defaultdict(list)
+    for r in recs:
+        named[r["name"]].append(r)
+    assert len(named["apex.decode_step"]) == paged3.decode_calls > 0
+    assert len(named["apex.prefill"]) == paged3.prefill_calls > 1
+    assert len(named["apex.sched.accept"]) == paged3.decode_calls
+    assert len(named["apex.sched.admit"]) == paged3.prefill_calls
+
+    def children(parent):
+        kids = sorted((r for r in recs
+                       if r["parent_id"] == parent["span_id"]),
+                      key=lambda r: r["span_id"])
+        for a, b in zip([parent] + kids, kids):
+            assert a["t0"] <= b["t0"]
+        for kid in kids:
+            assert parent["t0"] <= kid["t0"] <= kid["t1"] <= parent["t1"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["t1"] <= b["t0"]
+        return [k["name"] for k in kids]
+
+    for step in named["apex.decode_step"]:
+        assert children(step) == ["apex.decode_step.launch",
+                                  "apex.decode_step.fetch"]
+        a = step["attrs"]
+        assert 0 < a["active"] <= a["slots"] == 3
+        assert 0 < a["pages_in_use"] <= a["pages"] \
+            == paged3.pool.capacity
+        assert 0 < a["resident"] <= a["pages_in_use"] * 8
+        assert by_id[step["parent_id"]]["name"] == "apex.sched.step"
+    for call in named["apex.prefill"]:
+        assert children(call) == [
+            "apex.prefill.plan", "apex.prefill.launch",
+            "apex.prefill.fetch", "apex.prefill.index"]
+        assert 0 < call["attrs"]["admitted"] <= call["attrs"]["slots"] == 3
+        assert by_id[call["parent_id"]]["name"] == "apex.sched.admit"
+    for launch in named["apex.prefill.launch"]:
+        a = launch["attrs"]
+        assert 0 < a["real_positions"] <= a["bucket"] * a["slots"]
+        assert a["hit_tokens"] == 0 and a["new_pages"] > 0
+    for tick in named["apex.sched.step"]:
+        assert tick["parent_id"] is None and tick["attrs"]["queued"] >= 0
+        assert set(children(tick)) <= {"apex.sched.admit",
+                                       "apex.decode_step",
+                                       "apex.sched.accept"}
+    assert sum(a["attrs"]["real_positions"]
+               for a in named["apex.prefill.launch"]) \
+        == sum(len(r.tokens) for r in _mixed_requests())
+    assert not tracer.open_spans()
+
+
+def test_spans_on_change_no_token_and_retrace_nothing(paged3):
+    from apex_tpu.monitor import Tracer
+
+    plain = _spanned_run(paged3)
+    tracer = Tracer()
+    traced = _spanned_run(paged3, tracer)
+    assert traced == plain and all(plain.values())
+    assert paged3.decode_traces == 1
+    assert any(r["name"] == "apex.decode_step"
+               for r in tracer.completed_records())
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("layout", ["slot", "paged", "tp2"])
+def test_lowered_programs_name_the_cache_write_and_the_projection(
+        layout, program, tp_devices):
+    """``kv_write`` and ``attn_proj`` ride the lowered program's location
+    metadata, nested in ``attention``: what a device trace's reader
+    splits attention by. Names only: the cost ledger, which knows
+    neither, still files both under ``attention``."""
+    eng = Engine(CFG, init_gpt2_params(CFG), EngineConfig(
+        num_slots=2, max_len=32, temperature=0.0,
+        page_size=None if layout == "slot" else 8,
+        tp=2 if layout == "tp2" else 1))
+    fn, args = {"decode": (eng._decode, eng._decode_args()),
+                "prefill": (eng._make_prefill(8), eng._prefill_args(8))
+                }[program]
+    text = fn.lower(*args).as_text(debug_info=True)
+    paths = [p.split("/") for p in set(re.findall(r'"([^"]*)"', text))]
+    for scope in ("kv_write", "attn_proj"):
+        inside = [p for p in paths if scope in p[:-1]]
+        assert inside, scope
+        assert all(p[p.index(scope) - 1] == "attention" for p in inside), \
+            inside[:3]
+    assert not any("kv_write" in p and "attn_proj" in p for p in paths)
 
 
 # -------------------------------------------------- scheduler / events

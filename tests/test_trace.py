@@ -30,7 +30,8 @@ from apex_tpu.monitor import GoodputLedger, MemoryAccountant, Tracer
 from apex_tpu.monitor.flight import FlightRecorder, thread_stacks
 from apex_tpu.monitor.memory import (publish_compiled_memory,
                                      sample_device_memory)
-from apex_tpu.monitor.trace import (ChromeTraceWriter, read_chrome_trace,
+from apex_tpu.monitor.trace import (ChromeTraceWriter, get_tracer,
+                                    read_chrome_trace, set_tracer,
                                     spans_by_trace)
 from apex_tpu.resilience import FaultInjector, resilient_step
 from apex_tpu.resilience.distributed import CollectiveWatchdog
@@ -189,6 +190,66 @@ def test_annotate_mirrors_to_enabled_tracer():
     with prof_mod.annotate("plain"):
         pass  # no tracer side effects
     assert len(tr.completed_records()) == 1
+
+
+def test_annotate_attrs_reach_the_profiler_with_the_tracer_disabled(
+        tmp_path):
+    """No tracer installed, a profiler session running: the range lands
+    on the host plane of the session's ``.xplane.pb`` with its attributes
+    as the event's stats — what the benchmark's readers take."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    assert get_tracer().enabled is False
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with prof.annotate("apex.test.outer", active=4, resident=300):
+            with prof.annotate("apex.test.inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    found = {e.name: (e, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("apex.test.")}
+    assert set(found) == {"apex.test.outer", "apex.test.inner"}
+    outer, stats = found["apex.test.outer"]
+    inner, none = found["apex.test.inner"]
+    assert stats == {"active": 4, "resident": 300} and none == {}
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns \
+        <= outer.start_ns + outer.duration_ns
+
+
+def test_annotate_nests_under_the_ambient_parent_with_a_tracer():
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:
+        with prof.annotate("apex.test.outer", queued=2) as outer:
+            with prof.annotate("apex.test.inner") as inner:
+                assert tr.current() is inner
+            with prof.annotate("apex.test.next", bucket=8):
+                pass
+    finally:
+        set_tracer(prev)
+    recs = {r["name"]: r for r in tr.completed_records()}
+    assert list(recs) == ["apex.test.inner", "apex.test.next",
+                          "apex.test.outer"]
+    assert recs["apex.test.outer"]["parent_id"] is None
+    assert recs["apex.test.outer"]["attrs"] == {"queued": 2}
+    for child in ("apex.test.inner", "apex.test.next"):
+        assert recs[child]["parent_id"] == outer.span_id
+        assert recs[child]["trace_id"] == outer.trace_id
+        assert recs["apex.test.outer"]["t0"] <= recs[child]["t0"] \
+            <= recs[child]["t1"] <= recs["apex.test.outer"]["t1"]
+    assert recs["apex.test.next"]["attrs"] == {"bucket": 8}
+    assert not tr.open_spans()
 
 
 def test_profile_rejects_nesting(monkeypatch):
