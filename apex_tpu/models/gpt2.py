@@ -10,6 +10,7 @@ fp32 params by default (amp O1 shape).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -131,15 +132,21 @@ def lm_loss(model: GPT2, params, tokens):
 
 # --------------------------------------------------------------- serving
 #
-# The cache-aware forward used by apex_tpu.serve: ONE token per slot per
-# call, attention over the slot's cached K/V, learned positional
-# embeddings indexed by each slot's absolute position. It is a pure
-# function over the SAME param pytree GPT2.init/flax produce (no separate
-# serving weights), with every array shape fixed at [num_slots, ...] — the
-# serve engine's single-compile invariant rests on that. The flash kernel
-# is a training/prefill-batch device; at one query row per slot the MXU
-# work is a [1, L] matvec, so decode attention is the chunked-softmax XLA
-# path in serve.attention instead.
+# The cache-aware forward used by apex_tpu.serve, written over ROWS: one
+# token per slot per call (decode, the verify scan's body: [num_slots]
+# rows) or one chunk of a prompt per slot (the batched prefill:
+# [num_slots * bucket] rows through the same dense code, every weight read
+# once a call). Learned positional embeddings are indexed by each row's
+# absolute position. It is a pure function over the SAME param pytree
+# GPT2.init/flax produce (no separate serving weights), with every array
+# shape fixed by the engine's geometry — the serve engine's
+# one-compile-per-program invariant rests on that. Only the cache append
+# and the attention differ between the two forms (_append_and_attend):
+# at one query row per slot the MXU work is a [1, L] matvec, so decode
+# attention is the chunked-softmax XLA path in serve.attention; a chunk
+# attends over its own fresh K/V in one plain causal [T, T] block a slot
+# and head (serve.attention.chunk_attention; at GPT-2 XL's bucket 64 the
+# scores are [4, 25, 64, 64] and the plain form beats a kernel launch).
 
 
 def _affine_layer_norm(x, scale, bias, eps: float = 1e-5):
@@ -151,10 +158,66 @@ def _affine_layer_norm(x, scale, bias, eps: float = 1e-5):
     return manual_layer_norm(x, scale, bias, (x.shape[-1],), eps)
 
 
+def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
+                       kv_quant):
+    """One layer's cache append and attention, for either cache layout
+    and either form of the forward. ``q``/``k``/``v``: ``[rows, heads,
+    head_dim]`` with ``rows = pos.size``; ``pos``/``write_mask``:
+    ``[num_slots]`` (one token a slot: appended at ``pos``, attending
+    over cached ``0..pos``) or ``[num_slots, T]`` (a chunk a slot).
+    Returns ``(o [rows, heads, head_dim], cache)``."""
+    from apex_tpu.serve.attention import (cached_attention,
+                                          chunk_attention, paged_attention)
+    from apex_tpu.serve.kv_cache import (paged_write_token, write_rows,
+                                         write_token)
+
+    if pos.ndim == 2:
+        rows = pos.shape + q.shape[1:]
+        cache, k_read, v_read = write_rows(
+            cache, layer, k.reshape(rows), v.reshape(rows), pos,
+            write_mask, codec=kv_quant)
+        o = chunk_attention(q.reshape(rows), k_read, v_read, cache, layer,
+                            pos[:, 0], block_k=block_k)
+        return o.reshape(q.shape), cache
+    # layout dispatch is structural, NOT isinstance: these imports are
+    # function-local (the serve package imports this module), so a
+    # purge-and-reimport of apex_tpu.serve.kv_cache mid-process would
+    # make isinstance(cache, PagedKVCache) compare against a fresh class
+    # and silently route a paged cache down the slot path
+    if hasattr(cache, "page_table"):
+        cache = paged_write_token(cache, layer, k, v, pos, write_mask,
+                                  codec=kv_quant)
+        attend = functools.partial(paged_attention, q, cache.k[layer],
+                                   cache.v[layer], cache.page_table, pos)
+    else:
+        cache = write_token(cache, layer, k, v, pos, write_mask,
+                            codec=kv_quant)
+        attend = functools.partial(cached_attention, q, cache.k[layer],
+                                   cache.v[layer], pos)
+    o = attend(block_k=block_k,
+               k_scale=None if kv_quant is None else cache.k_scale[layer],
+               v_scale=None if kv_quant is None else cache.v_scale[layer])
+    return o, cache
+
+
+def _final_logits(x, p, dt, shape, logits_at):
+    """Final norm and the logits product over the rows that are asked
+    for: every row (``[*shape, vocab]``), or in the chunk form row
+    ``logits_at[b]`` of each slot's chunk (``[num_slots, vocab]``)."""
+    x = x.reshape(shape + x.shape[-1:])
+    if logits_at is not None:
+        x = x[jnp.arange(shape[0]), logits_at.astype(jnp.int32)]
+    x = _affine_layer_norm(x, p["ln_f"]["weight"], p["ln_f"]["bias"])
+    return jax.lax.dot_general(
+        x, p["wte"].astype(dt), (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
-                       write_mask, *, block_k=None, kv_quant=None,
-                       final_scope: str = "sampling"):
-    """One decode token per slot through GPT-2 with the serving KV cache.
+                       write_mask, logits_at=None, *, block_k=None,
+                       kv_quant=None, final_scope: str = "sampling"):
+    """One decode token per slot — or one chunk of a prompt per slot —
+    through GPT-2 with the serving KV cache.
 
     ``tokens``/``positions``/``write_mask``: ``[num_slots]`` (int32, int32,
     bool). Each masked slot's token K/V is appended to the cache at
@@ -181,20 +244,24 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
     the block-scale KV codec: each appended token's K/V is encoded with
     one fp32 scale per head inside the write, and attention dequantizes
     per streamed chunk from the cache's scale planes. Encode is
-    deterministic, so prefill and decode still produce bit-identical
-    cache bytes for the same token at the same position (the PR-5
-    invariant survives quantization).
+    deterministic, so the same token at the same position gets the same
+    codes and scales whichever program appends it.
+
+    **Chunk form (the batched prefill).** With ``tokens``/``positions``/
+    ``write_mask`` of shape ``[num_slots, T]`` the ``T`` consecutive
+    positions of every slot go through the layers TOGETHER: the dense
+    parts run once over ``num_slots * T`` rows (every weight is read once
+    a call), the chunk's K/V take one masked scatter
+    (:func:`~apex_tpu.serve.kv_cache.write_rows`), and a row attends
+    causally over its slot's chunk and over the cached positions before
+    ``positions[:, 0]`` (:func:`~apex_tpu.serve.attention.
+    chunk_attention`). ``logits_at`` (``[num_slots]`` int32 chunk index)
+    picks the ONE row a slot whose logits are computed and returned as
+    ``[num_slots, vocab]``; ``None`` returns every row's, ``[num_slots,
+    T, vocab]``. A batched product and a one-row product may round
+    differently in the last place, so the chunk form matches the
+    one-token form to a tolerance, not to the bit (docs/serving.md).
     """
-    from apex_tpu.serve.attention import cached_attention, paged_attention
-    from apex_tpu.serve.kv_cache import paged_write_token, write_token
-
-    # layout dispatch is structural, NOT isinstance: these imports are
-    # function-local (the serve package imports this module), so a
-    # purge-and-reimport of apex_tpu.serve.kv_cache mid-process would
-    # make isinstance(cache, PagedKVCache) compare against a fresh class
-    # and silently route a paged cache down the slot path
-    paged = hasattr(cache, "page_table")
-
     c = cfg
     dt = c.compute_dtype
     h, d = c.n_head, c.n_embd // c.n_head
@@ -202,7 +269,8 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
     pos = positions.astype(jnp.int32)
 
     x = (p["wte"][tokens].astype(dt)
-         + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt))
+         + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt)
+         ).reshape(-1, c.n_embd)
     # phase markers: trace-safe jax.named_scope only (scope names ride
     # the MLIR loc(...) metadata — monitor/costs.py attributes the cost
     # ledger per phase on them; no traced effect, APX001-quiet). Inside
@@ -222,25 +290,8 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
             k = k.reshape(-1, h, d)
             v = v.reshape(-1, h, d)
         with jax.named_scope("attention"):
-            if paged:
-                cache = paged_write_token(cache, i, k, v, pos,
-                                          write_mask, codec=kv_quant)
-                o = paged_attention(
-                    q, cache.k[i], cache.v[i], cache.page_table, pos,
-                    block_k=block_k,
-                    k_scale=(None if kv_quant is None
-                             else cache.k_scale[i]),
-                    v_scale=(None if kv_quant is None
-                             else cache.v_scale[i]))
-            else:
-                cache = write_token(cache, i, k, v, pos, write_mask,
-                                    codec=kv_quant)
-                o = cached_attention(
-                    q, cache.k[i], cache.v[i], pos, block_k=block_k,
-                    k_scale=(None if kv_quant is None
-                             else cache.k_scale[i]),
-                    v_scale=(None if kv_quant is None
-                             else cache.v_scale[i]))
+            o, cache = _append_and_attend(cache, i, q, k, v, pos,
+                                          write_mask, block_k, kv_quant)
             with jax.named_scope("attn_proj"):
                 o = o.reshape(-1, c.n_embd)
                 x = x + (o.astype(dt)
@@ -254,10 +305,7 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
                                      blk["mlp_proj_w"].astype(dt),
                                      blk["mlp_proj_b"].astype(dt))
     with jax.named_scope(final_scope):
-        x = _affine_layer_norm(x, p["ln_f"]["weight"], p["ln_f"]["bias"])
-        logits = jax.lax.dot_general(
-            x, p["wte"].astype(dt), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        logits = _final_logits(x, p, dt, pos.shape, logits_at)
     return logits, cache
 
 
@@ -286,11 +334,14 @@ def _psum_halves_into(part, resid, bias, axis_name, ln=None):
 
 
 def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
-                          cache, tokens, positions, write_mask, *,
-                          block_k=None, kv_quant=None,
+                          cache, tokens, positions, write_mask,
+                          logits_at=None, *, block_k=None, kv_quant=None,
                           axis_name: str = "tp",
                           final_scope: str = "sampling"):
-    """The PER-RANK body of the tensor-parallel single-token forward —
+    """The PER-RANK body of the tensor-parallel token forward, in either
+    of :func:`gpt2_token_forward`'s forms (one token or one chunk a slot:
+    the gathers run over heads and hidden columns and the psums over
+    rows, so neither cares how many rows there are) —
     run under ``shard_map`` over the serving mesh (``apex_tpu.serve.tp``
     owns the param layout and specs). Heads are sharded: this rank sees
     ``n_head // tp`` heads' qkv columns, its slice of the KV cache's
@@ -319,10 +370,6 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
     returned logits are identical on every rank (the caller's
     ``out_specs`` treat them as replicated).
     """
-    from apex_tpu.serve.attention import cached_attention, paged_attention
-    from apex_tpu.serve.kv_cache import paged_write_token, write_token
-
-    paged = hasattr(cache, "page_table")
     c = cfg
     dt = c.compute_dtype
     h_loc = c.n_head // tp
@@ -331,7 +378,8 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
     pos = positions.astype(jnp.int32)
 
     x = (p["wte"][tokens].astype(dt)
-         + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt))
+         + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt)
+         ).reshape(-1, c.n_embd)
     # phase markers mirror gpt2_token_forward's; collective sites carry
     # their own nested "collective" scope (innermost scope wins in the
     # ledger walk, so a gather inside attention attributes to collective)
@@ -354,25 +402,8 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
             # over that head's head_dim), so this rank's shard of the
             # quantized pool is bit-identical to the single-chip
             # engine's same head slice
-            if paged:
-                cache = paged_write_token(cache, i, k, v, pos,
-                                          write_mask, codec=kv_quant)
-                o = paged_attention(
-                    q, cache.k[i], cache.v[i], cache.page_table, pos,
-                    block_k=block_k,
-                    k_scale=(None if kv_quant is None
-                             else cache.k_scale[i]),
-                    v_scale=(None if kv_quant is None
-                             else cache.v_scale[i]))
-            else:
-                cache = write_token(cache, i, k, v, pos, write_mask,
-                                    codec=kv_quant)
-                o = cached_attention(
-                    q, cache.k[i], cache.v[i], pos, block_k=block_k,
-                    k_scale=(None if kv_quant is None
-                             else cache.k_scale[i]),
-                    v_scale=(None if kv_quant is None
-                             else cache.v_scale[i]))
+            o, cache = _append_and_attend(cache, i, q, k, v, pos,
+                                          write_mask, block_k, kv_quant)
             out_b = blk["attn_out"]["bias"].astype(dt)
             if sync == "exact":
                 # concatenate the heads across ranks, then the FULL
@@ -440,8 +471,5 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
                     x, _ = _psum_halves_into(attn_part + mlp_part, x,
                                              out_b + proj_b, axis_name)
     with jax.named_scope(final_scope):
-        x = _affine_layer_norm(x, p["ln_f"]["weight"], p["ln_f"]["bias"])
-        logits = jax.lax.dot_general(
-            x, p["wte"].astype(dt), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        logits = _final_logits(x, p, dt, pos.shape, logits_at)
     return logits, cache

@@ -1,6 +1,9 @@
-"""Decode attention over the static KV cache — slot-contiguous or paged.
+"""Attention over the static KV cache — slot-contiguous or paged: the
+decode step's (one query a slot) and the batched prefill's (a chunk a
+slot, :func:`chunk_attention`, at the end of this file).
 
-One query token per slot against that slot's cached keys/values. The key
+**Decode.** One query token per slot against that slot's cached
+keys/values. The key
 axis is static (the slot cache's ``max_len``, or the paged cache's
 ``max_pages_per_slot * page_size`` virtual axis); reachability is a mask
 (``key_pos <= position``), never a shape — so the op compiles once and a
@@ -15,8 +18,11 @@ static python loop. The chunk geometry is what :mod:`apex_tpu.tune` tunes
 ``[block_k, head_dim]`` K/V tile at a time through VMEM, so the block size
 is a real tile-geometry knob, with
 :func:`~apex_tpu.ops.pallas.tiling.decode_attention_block` as the
-committed heuristic. Both the prefill scan body and the decode step call
-this function with the same geometry, so the two paths stay bit-identical.
+committed heuristic. The decode step and the speculative verify scan's
+body call this function with the same geometry, so those two stay
+bit-identical. The batched prefill does not come through here: its
+chunk's own keys never touch the cache's key axis, so it matches the
+decode step to float32 rounding and not to the bit (docs/serving.md).
 
 **The paged path shares the slot path's arithmetic verbatim**: the only
 difference is where a chunk's K/V rows are fetched from (a contiguous
@@ -121,7 +127,7 @@ def _combine_chunks(q: jax.Array, positions: jax.Array, L: int, bk: int,
     Everything numeric happens HERE, identically for both layouts: each
     score's reduction runs over ``d`` (not ``L``), the global row max
     equals the max over chunk maxima bit-for-bit, and only the SUM order
-    depends on ``block_k`` — identically in prefill and decode, and
+    depends on ``block_k`` — identically in decode and verify, and
     identically in slot and paged engines.
     """
     b, h, d = q.shape
@@ -237,3 +243,104 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         return ks, vs
 
     return _combine_chunks(q, positions, L, bk, s, fetch)
+
+
+def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
+                    layer: int, start: jax.Array, *,
+                    block_k: int) -> jax.Array:
+    """Attention of a chunk of ``T`` consecutive tokens per slot — the
+    batched prefill's attention, for either cache layout.
+
+    ``q``/``k``/``v``: ``[num_slots, T, heads, head_dim]``, the chunk's
+    own queries, keys and values (``k``/``v`` as a read of the cache
+    would return them: :func:`~apex_tpu.serve.kv_cache.write_rows`);
+    ``start``: ``[num_slots]`` int32, the absolute position of each
+    slot's first chunk token. The query at chunk position ``t`` of slot
+    ``b`` attends
+
+    (a) causally over the chunk's own keys ``0..t`` of that slot, taken
+        from ``k``/``v`` and never through the cache: one plain
+        ``[T, T]`` score block per slot and head, whose cost follows
+        the chunk alone; and
+    (b) over the cached positions ``< start[b]`` (a prompt's head that
+        the prefix index served), fetched ``block_k`` rows at a time from
+        layer ``layer`` of ``cache`` — through the page table on a paged
+        cache — and folded into (a)'s running max, sum and weighted sum.
+
+    (b) is a loop whose trip count is DATA: ``ceil(max(start) /
+    block_k)`` chunks, so a call with no hit runs none of it and a call
+    with one pays for the head it attends over, never for ``max_len`` or
+    the pool. A slot with ``start == 0`` beside one with a hit has every
+    fetched key masked, which changes none of its bits.
+
+    All math fp32, the two products at ``HIGHEST`` precision (on a TPU
+    the default would round the probabilities to bf16 before the weighted
+    sum); one max-subtracted softmax over (a) and (b) together. A row's
+    own token is always reachable, so the denominator is never empty.
+    Reductions run within a slot and a head. Returns ``[num_slots, T,
+    heads, head_dim]`` in ``q.dtype``.
+    """
+    b, t, h, d = q.shape
+    paged = hasattr(cache, "page_table")
+    ps = cache.page_size if paged else None
+    bk = resolve_block_k(cache.max_len, h, d, q.dtype, block_k,
+                         page_size=ps)
+    s = jnp.float32(1.0 / (d ** 0.5))
+    hi = jax.lax.Precision.HIGHEST
+    q32 = q.astype(_f32)
+
+    def scores(ks, reach):
+        """Every query against ``ks`` ``[b, k, h, d]``, unreachable keys
+        at ``NEG_INF`` (``reach`` broadcasts to ``[b, h, t, k]``)."""
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q32, ks.astype(_f32),
+                        precision=hi) * s
+        return jnp.where(reach, sc, NEG_INF)
+
+    def weigh(sc, vs, reach, m):
+        """The softmax's sum and weighted values about the max ``m``."""
+        e = jnp.where(reach, jnp.exp(sc - m[..., None]), 0.0)
+        return (jnp.sum(e, axis=-1),
+                jnp.einsum("bhqk,bkhd->bhqd", e, vs.astype(_f32),
+                           precision=hi))
+
+    idx = jnp.arange(t, dtype=jnp.int32)
+    causal = (idx[None, :] <= idx[:, None])[None, None]    # [1, 1, q, k]
+    sc = scores(k, causal)
+    m = sc.max(axis=-1)                                    # [b, h, t]
+    den, num = weigh(sc, v, causal, m)
+
+    start = start.astype(jnp.int32)
+    scales = cache.k_scale is not None
+
+    def fetch(buf, r0):
+        """Rows ``r0 .. r0 + block_k`` of every slot's key axis out of
+        the STACKED buffer in one indexing op: slicing the layer out
+        first would be loop-invariant, hoisted, and paid by every call."""
+        if paged:
+            pages = jax.lax.dynamic_index_in_dim(
+                cache.page_table, r0 // ps, axis=1, keepdims=False)
+            return jax.lax.dynamic_slice_in_dim(
+                buf[layer, pages], r0 % ps, bk, axis=1)
+        return jax.lax.dynamic_slice(
+            buf, (layer, 0, r0) + (0,) * (buf.ndim - 3),
+            (1, b, bk) + buf.shape[3:])[0]
+
+    def body(i, carry):
+        m, den, num = carry
+        r0 = i * bk
+        ks, vs = fetch(cache.k, r0), fetch(cache.v, r0)
+        if scales:
+            ks = ks.astype(_f32) * fetch(cache.k_scale, r0)[..., None]
+            vs = vs.astype(_f32) * fetch(cache.v_scale, r0)[..., None]
+        kpos = r0 + jnp.arange(bk, dtype=jnp.int32)
+        reach = (kpos[None, :] < start[:, None])[:, None, None, :]
+        sc = scores(ks, reach)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        keep = jnp.exp(m - m_new)
+        d_den, d_num = weigh(sc, vs, reach, m_new)
+        return (m_new, den * keep + d_den, num * keep[..., None] + d_num)
+
+    m, den, num = jax.lax.fori_loop(
+        0, (jnp.max(start) + bk - 1) // bk, body, (m, den, num))
+    o = num / den[..., None]                               # [b, h, t, d]
+    return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)

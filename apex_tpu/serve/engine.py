@@ -1,4 +1,5 @@
-"""AOT prefill + one-jit decode over the static KV cache (slot or paged).
+"""AOT batched prefill + one-jit decode over the static KV cache (slot or
+paged).
 
 The engine owns three compiled artifacts and NOTHING else touches the
 device:
@@ -8,13 +9,20 @@ device:
   backfill all happen by changing *values* (masks, lengths, page-table
   rows), so the jit cache holds exactly one entry for the life of the
   engine — asserted by tier-1 (``Engine.decode_traces``).
-- ``prefill`` — a ``lax.scan`` of the *same* single-token forward over the
-  prompt positions, at the same ``[num_slots]`` width (non-admitted slots
-  mask their writes). One compile per pow2 prompt-length bucket. Because
-  prefill and decode share the forward at identical shapes, an
-  incrementally decoded token's logits are bit-identical (fp32) to the
-  same token's logits under full-sequence prefill — there is no
-  "prefill path" to drift from.
+- ``prefill`` — ONE batched ``[num_slots, bucket]`` forward (the same
+  ``gpt2_token_forward``, in its chunk form): the prompts' positions go
+  through the layers together, so a call reads every weight once; their
+  K/V take one masked scatter a layer (rows of non-admitted slots and
+  of padding are dropped); a row attends causally over its own chunk
+  and, after a prefix hit, over the cached head in a loop whose trip
+  count is data; the logits are those of each admitted slot's last real
+  position. One compile per pow2 prompt-length bucket, one device run a
+  call. Prefill and decode are the same mathematics in another order of
+  float32 sums (a batched product against a one-row product, a softmax
+  over the chunk against ``block_k`` chunks of the cache), so an
+  incrementally decoded token's logits match the same token's logits
+  under full-sequence prefill to rounding, not to the bit
+  (docs/serving.md has what stays bit-exact).
 - ``evict`` — a mask-shaped length reset (kv_cache.evict_slots), one
   compile total.
 
@@ -29,7 +37,7 @@ against the slot engine** on identical request traces at the same
 is tuned per layout, so pin ``block_k`` for bitwise comparison). With ``prefix_cache=True`` a hash-based prefix
 index shares read-only prompt pages across requests: a request whose
 prompt prefix is already resident skips prefill for those pages (the
-scan covers only the tail; a partially-used boundary page is
+call covers only the tail; a partially-used boundary page is
 copied-on-write first), which is what removes the repeated fleet-wide
 system-prompt prefill. Pages for a request's whole admitted budget are
 reserved at admission, so decode can never page-fault mid-stream —
@@ -46,7 +54,7 @@ structure stay replicated data — so the allocator, prefix index,
 journal, and scheduler are mesh-agnostic and the one-compile invariant
 becomes **one compile per mesh shape** (``decode_traces`` still reads
 1). The per-rank forward runs under ``shard_map`` inside the SAME
-jitted decode step and prefill scan; per-layer cross-rank sync is
+jitted decode step and prefill call; per-layer cross-rank sync is
 ``tp_sync="exact"`` (all-gather concatenation — **bit-identical in fp32
 to the single-chip engine at equal ``block_k``**, greedy and sampled;
 the tier-1 oracle), ``"overlap"`` (TokenWeave: the two per-layer
@@ -63,8 +71,9 @@ state, split in-graph, and returned — a fixed seed replays a stream
 bit-for-bit.
 
 **Speculative mode** (``EngineConfig(spec_draft_len=K)``) adds a FOURTH
-compiled artifact: ``verify`` — structurally the prefill scan over
-``K + 1`` positions (column 0 re-feeds the slot's last committed token,
+compiled artifact: ``verify`` — a ``lax.scan`` of the one-token decode
+forward over ``K + 1`` positions, the only scan of the token forward the
+engine has (column 0 re-feeds the slot's last committed token,
 columns 1..K are host-side draft guesses from
 :class:`~apex_tpu.serve.spec.NGramDrafter`). Acceptance is exact and
 in-graph: position ``p``'s logits produce the target policy's own next
@@ -375,35 +384,40 @@ class Engine:
         which at GPT-2 XL is the whole model baked into every program."""
         return self.params if self.mesh is None else self._tp_params
 
-    def _token_step(self, weights, cache, tokens, positions, mask, *,
-                    final_scope: str = "sampling"):
+    def _token_step(self, weights, cache, tokens, positions, mask,
+                    logits_at=None, *, final_scope: str = "sampling"):
+        """The model forward at this engine's geometry: one token a slot
+        (``[num_slots]`` arguments: decode, the verify scan's body) or
+        one chunk a slot (``[num_slots, T]``: prefill, with ``logits_at``
+        naming the row a slot whose logits are wanted)."""
+        kw = dict(block_k=self.block_k, kv_quant=self._kv_quant,
+                  final_scope=final_scope)
+        data = (tokens, positions, mask) \
+            + (() if logits_at is None else (logits_at,))
         if self.mesh is None:
             return gpt2_token_forward(self.model_cfg, weights, cache,
-                                      tokens, positions, mask,
-                                      block_k=self.block_k,
-                                      kv_quant=self._kv_quant,
-                                      final_scope=final_scope)
-        # tensor-parallel: the SAME call sites (decode_fn, the prefill
-        # scan body) lower the per-rank forward under shard_map — the
-        # cache rides in head-sharded, the page table/lengths replicated,
-        # logits come back replicated (identical on every rank by the
-        # sync-mode contract), and sampling stays outside on the full
-        # replicated logits exactly as on a single chip
+                                      *data, **kw)
+        # tensor-parallel: the SAME call sites (decode_fn, prefill_fn,
+        # the verify scan body) lower the per-rank forward under
+        # shard_map — the cache rides in head-sharded, the page
+        # table/lengths replicated, logits come back replicated
+        # (identical on every rank by the sync-mode contract), and
+        # sampling stays outside on the full replicated logits exactly
+        # as on a single chip
         from jax.sharding import PartitionSpec as P
 
         specs = tp_cache_specs(cache)
 
-        def rank_body(params, cache, tokens, positions, mask):
+        def rank_body(params, cache, *data):
             return gpt2_token_forward_tp(
                 self.model_cfg, self._tp, self.config.tp_sync, params,
-                cache, tokens, positions, mask, block_k=self.block_k,
-                kv_quant=self._kv_quant, final_scope=final_scope)
+                cache, *data, **kw)
 
         fn = shard_map(rank_body, mesh=self.mesh,
-                       in_specs=(self._tp_param_specs, specs, P(), P(),
-                                 P()),
+                       in_specs=(self._tp_param_specs, specs)
+                       + (P(),) * len(data),
                        out_specs=(P(), specs), check_vma=False)
-        return fn(weights, cache, tokens, positions, mask)
+        return fn(weights, cache, *data)
 
     def _decode_fn(self, weights, cache, last_tokens, active, rng,
                    pol=None):
@@ -418,33 +432,31 @@ class Engine:
         return next_tokens, logits, cache, rng
 
     def _make_prefill(self, bucket: int):
+        """The ``prefill_<bucket>`` program: ONE ``[num_slots, bucket]``
+        forward. Every admitted prompt's positions go through the layers
+        together, their K/V take one masked write a layer at ``start +
+        t``, and each admitted slot's first token is drawn in-program
+        from the logits of its last real position."""
         keep = self.config.keep_prefill_logits
 
         def prefill_fn(weights, cache, tokens, admit, start, tail_lens,
                        rng, pol=None):
             self.prefill_traces += 1
-            cache = kv_cache.reset_slots(cache, admit)
-
-            def body(carry, p):
-                cache, last_logits = carry
-                write = admit & (p < tail_lens)
-                # absolute position = start + scan step: with a prefix
-                # hit the scan covers only the tail, attending back over
-                # the shared pages (start == 0 and tail == prompt on the
-                # slot path — bit-identical to the pre-paging scan)
-                positions = jnp.where(write, start + p, cache.lengths)
-                logits, cache = self._token_step(
-                    weights, cache, tokens[:, p], positions, write)
-                last_logits = jnp.where(write[:, None], logits,
-                                        last_logits)
-                return (cache, last_logits), (logits if keep else None)
-
-            vocab = self.model_cfg.vocab_size
-            init_logits = jnp.zeros((self.config.num_slots, vocab),
-                                    jnp.float32)
-            (cache, last_logits), all_logits = jax.lax.scan(
-                body, (cache, init_logits),
-                jnp.arange(bucket, dtype=jnp.int32))
+            t = jnp.arange(bucket, dtype=jnp.int32)[None, :]
+            # absolute position = start + chunk index: with a prefix hit
+            # the call covers only the tail, attending back over the
+            # shared pages (start == 0 and tail == prompt otherwise)
+            write = admit[:, None] & (t < tail_lens[:, None])
+            last = jnp.maximum(tail_lens - 1, 0)
+            logits, cache = self._token_step(
+                weights, cache, tokens, start[:, None] + t, write,
+                None if keep else last)
+            if keep:
+                all_logits = jnp.swapaxes(logits, 0, 1)     # [P, B, V]
+                last_logits = jnp.take_along_axis(
+                    logits, last[:, None, None], axis=1)[:, 0]
+            else:
+                all_logits, last_logits = None, logits
             cache = kv_cache.set_lengths(cache, admit, start + tail_lens)
             with jax.named_scope("sampling"):
                 rng, sub = jax.random.split(rng)
@@ -454,15 +466,15 @@ class Engine:
         return jax.jit(prefill_fn)
 
     def _make_verify(self):
-        """The speculative verify step: structurally the prefill scan
-        over ``draft_len + 1`` positions at decode width. Column 0
+        """The speculative verify step: a scan of the one-token decode
+        forward over ``draft_len + 1`` positions at decode width. Column 0
         re-feeds each slot's last committed token (exactly what
         ``decode_step`` would feed), columns ``1..K`` are the host
         drafter's guesses; position ``p``'s logits produce the target
         policy's own next token, and a draft is accepted iff it EQUALS
         that target (exact rejection-sampling acceptance for a
-        point-mass drafter — no tolerance, the fp32 prefill-vs-decode
-        bit-exactness IS the oracle). The accepted run length is data:
+        point-mass drafter — no tolerance: the scan's body IS the decode
+        step's forward, bit for bit). The accepted run length is data:
         ``set_lengths`` commits ``accepted + 1`` tokens and thereby
         rolls back every rejected draft row (stale K/V beyond
         ``lengths`` is unreachable — attention reachability is keyed on
@@ -655,7 +667,7 @@ class Engine:
         self.decode_calls = 0            # decode_step executions
         self.prefill_calls = 0           # host prefill() invocations
         self.prefill_requests = 0        # slot-prompts prefilled
-        self.prefill_scanned_tokens = 0  # scan steps actually paid
+        self.prefill_scanned_tokens = 0  # positions paid: bucket a call
         self.prefix_hits = 0             # prompts that reused >=1 page
         self.prefix_hit_tokens = 0       # tokens served from the index
         self.last_prefill_stats: Dict[int, Dict[str, int]] = {}
@@ -811,18 +823,18 @@ class Engine:
                 cacheable: Optional[Dict[int, int]] = None):
         """Insert ``{slot: prompt token ids}`` in one compiled call.
 
-        Pads every prompt to the shared pow2 bucket, resets the target
-        slots, scans the single-token forward over the prompt positions
-        (non-target slots are fully masked), and samples each admitted
-        slot's first generated token. Returns ``(first_tokens [B],
-        last_logits [B, vocab], all_logits [P, B, vocab] | None)``; only
-        the admitted slots' rows are meaningful.
+        Pads every prompt to the shared pow2 bucket, runs the batched
+        ``[num_slots, bucket]`` forward (rows of non-target slots and of
+        padding are computed, discarded, and written nowhere), and
+        samples each admitted slot's first generated token. Returns
+        ``(first_tokens [B], last_logits [B, vocab], all_logits [P, B,
+        vocab] | None)``; only the admitted slots' rows are meaningful.
 
         Paged mode: ``budgets[slot]`` (default: worst case ``max_len -
         len(prompt)``) sizes the page reservation — pages for the whole
         admitted budget are taken here so decode never allocates. With a
         prefix index, the longest indexed prefix is shared read-only and
-        the scan covers only the tail (a partial boundary page is
+        the call covers only the tail (a partial boundary page is
         copied-on-write); afterwards the prompt's full pages are inserted
         into the index — ``cacheable[slot]`` caps how many leading tokens
         are indexable (recovery passes the original prompt length so
@@ -902,7 +914,7 @@ class Engine:
                       budgets: Optional[Dict[int, int]]):
         """The host half of an admission, before anything is launched:
         release, plan, evict, allocate, copy-on-write, and the page-table
-        upload. Returns ``(starts [num_slots], {slot: tail to scan},
+        upload. Returns ``(starts [num_slots], {slot: tail to run},
         fresh pages taken)`` and leaves ``last_prefill_stats``."""
         starts = np.zeros((self.config.num_slots,), np.int32)
         tails: Dict[int, Sequence[int]] = dict(prompts)

@@ -303,6 +303,61 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
     return cache.replace(**out)
 
 
+# ------------------------------------------------ a chunk of rows (prefill)
+
+
+@jax.named_scope("kv_write")
+def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
+               positions: jax.Array, mask: jax.Array,
+               codec: Optional[str] = None):
+    """Append a chunk of tokens' K/V per slot in one masked scatter —
+    the batched prefill's write, for either cache layout.
+
+    ``k_rows``/``v_rows``: ``[num_slots, T, heads, head_dim]``;
+    ``positions``/``mask``: ``[num_slots, T]`` (int32 absolute position,
+    bool). Row ``(b, t)`` lands at ``positions[b, t]`` of slot ``b`` —
+    through the page table on a paged cache — where ``mask[b, t]``. A
+    masked-off row (a slot this call does not admit, or a prompt's
+    padding) is given an out-of-range index and DROPPED by the scatter:
+    no byte of a decoding neighbour, of the null page, or of a row past
+    the prompt is touched. Real rows never alias (one slot's positions
+    are distinct, and the allocator maps no writable page twice).
+
+    With ``codec`` each row is encoded exactly as :func:`write_token`
+    encodes it (one scale per token and head) and the scales take the
+    same scatter. Returns ``(cache, k_read, v_read)``: the cache, and the
+    rows as a later read of the cache returns them (fp32 decoded codes,
+    or the rows in the cache's dtype) — what the chunk's own attention
+    attends over, so that prefill sees the values decode will see.
+    """
+    pos = positions.astype(jnp.int32)
+    live = mask & (pos >= 0) & (pos < cache.max_len)
+    slot = jnp.arange(pos.shape[0], dtype=jnp.int32)[:, None]
+    if hasattr(cache, "page_table"):
+        ps = cache.page_size
+        pages = cache.page_table[
+            slot, jnp.clip(pos // ps, 0, cache.max_pages_per_slot - 1)]
+        index = (layer, jnp.where(live, pages, cache.num_pages), pos % ps)
+    else:
+        index = (layer, jnp.broadcast_to(slot, pos.shape),
+                 jnp.where(live, pos, cache.max_len))
+    out, read = {}, {}
+    for name, rows in (("k", k_rows), ("v", v_rows)):
+        buf = getattr(cache, name)
+        if codec is None:
+            rows = read[name] = rows.astype(buf.dtype)
+        else:
+            from apex_tpu.quant.kv import decode_kv, encode_kv
+
+            rows, scales = encode_kv(codec, rows.astype(jnp.float32))
+            sbuf = getattr(cache, name + "_scale")
+            rows, scales = rows.astype(buf.dtype), scales.astype(sbuf.dtype)
+            read[name] = decode_kv(rows, scales)
+            out[name + "_scale"] = sbuf.at[index].set(scales, mode="drop")
+        out[name] = buf.at[index].set(rows, mode="drop")
+    return cache.replace(**out), read["k"], read["v"]
+
+
 # ------------------------------------------------- tensor-parallel layout
 #
 # Both cache layouts shard the SAME axis under tensor parallelism: axis 3

@@ -6,9 +6,16 @@ THE chaos invariant under test (ISSUE 8 acceptance): under any seeded
 storms, deadlines, bounded queues — **every submitted request reaches
 exactly one terminal status** (completed / evicted / aborted / rejected /
 deadline-exceeded), no request is ever silently lost, surviving slots'
-greedy outputs stay bit-identical to an uncrashed run, and
+greedy outputs equal an uncrashed run's token for token, and
 ``Engine.decode_traces`` does not grow across a ``recover()`` (the
 compiled executables are reused, never retraced).
+
+Across the prefill/decode border: recovery re-prefills prompt plus
+generated-so-far in ONE batched forward where the unbroken stream
+decoded them one row at a time; the same mathematics in another order
+of float32 sums, so the continued stream's logits match the unbroken
+one's to rounding (``tests/test_serve.py``: ``BORDER``) and its tokens
+are equal on these seeds. The assertions below compare tokens.
 
 Engines are compiled once per geometry and shared across tests via
 ``Engine.reset()`` (the PR-5 contract); trace-counter assertions use
@@ -322,8 +329,9 @@ def test_crash_recover_drain_smoke(greedy2):
     """THE tier-1 chaos acceptance: one schedule combining a decode-step
     crash, a latency spike, and a queue storm. Every submitted request
     (initial + storm) reaches exactly one terminal status, surviving
-    requests' greedy outputs are bit-identical to an uncrashed run, and
-    decode compiles exactly zero additional times across the recovery."""
+    requests' greedy outputs equal an uncrashed run's token for token
+    (across the border: module docstring), and decode compiles exactly
+    zero additional times across the recovery."""
     base_sched = ServeScheduler(greedy2.reset())
     for r in _requests(4):
         base_sched.submit(r)
@@ -351,9 +359,10 @@ def test_crash_recover_drain_smoke(greedy2):
 
 def test_warm_restart_determinism_greedy(greedy2):
     """Crash at every early tick in turn: greedy outputs always equal the
-    uncrashed run — recovery re-prefill is bit-exact by the PR-5
-    prefill/decode invariant and the journal rollback replays the torn
-    tick identically."""
+    uncrashed run — recovery's batched re-prefill matches the unbroken
+    decode stream to float32 rounding (the module docstring's border),
+    which on these seeds is the same argmax at every token, and the
+    journal rollback replays the torn tick identically."""
     base_sched = ServeScheduler(greedy2.reset())
     for r in _requests(3):
         base_sched.submit(r)
@@ -369,8 +378,10 @@ def test_warm_restart_determinism_greedy(greedy2):
 
 def test_warm_restart_replays_sampled_stream(params):
     """The PRNG key path is journaled and restored: a temperature>0
-    stream continues bit-for-bit across a crash — the strictest form of
-    'surviving slots stay bit-identical'."""
+    stream continues token for token across a crash — the strictest
+    form of 'surviving slots stay identical': the re-prefilled logits
+    match to float32 rounding (the module docstring's border) and the
+    restored key draws the same tokens from them on these seeds."""
     eng = Engine(CFG, params,
                  EngineConfig(num_slots=2, max_len=32, temperature=0.8,
                               top_k=5), seed=0)
@@ -591,8 +602,8 @@ def test_chaos_smoke_under_paging(paged2):
     """ISSUE 9 acceptance: THE PR-8 chaos smoke re-run on a paged engine
     with shared prefix pages — decode-step crash + latency spike + queue
     storm. Every submitted request reaches exactly one terminal status,
-    surviving greedy outputs are bit-identical to the uncrashed paged
-    run, and decode_traces delta is 0 across the recovery."""
+    surviving greedy outputs equal the uncrashed paged run's token for
+    token, and decode_traces delta is 0 across the recovery."""
     base_sched = ServeScheduler(paged2.reset())
     for r in _prefix_requests(4):
         base_sched.submit(r)
@@ -622,7 +633,8 @@ def test_chaos_smoke_under_paging(paged2):
 def test_warm_restart_paged_determinism_and_journal(paged2):
     """Crash at every early tick in turn: the paged engine's greedy
     outputs always equal the uncrashed run (recovery re-prefill through
-    shared pages is bit-exact), and the journal payload records the page
+    shared pages matches to float32 rounding: the module docstring's
+    border), and the journal payload records the page
     accounting — tables, refcounts, prefix-index size — for the
     postmortem."""
     base_sched = ServeScheduler(paged2.reset())

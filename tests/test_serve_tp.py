@@ -154,26 +154,30 @@ def test_tp_bit_exact_vs_single_chip_greedy(base8, tp2):
            {k: v["finish_reason"] for k, v in base.items()}
 
 
-def test_tp_decode_logits_bit_exact_vs_single_prefill(params, tp2,
-                                                      tp_devices):
+def test_tp_decode_logits_match_single_prefill(params, tp2, tp_devices):
     """Strongest oracle form: the tp=2 engine's incremental decode
-    LOGITS equal the single-chip engine's full-sequence prefill logits
-    bit-for-bit in fp32 — crossing the mesh boundary AND the
-    prefill/decode boundary in one assertion."""
+    LOGITS match the single-chip engine's full-sequence prefill logits —
+    crossing the mesh boundary (bit-exact on its own: the tp engine's
+    batched prefill against the single chip's, same call) AND the
+    prefill/decode border (float32 rounding: the batched prefill sums in
+    another order than the one-row decode step)."""
     seq = _tokens(12)
     keeper = _engine(params, keep_prefill_logits=True)
     _, _, all_logits = keeper.prefill({1: seq})
     all_logits = np.asarray(all_logits)              # [P, B, V]
     inc = tp2.reset()
-    inc.prefill({1: seq[:5]})
+    _, tp_last, _ = inc.prefill({1: seq[:5]})
+    _, one_last, _ = keeper.reset().prefill({1: seq[:5]})
+    np.testing.assert_array_equal(np.asarray(tp_last)[1],
+                                  np.asarray(one_last)[1])
     for j in range(5, len(seq)):
         forced = np.array([0, seq[j], 0], np.int32)
         _, logits = inc.decode_step(forced,
                                     np.array([False, True, False]))
         a, b = all_logits[j, 1], np.asarray(logits)[1]
         assert a.dtype == np.float32
-        assert np.array_equal(a, b), \
-            f"tp decode pos {j} drifted: max|d|={np.abs(a - b).max()}"
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                   err_msg=f"tp decode pos {j}")
 
 
 def test_tp_paged_bit_exact_vs_single_chip(paged1, tp2_paged):

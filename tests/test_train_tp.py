@@ -14,8 +14,9 @@ fake-multihost harness + the conftest-forced 8-device CPU mesh:
   dp×tp=2 GPT-2 trainer ends bit-identical to the uninterrupted
   single-chip oracle, the committed checkpoint's manifest carries the
   dp×tp ``layout`` block, and the restored params serve through a tp=2
-  ``Engine`` with decode logits bit-equal to a single-chip prefill of
-  the trained params;
+  ``Engine`` with decode logits matching a single-chip prefill of the
+  trained params to float32 rounding (the batched prefill sums in
+  another order than the one-row decode step: docs/serving.md);
 - **topology-portable restore**: a checkpoint written at tp=2 restores
   onto a tp=1 job automatically (the sharded manager reassembles leaves
   topology-independently), publishing a counted
@@ -147,8 +148,8 @@ def test_chaos_dp_tp_train_then_serve_bit_identical(tmp_path, events,
     params bit-identical to the uninterrupted single-chip oracle, the
     committed manifest carries the dp×tp layout block, zero recompiles
     across every leg (the custom-fns cache), and the trained checkpoint
-    serves through a tp=2 Engine with decode logits bit-equal to a
-    single-chip prefill of the same params."""
+    serves through a tp=2 Engine with decode logits matching a
+    single-chip prefill of the same params to float32 rounding."""
     steps = 12
     init = init_gpt2_params(CFG, seed=0)
     spec = {"params": tp_param_specs(CFG, "exact")}
@@ -192,8 +193,9 @@ def test_chaos_dp_tp_train_then_serve_bit_identical(tmp_path, events,
 
     # train-then-serve: restore the committed step, load the params into
     # a tp=2 serving Engine (head-major qkv permutation happens at param
-    # load), and hold its incremental decode LOGITS bit-equal to a
-    # single-chip prefill of the trained params
+    # load), and hold its incremental decode LOGITS to a single-chip
+    # prefill of the trained params: across the prefill/decode border,
+    # so to float32 rounding (tests/test_serve.py: BORDER), not the bit
     probe = Trainer(cfg, loss_fn=_gpt2_loss, init_params=init,
                     batch_fn=_gpt2_batch, tp_spec=spec)
     restored = mgr.restore_latest(probe._tree(0))
@@ -217,8 +219,8 @@ def test_chaos_dp_tp_train_then_serve_bit_identical(tmp_path, events,
                                        np.array([False, True, False]))
         a, b = all_logits[j, 1], np.asarray(logits)[1]
         assert a.dtype == np.float32
-        assert np.array_equal(a, b), \
-            f"served pos {j} drifted: max|d|={np.abs(a - b).max()}"
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                   err_msg=f"served pos {j}")
 
 
 # --------------------------------------------- topology-portable restore
