@@ -50,6 +50,11 @@ one invariant:
   TokenWeave-style (``tp_sync="overlap"``) or relaxed
   (``tp_sync="relaxed"``), and the default exact mode bit-identical in
   fp32 to the single-chip engine at equal ``block_k``.
+- :mod:`~apex_tpu.serve.model` — the model seam: what a model gives the
+  engine (its cache constructor, its token forward, the modes it
+  refuses); GPT-2 and DeepSeek-V3 behind it. :mod:`~apex_tpu.serve.moe`
+  is the serving expert layer of the latter: a rank's share of an
+  expert-parallel layer, no token dropped.
 - :mod:`~apex_tpu.serve.cli` — ``apex-tpu-serve``: load a model config,
   run a scripted or stdin request stream, print per-request stats.
 

@@ -344,3 +344,136 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
         0, (jnp.max(start) + bk - 1) // bk, body, (m, den, num))
     o = num / den[..., None]                               # [b, h, t, d]
     return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)
+
+
+# ------------------------------------------ latent attention (MLA models)
+#
+# A latent-attention model caches one row a token a layer, ``[c_kv, k_r]``:
+# the normalised key-value latent (``C`` wide) and the one rotated key
+# (``R`` wide) that all heads share. Keys and values are ``c_kv`` times the
+# up-projection ``W_kvb``; the two functions below are the two orders in
+# which that product can be taken. One cache, two paths.
+
+
+def latent_decode_attention(q_lat: jax.Array, q_rope: jax.Array,
+                            cache, layer: int, positions: jax.Array, *,
+                            scale: float) -> jax.Array:
+    """One query a slot over the slot's latent pages, in the ABSORBED
+    form: the key up-projection is folded into the query (``q_lat = q_nope
+    W_kb^T``, by the caller), so a score is ``(q_lat . c_kv + q_rope .
+    k_r) * scale`` straight off the cached rows, and the result is the
+    probability-weighted LATENT ``[num_slots, heads, C]`` (float32), which
+    the caller takes through the value up-projection. No key or value of
+    any head is ever expanded for a cached token.
+
+    ``q_lat [num_slots, heads, C]``, ``q_rope [num_slots, heads, R]``;
+    ``cache`` a :class:`~apex_tpu.serve.kv_cache.PagedLatentCache` whose
+    layer ``layer`` holds rows ``C + R`` wide; slot ``b`` attends over
+    positions ``0 .. positions[b]``. The slot's whole virtual key axis is
+    gathered through the page table (unmapped entries read the null page,
+    behind the reachability mask); softmax in float32, the two products
+    in the cache's dtype with float32 accumulation."""
+    b, _, c = q_lat.shape
+    rows = cache.rows[layer, cache.page_table]         # [b, pages, ps, C+R]
+    rows = rows.reshape(b, -1, rows.shape[-1])
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(rows.dtype)
+    sc = jnp.einsum("bhw,bkw->bhk", q, rows,
+                    preferred_element_type=_f32) * jnp.float32(scale)
+    kpos = jnp.arange(rows.shape[1], dtype=jnp.int32)
+    reach = kpos[None, None, :] <= positions.astype(jnp.int32)[:, None, None]
+    sc = jnp.where(reach, sc, NEG_INF)
+    e = jnp.where(reach, jnp.exp(sc - sc.max(axis=-1, keepdims=True)), 0.0)
+    o = jnp.einsum("bhk,bkc->bhc", e.astype(rows.dtype), rows[..., :c],
+                   preferred_element_type=_f32)
+    return o / jnp.sum(e, axis=-1)[..., None]
+
+
+def latent_chunk_attention(q_nope: jax.Array, q_rope: jax.Array,
+                           k_nope: jax.Array, k_rope: jax.Array,
+                           v: jax.Array, w_kb: jax.Array, w_vb: jax.Array,
+                           cache, layer: int, start: jax.Array, *,
+                           scale: float) -> jax.Array:
+    """A chunk of ``T`` consecutive tokens a slot — the batched prefill of
+    a latent-attention model. The query at chunk position ``t`` of slot
+    ``b`` attends
+
+    (a) causally over the chunk's own tokens in the PLAIN form: their
+        keys ``[k_nope, k_rope]`` and values ``v`` expanded from the
+        chunk's latent rows by the caller (``k_nope``/``v`` ``[b, T,
+        heads, N]``/``[b, T, heads, V]``, ``k_rope [b, T, R]``: one
+        rotated key for all heads), as a read of the cache would give
+        them; and
+    (b) over the cached positions ``< start[b]`` (a prompt's head that
+        the prefix index served) in the absorbed form of
+        :func:`latent_decode_attention`, one page of latent rows at a
+        time, folded into (a)'s running max and sum. The loop's trip
+        count is data, and the whole of (b), its absorbed queries
+        included (``w_kb``/``w_vb`` ``[C, heads, N]``/``[C, heads, V]``:
+        the two halves of the up-projection), sits behind a ``cond``: a
+        call with no hit pays for none of it.
+
+    One max-subtracted float32 softmax over (a) and (b) together; (a)'s
+    products at ``HIGHEST`` precision as in :func:`chunk_attention`, (b)'s
+    as the decode step takes them.
+    Returns ``[b, T, heads, V]`` in ``q_nope.dtype``."""
+    b, t, h, _ = q_nope.shape
+    c = w_kb.shape[0]
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.float32(scale)
+    qn, qr = q_nope.astype(_f32), q_rope.astype(_f32)
+
+    idx = jnp.arange(t, dtype=jnp.int32)
+    causal = (idx[None, :] <= idx[:, None])[None, None]    # [1, 1, q, k]
+    sc = (jnp.einsum("bqhn,bkhn->bhqk", qn, k_nope.astype(_f32),
+                     precision=hi)
+          + jnp.einsum("bqhr,bkr->bhqk", qr, k_rope.astype(_f32),
+                       precision=hi)) * s
+    sc = jnp.where(causal, sc, NEG_INF)
+    m = sc.max(axis=-1)                                    # [b, h, t]
+    e = jnp.where(causal, jnp.exp(sc - m[..., None]), 0.0)
+    den = jnp.sum(e, axis=-1)
+    num = jnp.einsum("bhqk,bkhv->bhqv", e, v.astype(_f32), precision=hi)
+
+    start = start.astype(jnp.int32)
+    ps = cache.page_size
+
+    def over_the_head(carry):
+        # the decode step's numerics: absorbed queries and probabilities
+        # in the cache's dtype, float32 accumulation
+        dt = cache.rows.dtype
+        q = jnp.concatenate(
+            [jnp.einsum("bqhn,chn->bqhc", q_nope, w_kb,
+                        preferred_element_type=_f32).astype(dt),
+             q_rope.astype(dt)], axis=-1)                  # [b, t, h, C+R]
+
+        def body(i, carry):
+            m, den, num, lat = carry
+            pages = jax.lax.dynamic_index_in_dim(
+                cache.page_table, i, axis=1, keepdims=False)
+            rows = cache.rows[layer, pages]                # [b, ps, C+R]
+            kpos = i * ps + jnp.arange(ps, dtype=jnp.int32)
+            reach = (kpos[None, :] < start[:, None])[:, None, None, :]
+            sc = jnp.where(reach, jnp.einsum(
+                "bqhw,bkw->bhqk", q, rows,
+                preferred_element_type=_f32) * s, NEG_INF)
+            m_new = jnp.maximum(m, sc.max(axis=-1))
+            keep = jnp.exp(m - m_new)
+            e = jnp.where(reach, jnp.exp(sc - m_new[..., None]), 0.0)
+            return (m_new, den * keep + jnp.sum(e, axis=-1),
+                    num * keep[..., None],
+                    lat * keep[..., None] + jnp.einsum(
+                        "bhqk,bkc->bhqc", e.astype(dt), rows[..., :c],
+                        preferred_element_type=_f32))
+
+        m, den, num = carry
+        m, den, num, lat = jax.lax.fori_loop(
+            0, (jnp.max(start) + ps - 1) // ps, body,
+            (m, den, num, jnp.zeros((b, h, t, c), _f32)))
+        return m, den, num + jnp.einsum(
+            "bhqc,chv->bhqv", lat.astype(dt), w_vb,
+            preferred_element_type=_f32)
+
+    _, den, num = jax.lax.cond(jnp.max(start) > 0, over_the_head,
+                               lambda carry: carry, (m, den, num))
+    o = num / den[..., None]                               # [b, h, t, V]
+    return jnp.transpose(o, (0, 2, 1, 3)).astype(q_nope.dtype)

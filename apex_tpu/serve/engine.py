@@ -105,15 +105,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.models.gpt2 import (GPT2Config, gpt2_token_forward,
-                                  gpt2_token_forward_tp)
+from apex_tpu.models.gpt2 import GPT2Config, gpt2_token_forward_tp
 from apex_tpu.ops.pallas.tiling import pow2_ceil
 from apex_tpu.serve import kv_cache, paging
 from apex_tpu.serve import spec as serve_spec
 from apex_tpu.serve import tp as serve_tp
-from apex_tpu.serve.attention import resolve_block_k
-from apex_tpu.serve.kv_cache import (init_cache, init_paged_cache,
-                                     shard_cache, tp_cache_specs)
+from apex_tpu.serve.kv_cache import shard_cache, tp_cache_specs
+from apex_tpu.serve.model import serving_model
 from apex_tpu.serve.paging import PagePool, PrefixIndex
 from jax import shard_map
 # bound at module import, NOT function-locally (the scheduler's
@@ -126,7 +124,8 @@ from apex_tpu.utils.prof import annotate
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Serving-side knobs (the model config stays ``GPT2Config``)."""
+    """Serving-side knobs; the model's own config (``GPT2Config``,
+    ``DeepseekV3Config``) is the engine's first argument."""
 
     num_slots: int = 4
     max_len: Optional[int] = None      # default: model n_positions
@@ -188,24 +187,30 @@ class EngineConfig:
 
 
 class Engine:
-    """A servable GPT-2: static cache + compiled prefill/decode.
+    """A servable model: static cache + compiled prefill/decode.
 
-    ``params`` is the standard flax param pytree of ``models.gpt2.GPT2``
-    (``model.init(...)`` or a training checkpoint); serving casts to the
-    model config's ``compute_dtype`` on the fly. Use fp32 configs for
-    bit-exactness claims.
+    ``model_cfg`` names the model through the seam of
+    :mod:`apex_tpu.serve.model`: a ``GPT2Config`` (``params`` is then the
+    standard flax param pytree of ``models.gpt2.GPT2``, ``model.init(...)``
+    or a training checkpoint; serving casts to the model config's
+    ``compute_dtype`` on the fly; use fp32 configs for bit-exactness
+    claims), or any config that provides ``serving_model()``, such as
+    ``models.deepseek_v3.DeepseekV3Config`` (its weights are held in
+    ``compute_dtype``). A model refuses, at build, the engine modes it has
+    no mechanism for.
     """
 
-    def __init__(self, model_cfg: GPT2Config, params,
+    def __init__(self, model_cfg, params,
                  config: EngineConfig = EngineConfig(), *, seed: int = 0):
         self.model_cfg = model_cfg
+        self.model = serving_model(model_cfg)
         self.config = config
         self.params = params
-        self.max_len = int(config.max_len or model_cfg.n_positions)
-        if self.max_len > model_cfg.n_positions:
+        self.max_len = int(config.max_len or self.model.max_positions)
+        if self.max_len > self.model.max_positions:
             raise ValueError(
                 f"max_len={self.max_len} exceeds the model's "
-                f"n_positions={model_cfg.n_positions}")
+                f"n_positions={self.model.max_positions}")
         self._paged = config.page_size is not None
         if self._paged:
             ps = int(config.page_size)
@@ -228,7 +233,8 @@ class Engine:
                 "(prefix sharing is page-granular)")
         elif config.num_pages is not None:
             raise ValueError("num_pages needs page_size (paged mode)")
-        h, d = model_cfg.n_head, model_cfg.n_embd // model_cfg.n_head
+        self.model.refuse(config, self._paged)
+        h = self.model.heads
         # tensor-parallel mesh (docs/serving.md "Tensor-parallel
         # decode"): every geometry error is a build-time ValueError,
         # never a bad lowering
@@ -275,11 +281,7 @@ class Engine:
         # sharded engine tunes at its PER-SHARD head count with the
         # shard count as its own key axis (winners never leak across
         # mesh shapes)
-        self.block_k = resolve_block_k(self.max_len, h // self._tp, d,
-                                       model_cfg.compute_dtype,
-                                       config.block_k,
-                                       page_size=config.page_size,
-                                       tp_shards=self._tp)
+        self.block_k = self.model.block_k(self.max_len, config, self._tp)
         # speculative decoding + the DecodePolicy seam: every bad knob is
         # a build-time ValueError (both CLIs surface them as exit 2
         # before any compile)
@@ -307,7 +309,7 @@ class Engine:
             from apex_tpu.quant.kv import check_kv_codec
 
             check_kv_codec(self._kv_quant)
-            if model_cfg.compute_dtype != jnp.float32:
+            if self.model.compute_dtype != jnp.float32:
                 raise ValueError(
                     f"kv_quant={self._kv_quant!r} requires "
                     f"compute_dtype=float32: the quantization quality "
@@ -395,8 +397,7 @@ class Engine:
         data = (tokens, positions, mask) \
             + (() if logits_at is None else (logits_at,))
         if self.mesh is None:
-            return gpt2_token_forward(self.model_cfg, weights, cache,
-                                      *data, **kw)
+            return self.model.forward(weights, cache, *data, **kw)
         # tensor-parallel: the SAME call sites (decode_fn, prefill_fn,
         # the verify scan body) lower the per-rank forward under
         # shard_map — the cache rides in head-sharded, the page
@@ -423,13 +424,14 @@ class Engine:
                    pol=None):
         self.decode_traces += 1          # python side effect: trace count
         positions = cache.lengths
-        logits, cache = self._token_step(weights, cache, last_tokens,
-                                         positions, active)
+        # a model's counters, where it returns any, ride out last
+        logits, cache, *counters = self._token_step(
+            weights, cache, last_tokens, positions, active)
         with jax.named_scope("sampling"):
             rng, sub = jax.random.split(rng)
             next_tokens = self._sample(logits, sub, pol)
         cache = kv_cache.advance(cache, active)
-        return next_tokens, logits, cache, rng
+        return (next_tokens, logits, cache, rng, *counters)
 
     def _make_prefill(self, bucket: int):
         """The ``prefill_<bucket>`` program: ONE ``[num_slots, bucket]``
@@ -448,7 +450,7 @@ class Engine:
             # shared pages (start == 0 and tail == prompt otherwise)
             write = admit[:, None] & (t < tail_lens[:, None])
             last = jnp.maximum(tail_lens - 1, 0)
-            logits, cache = self._token_step(
+            logits, cache, *counters = self._token_step(
                 weights, cache, tokens, start[:, None] + t, write,
                 None if keep else last)
             if keep:
@@ -461,7 +463,8 @@ class Engine:
             with jax.named_scope("sampling"):
                 rng, sub = jax.random.split(rng)
                 first_tokens = self._sample(last_logits, sub, pol)
-            return cache, first_tokens, last_logits, all_logits, rng
+            return (cache, first_tokens, last_logits, all_logits, rng,
+                    *counters)
 
         return jax.jit(prefill_fn)
 
@@ -627,15 +630,12 @@ class Engine:
     def _init_state(self, seed: int) -> None:
         """ALL mutable serving state lives here (shared by __init__ and
         :meth:`reset` so a drain/restart can never miss a field)."""
-        h = self.model_cfg.n_head
-        d = self.model_cfg.n_embd // h
         b = self.config.num_slots
+        self.cache: Any = self.model.init_cache(
+            b, self.max_len, self.config.page_size,
+            self._num_pages if self._paged else None, self._kv_quant)
         if self._paged:
             ps = int(self.config.page_size)
-            self.cache: Any = init_paged_cache(
-                self.model_cfg.n_layer, b, self.max_len, ps,
-                self._num_pages, h, d, self.model_cfg.compute_dtype,
-                kv_quant=self._kv_quant)
             self.pool: Optional[PagePool] = PagePool(self._num_pages, ps)
             self.prefix: Optional[PrefixIndex] = \
                 PrefixIndex(ps) if self.config.prefix_cache else None
@@ -645,9 +645,6 @@ class Engine:
             # admission × page_size); slot engines use max_len flat
             self._slot_capacity = np.zeros((b,), np.int64)
         else:
-            self.cache = init_cache(
-                self.model_cfg.n_layer, b, self.max_len, h, d,
-                self.model_cfg.compute_dtype, kv_quant=self._kv_quant)
             self.pool = None
             self.prefix = None
             self._slot_pages = [[] for _ in range(b)]
@@ -818,6 +815,16 @@ class Engine:
             attrs["pages"] = self.pool.capacity
         return attrs
 
+    def _note_counters(self, span: str, counters, real_rows: int) -> None:
+        """Where the model's forward returned counters (an expert
+        model's routing), fetch them, now that the call's tokens are
+        here, and leave them as the attributes of ``<span>.routing``,
+        inside the call's own span."""
+        if counters:
+            with annotate(span + ".routing", **self.model.call_counters(
+                    np.asarray(counters[0]), real_rows)):
+                pass
+
     def prefill(self, prompts: Dict[int, Sequence[int]], *,
                 budgets: Optional[Dict[int, int]] = None,
                 cacheable: Optional[Dict[int, int]] = None):
@@ -881,13 +888,14 @@ class Engine:
                         jnp.asarray(lens), self.rng)
                 if self._policy is not None:
                     args += (self._policy_args(),)
-                self.cache, first, last_logits, all_logits, self.rng = \
-                    fn(*args)
+                (self.cache, first, last_logits, all_logits, self.rng,
+                 *counters) = fn(*args)
             self.prefill_calls += 1
             self.prefill_requests += len(prompts)
             self.prefill_scanned_tokens += int(bucket)
             with annotate("apex.prefill.fetch"):
                 first_np = np.asarray(first)
+            self._note_counters("apex.prefill", counters, int(lens.sum()))
             self.last_tokens = np.where(admit, first_np, self.last_tokens)
             full_lens = starts + lens
             self._host_lengths = np.where(admit, full_lens,
@@ -1011,10 +1019,13 @@ class Engine:
                 args = (self._weights, self.cache, lt, act, self.rng)
                 if self._policy is not None:
                     args += (self._policy_args(),)
-                next_tokens, logits, self.cache, self.rng = fn(*args)
+                next_tokens, logits, self.cache, self.rng, *counters = \
+                    fn(*args)
             self.decode_calls += 1
             with annotate("apex.decode_step.fetch"):
                 next_np = np.asarray(next_tokens)
+            self._note_counters("apex.decode_step", counters,
+                                int(act_np.sum()))
             self.last_tokens = np.where(act_np, next_np, self.last_tokens)
             self._host_lengths = self._host_lengths + act_np
             return next_np, logits
@@ -1144,6 +1155,7 @@ class Engine:
         """
         if not self._paged or self.prefix is None:
             return []
+        self._refuse_page_migration()
         out = []
         for h, page in self.prefix.lookup(tokens, touch=False):
             k_np = np.asarray(jax.device_get(self.cache.k[:, page]))
@@ -1193,10 +1205,10 @@ class Engine:
                 "import_prefix_pages needs a paged engine with "
                 "prefix_cache=True (page migration lands in the prefix "
                 "index)")
+        self._refuse_page_migration()
         ps = int(self.config.page_size)
-        h_heads = self.model_cfg.n_head
-        d = self.model_cfg.n_embd // h_heads
-        shape = (self.model_cfg.n_layer, ps, h_heads, d)
+        shape = (self.model.n_layer, ps, self.model.heads,
+                 self.model.head_dim)
         stats = {"installed": 0, "duplicate": 0, "no_capacity": 0}
         for p in payloads:
             if tuple(np.shape(p["k"])) != shape or \
@@ -1243,6 +1255,14 @@ class Engine:
                           codec=self._kv_quant)
         return stats
 
+    def _refuse_page_migration(self) -> None:
+        if not hasattr(self.cache, "k"):
+            raise ValueError(
+                f"{self.model.name} pages do not migrate: export/import, "
+                f"their payload digest and kv_cache.install_page move a "
+                f"page's K and V arrays, and a latent pool has one array "
+                f"of rows (prefix sharing inside the engine is unaffected)")
+
     @property
     def lengths(self) -> np.ndarray:
         return np.asarray(self.cache.lengths)
@@ -1263,7 +1283,7 @@ class Engine:
         actual lowering via :meth:`decode_collectives`."""
         if self._tp == 1:
             return {"all_gather": 0, "all_reduce": 0}
-        return serve_tp.expected_collectives(self.model_cfg.n_layer,
+        return serve_tp.expected_collectives(self.model.n_layer,
                                              self.config.tp_sync)
 
     def decode_collectives(self) -> Dict[str, int]:
@@ -1313,9 +1333,9 @@ class Engine:
         if self._spec_k:
             execs["verify"] = costs.executable_record(
                 self._verify_lowered, self._verify_aot)
-        dtype = jnp.dtype(self.model_cfg.compute_dtype)
+        dtype = jnp.dtype(self.model.compute_dtype)
         workload = {
-            "model": "gpt2",
+            "model": self.model.name,
             "num_slots": int(self.config.num_slots),
             "max_len": int(self.max_len),
             "page_size": int(self.config.page_size or 0),
@@ -1324,10 +1344,7 @@ class Engine:
             "block_k": int(self.block_k),
             "tp": int(self._tp),
             "tp_sync": self.config.tp_sync if self._tp > 1 else None,
-            "n_layer": int(self.model_cfg.n_layer),
-            "n_embd": int(self.model_cfg.n_embd),
-            "n_head": int(self.model_cfg.n_head),
-            "vocab_size": int(self.model_cfg.vocab_size),
+            **self.model.workload(),
             "spec_draft_len": int(self._spec_k),
             "decode_policy": self.config.decode_policy,
             "kv_quant": self._kv_quant,
@@ -1378,7 +1395,7 @@ class Engine:
         different blocks are incomparable."""
         if self._kv_quant is None:
             return 0
-        return int(self.model_cfg.n_embd // self.model_cfg.n_head)
+        return int(self.model.head_dim)
 
     @property
     def kv_cache_bytes(self) -> int:
@@ -1389,11 +1406,7 @@ class Engine:
         its scale overhead); stamped into the serving AOT
         ``hbm_snapshot`` and the bench's
         ``resident_tokens_per_hbm_byte`` so captures carry it."""
-        total = int(self.cache.k.nbytes) + int(self.cache.v.nbytes)
-        if self.cache.k_scale is not None:
-            total += int(self.cache.k_scale.nbytes)
-            total += int(self.cache.v_scale.nbytes)
-        return total
+        return kv_cache.cache_bytes(self.cache)
 
 
 @functools.lru_cache(maxsize=8)

@@ -20,6 +20,13 @@ jitted decode step that closes over either pytree compiles exactly once:
   null page: masked-off writes are routed there and unmapped table
   entries read its zeros (discarded by the attention reachability mask).
 
+- :class:`PagedLatentCache` — the paged pool of a latent-attention (MLA)
+  model: ONE array ``rows`` ``[n_layer, num_pages, page_size, width]``
+  (a token's normalised key-value latent and its one rotated key, shared
+  by every head: no head axis, no second array) with the same
+  ``page_table`` and ``lengths``, so the allocator, the prefix index and
+  every length mutator below serve it unchanged.
+
 All mutators are pure functions returning a new cache (the engine's
 jitted callables donate nothing and alias nothing). Masked writes
 read-modify-write the existing token so an inactive slot's bytes are
@@ -416,24 +423,36 @@ def shard_cache(cache, mesh, axis: str = "tp"):
     return out
 
 
+def _token_arrays(cache) -> tuple:
+    """Names of the cache's arrays that hold tokens (axis 1 is the slot,
+    or the page of a paged layout)."""
+    if hasattr(cache, "rows"):
+        return ("rows",)
+    return ("k", "v") + (("k_scale", "v_scale")
+                         if cache.k_scale is not None else ())
+
+
+def cache_bytes(cache) -> int:
+    """Resident bytes of the cache's token storage (scale planes
+    included), whatever the layout."""
+    return sum(int(getattr(cache, name).nbytes)
+               for name in _token_arrays(cache))
+
+
 # host-callable copy-on-write: ONE jitted op (page indices are traced
 # scalars), compiled once per engine — sharing a partially-used prefix
 # page costs a page copy, never a recompile
 @jax.jit
-def copy_page(cache: PagedKVCache, src, dst) -> PagedKVCache:
-    """Copy page ``src`` onto page ``dst`` across every layer, both K and
-    V — the copy-on-write that gives a slot its own writable copy of a
-    shared prefix page whose tail it must append into."""
+def copy_page(cache, src, dst):
+    """Copy page ``src`` onto page ``dst`` across every layer and every
+    paged array of the cache (K and V with their scale planes, or the
+    latent rows) — the copy-on-write that gives a slot its own writable
+    copy of a shared prefix page whose tail it must append into."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    out = cache.replace(
-        k=cache.k.at[:, dst].set(cache.k[:, src]),
-        v=cache.v.at[:, dst].set(cache.v[:, src]))
-    if cache.k_scale is not None:
-        out = out.replace(
-            k_scale=cache.k_scale.at[:, dst].set(cache.k_scale[:, src]),
-            v_scale=cache.v_scale.at[:, dst].set(cache.v_scale[:, src]))
-    return out
+    return cache.replace(**{
+        name: getattr(cache, name).at[:, dst].set(getattr(cache, name)[:, src])
+        for name in _token_arrays(cache)})
 
 
 # host-callable page install: ONE jitted op (the page index is a traced
@@ -464,3 +483,84 @@ def install_page(cache: PagedKVCache, page, k_page: jax.Array,
             v_scale=cache.v_scale.at[:, page].set(
                 v_scale_page.astype(cache.v_scale.dtype)))
     return out
+
+
+# ------------------------------------------- the latent pool (MLA models)
+
+
+@flax.struct.dataclass
+class PagedLatentCache:
+    """Pytree of a latent-attention model's paged cache: one row a token
+    a layer, ``[c_kv, k_rope]`` (for DeepSeek-V3 widths 512 + 64 = 576),
+    which every head reads. Page indices, the null page and ``lengths``
+    mean what they mean in :class:`PagedKVCache`."""
+
+    rows: jax.Array        # [n_layer, num_pages, page_size, width]
+    lengths: jax.Array     # [num_slots] int32 — tokens resident per slot
+    page_table: jax.Array  # [num_slots, max_pages_per_slot] int32
+
+    @property
+    def n_layer(self) -> int:
+        return self.rows.shape[0]
+
+    @property
+    def num_pages(self) -> int:
+        return self.rows.shape[1]
+
+    @property
+    def page_size(self) -> int:
+        return self.rows.shape[2]
+
+    @property
+    def num_slots(self) -> int:
+        return self.page_table.shape[0]
+
+    @property
+    def max_pages_per_slot(self) -> int:
+        return self.page_table.shape[1]
+
+    @property
+    def max_len(self) -> int:
+        return self.page_size * self.max_pages_per_slot
+
+
+def init_paged_latent_cache(n_layer: int, num_slots: int, max_len: int,
+                            page_size: int, num_pages: int, width: int,
+                            dtype: Any = jnp.float32) -> PagedLatentCache:
+    """Allocate an empty latent pool; the geometry rules are
+    :func:`init_paged_cache`'s."""
+    if max_len % page_size:
+        raise ValueError(
+            f"page_size={page_size} must divide max_len={max_len}")
+    max_pages = max_len // page_size
+    if num_pages < max_pages + 1:
+        raise ValueError(
+            f"num_pages={num_pages} cannot hold one full-context request "
+            f"plus the null page (need >= {max_pages + 1})")
+    return PagedLatentCache(
+        rows=jnp.zeros((n_layer, num_pages, page_size, width), dtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        page_table=jnp.zeros((num_slots, max_pages), jnp.int32))
+
+
+@jax.named_scope("kv_write")
+def write_latent(cache: PagedLatentCache, layer: int, rows: jax.Array,
+                 positions: jax.Array, mask: jax.Array) -> PagedLatentCache:
+    """Append latent rows through the page table, one token a slot
+    (``rows [num_slots, width]``, ``positions``/``mask`` ``[num_slots]``:
+    the decode step) or a chunk a slot (``[num_slots, T, width]`` and
+    ``[num_slots, T]``: the batched prefill), in one masked scatter. Row
+    ``(b, t)`` lands at virtual position ``positions[b, t]`` of slot
+    ``b``; a masked-off row is given an out-of-range page and DROPPED,
+    as :func:`write_rows` drops it, so no byte of a neighbour, of the
+    null page or of a row past the prompt is touched."""
+    pos = positions.astype(jnp.int32)
+    live = mask & (pos >= 0) & (pos < cache.max_len)
+    slot = jnp.arange(pos.shape[0], dtype=jnp.int32).reshape(
+        (-1,) + (1,) * (pos.ndim - 1))
+    ps = cache.page_size
+    pages = cache.page_table[
+        slot, jnp.clip(pos // ps, 0, cache.max_pages_per_slot - 1)]
+    index = (layer, jnp.where(live, pages, cache.num_pages), pos % ps)
+    return cache.replace(rows=cache.rows.at[index].set(
+        rows.astype(cache.rows.dtype), mode="drop"))

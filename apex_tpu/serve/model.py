@@ -1,0 +1,169 @@
+"""The model seam: what a model gives ``serve.Engine``.
+
+The engine keeps the two compiled programs (``decode_fn``, ``prefill_fn``),
+the sampler, the page planner, the scheduler's contract and the spans. A
+model gives it, through one object:
+
+- ``name``, ``cfg``, ``max_positions``, ``n_layer``, ``vocab_size``,
+  ``compute_dtype``, and ``workload()``: what a cost ledger says of it;
+- ``refuse(config, paged)``: a build-time ``ValueError`` for every engine
+  mode the model has no mechanism for, naming the mechanism;
+- ``block_k(max_len, config, tp)``: the decode-attention chunk, resolved
+  once at build;
+- ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant)``: its
+  cache pytree, with ``lengths`` (and ``page_table`` where paged) as
+  ``serve/kv_cache.py`` and ``serve/paging.py`` expect them;
+- ``forward(weights, cache, tokens, positions, mask, logits_at=None, *,
+  block_k, kv_quant, final_scope)``: its token forward in the two shapes
+  (``[slots]``: decode and the verify scan's body; ``[slots, T]`` with
+  ``logits_at``: prefill). Returns ``(logits, cache)``, or ``(logits,
+  cache, counters)`` with ``counters`` a small int32 array the programs
+  hand back beside their results; a model that returns them also gives
+  ``call_counters(counters, real_rows)``, the attributes of the span
+  ``apex.<call>.routing`` the engine leaves them on.
+
+``serving_model(cfg)`` finds the object: a ``GPT2Config`` gets
+:class:`GPT2Serving`; any other config provides ``cfg.serving_model()``.
+The tensor-parallel per-rank forward is not behind the seam: it is
+GPT-2's (``serve/tp.py``), and a model without one refuses ``tp > 1``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+from apex_tpu.models.gpt2 import GPT2Config, gpt2_token_forward
+from apex_tpu.serve import kv_cache
+from apex_tpu.serve.attention import resolve_block_k
+
+
+class GPT2Serving:
+    """GPT-2 of any size: both cache layouts, every engine mode."""
+
+    name = "gpt2"
+
+    def __init__(self, cfg: GPT2Config):
+        self.cfg = cfg
+        self.max_positions = cfg.n_positions
+        self.n_layer, self.vocab_size = cfg.n_layer, cfg.vocab_size
+        self.compute_dtype = cfg.compute_dtype
+        self.heads, self.head_dim = cfg.n_head, cfg.n_embd // cfg.n_head
+
+    def refuse(self, config, paged: bool) -> None:
+        return None
+
+    def block_k(self, max_len: int, config, tp: int) -> int:
+        return resolve_block_k(max_len, self.heads // tp, self.head_dim,
+                               self.compute_dtype, config.block_k,
+                               page_size=config.page_size, tp_shards=tp)
+
+    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant):
+        if page_size is None:
+            return kv_cache.init_cache(
+                self.n_layer, num_slots, max_len, self.heads, self.head_dim,
+                self.compute_dtype, kv_quant=kv_quant)
+        return kv_cache.init_paged_cache(
+            self.n_layer, num_slots, max_len, page_size, num_pages,
+            self.heads, self.head_dim, self.compute_dtype, kv_quant=kv_quant)
+
+    def forward(self, weights, cache, *data, **kw):
+        return gpt2_token_forward(self.cfg, weights, cache, *data, **kw)
+
+    def workload(self) -> Dict[str, Any]:
+        return {"n_layer": int(self.cfg.n_layer),
+                "n_embd": int(self.cfg.n_embd),
+                "n_head": int(self.cfg.n_head),
+                "vocab_size": int(self.cfg.vocab_size)}
+
+
+class DeepseekV3Serving:
+    """``deepseek_v3`` (``models/deepseek_v3.py``) as one rank of an
+    expert-parallel deployment: the paged latent cache, one chip."""
+
+    name = "deepseek_v3"
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.max_positions = cfg.max_position_embeddings
+        self.n_layer, self.vocab_size = cfg.num_hidden_layers, cfg.vocab
+        self.compute_dtype = cfg.compute_dtype
+        self.heads, self.head_dim = (cfg.num_attention_heads,
+                                     cfg.latent_width)
+
+    def refuse(self, config, paged: bool) -> None:
+        missing = []
+        if not paged:
+            missing.append("page_size=None: the latent cache exists only "
+                           "as a paged pool (kv_cache.PagedLatentCache has "
+                           "no slot-contiguous twin)")
+        if config.tp > 1:
+            missing.append(f"tp={config.tp}: there is no per-rank forward "
+                           f"that shards MLA's heads and no head axis in "
+                           f"the latent cache to shard (serve/tp.py is "
+                           f"GPT-2's)")
+        if config.spec_draft_len:
+            missing.append(f"spec_draft_len={config.spec_draft_len}: the "
+                           f"verify scan's acceptance oracle is unproven "
+                           f"for a routed model, whose logits move with "
+                           f"the batch's rounding at a router near-tie")
+        if config.kv_quant is not None:
+            missing.append(f"kv_quant={config.kv_quant!r}: the block-scale "
+                           f"codec scales one (token, head) vector, and a "
+                           f"latent row has no head axis")
+        if missing:
+            raise ValueError(
+                "deepseek_v3 is not served with " + "; ".join(missing))
+
+    def block_k(self, max_len: int, config, tp: int) -> int:
+        # latent attention reads whole pages; the knob is accepted where
+        # it names one
+        if config.block_k not in (None, config.page_size):
+            raise ValueError(
+                f"block_k={config.block_k}: deepseek_v3's latent attention "
+                f"reads a page at a time (page_size={config.page_size})")
+        return int(config.page_size)
+
+    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant):
+        return kv_cache.init_paged_latent_cache(
+            self.n_layer, num_slots, max_len, page_size, num_pages,
+            self.cfg.latent_width, self.compute_dtype)
+
+    def forward(self, weights, cache, *data, block_k=None, kv_quant=None,
+                final_scope="sampling"):
+        from apex_tpu.models.deepseek_v3 import deepseek_v3_token_forward
+
+        return deepseek_v3_token_forward(self.cfg, weights, cache, *data,
+                                         final_scope=final_scope)
+
+    def call_counters(self, counters, real_rows: int) -> Dict[str, int]:
+        """What an engine call's span says of its routing: the two
+        counters the program returned, and what they are shares of."""
+        c = self.cfg
+        layers = c.num_hidden_layers - c.first_k_dense_replace
+        picks_here, experts_hit = (int(v) for v in counters)
+        return {"picks_here": picks_here, "experts_hit": experts_hit,
+                "experts_held": c.held * layers,
+                "picks": real_rows * c.num_experts_per_tok * layers}
+
+    def workload(self) -> Dict[str, Any]:
+        c = self.cfg
+        return {"n_layer": int(c.num_hidden_layers),
+                "n_embd": int(c.hidden_size),
+                "n_head": int(c.num_attention_heads),
+                "vocab_size": int(c.vocab),
+                "experts_held": int(c.held),
+                "n_routed_experts": int(c.n_routed_experts)}
+
+
+def serving_model(model_cfg):
+    """The seam object for a model config (see the module docstring)."""
+    if isinstance(model_cfg, GPT2Config):
+        return GPT2Serving(model_cfg)
+    if hasattr(model_cfg, "serving_model"):
+        return model_cfg.serving_model()
+    raise TypeError(
+        f"{type(model_cfg).__name__} is no servable model config: it is "
+        f"neither a GPT2Config nor provides serving_model()")
+
+
+__all__ = ["GPT2Serving", "DeepseekV3Serving", "serving_model"]

@@ -22,10 +22,12 @@ no launch overhead to amortize, so no Pallas kernel is needed for this op.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _f32 = jnp.float32
 
@@ -160,3 +162,51 @@ def fused_rope_2d(t: jax.Array, img_h: int, img_w: int,
     out_w = _rope_cached(t_w, jnp.cos(fw)[None, :, None, :],
                          jnp.sin(fw)[None, :, None, :])
     return jnp.concatenate([out_h, out_w, rest], axis=-1)
+
+
+# --------------------------------------------------- YaRN, interleaved pairs
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies under YaRN scaling (Peng et al.,
+    arXiv:2309.00071, as the published ``rope_type: yarn`` computes them),
+    float64 on the host: a constant of the traced program.
+
+    Pair ``i`` turns ``theta ** (-2i / dim)`` radians a position unscaled.
+    A pair that makes more than ``beta_fast`` turns over the original
+    context keeps that frequency; one that makes fewer than ``beta_slow``
+    has it divided by ``factor`` (positions interpolated); between the two
+    correction dimensions, ``dim * ln(original / (2 pi beta)) / (2 ln
+    theta)`` floored and ceiled, the two are blended by a linear ramp.
+    """
+    base = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(turns):
+        return (dim * math.log(original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return base / factor * ramp + base * (1.0 - ramp)
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array,
+                     inv_freq) -> jax.Array:
+    """Rotate the pairs ``(2i, 2i + 1)`` of ``x``'s last axis by
+    ``positions * inv_freq[i]`` radians. ``x``: ``[..., d]`` with ``d == 2
+    * len(inv_freq)``; ``positions`` has ``x``'s leading shape up to
+    broadcasting (``[rows]`` against ``[rows, heads, d]`` takes a
+    ``[:, None]``). Float32 inside, ``x``'s dtype out. The published
+    DeepSeek-V3 code de-interleaves the pairs and then rotates halves:
+    the same rotation in another order of the channels, so every
+    query-key product is the same."""
+    angle = positions.astype(_f32)[..., None] * jnp.asarray(inv_freq, _f32)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(_f32).reshape(x.shape[:-1] + (-1, 2))
+    even, odd = x32[..., 0], x32[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], -1)
+    return out.reshape(x.shape).astype(x.dtype)
