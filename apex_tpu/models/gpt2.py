@@ -165,20 +165,26 @@ def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
     head_dim]`` with ``rows = pos.size``; ``pos``/``write_mask``:
     ``[num_slots]`` (one token a slot: appended at ``pos``, attending
     over cached ``0..pos``) or ``[num_slots, T]`` (a chunk a slot).
-    Returns ``(o [rows, heads, head_dim], cache)``."""
+    Returns ``(o [rows, heads, head_dim], cache)``. The cache's head
+    axis is allocated in whole tiles (``kv_cache.padded_heads``): the
+    queries are padded with zero heads to meet it, attention runs over
+    all of them (the same tiles either way), and the padding's outputs
+    are dropped."""
     from apex_tpu.serve.attention import (cached_attention,
                                           chunk_attention, paged_attention)
-    from apex_tpu.serve.kv_cache import (paged_write_token, write_rows,
-                                         write_token)
+    from apex_tpu.serve.kv_cache import (pad_heads, paged_write_token,
+                                         write_rows, write_token)
 
+    heads = q.shape[-2]
+    q = pad_heads(q, cache.k.shape[-2])
     if pos.ndim == 2:
-        rows = pos.shape + q.shape[1:]
+        rows = pos.shape + k.shape[1:]
         cache, k_read, v_read = write_rows(
             cache, layer, k.reshape(rows), v.reshape(rows), pos,
             write_mask, codec=kv_quant)
-        o = chunk_attention(q.reshape(rows), k_read, v_read, cache, layer,
-                            pos[:, 0], block_k=block_k)
-        return o.reshape(q.shape), cache
+        o = chunk_attention(q.reshape(pos.shape + q.shape[1:]), k_read,
+                            v_read, cache, layer, pos[:, 0], block_k=block_k)
+        return o.reshape(q.shape)[:, :heads], cache
     # layout dispatch is structural, NOT isinstance: these imports are
     # function-local (the serve package imports this module), so a
     # purge-and-reimport of apex_tpu.serve.kv_cache mid-process would
@@ -197,7 +203,7 @@ def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
     o = attend(block_k=block_k,
                k_scale=None if kv_quant is None else cache.k_scale[layer],
                v_scale=None if kv_quant is None else cache.v_scale[layer])
-    return o, cache
+    return o[:, :heads], cache
 
 
 def _final_logits(x, p, dt, shape, logits_at):

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.pallas.tiling import decode_attention_block
+from apex_tpu.serve.kv_cache import pad_last
 from apex_tpu.tune.api import tuned_params
 
 _f32 = jnp.float32
@@ -368,15 +369,17 @@ def latent_decode_attention(q_lat: jax.Array, q_rope: jax.Array,
 
     ``q_lat [num_slots, heads, C]``, ``q_rope [num_slots, heads, R]``;
     ``cache`` a :class:`~apex_tpu.serve.kv_cache.PagedLatentCache` whose
-    layer ``layer`` holds rows ``C + R`` wide; slot ``b`` attends over
+    layer ``layer`` holds rows ``C + R`` wide in whole lanes (the query
+    is widened with zeros to meet them); slot ``b`` attends over
     positions ``0 .. positions[b]``. The slot's whole virtual key axis is
     gathered through the page table (unmapped entries read the null page,
     behind the reachability mask); softmax in float32, the two products
     in the cache's dtype with float32 accumulation."""
     b, _, c = q_lat.shape
-    rows = cache.rows[layer, cache.page_table]         # [b, pages, ps, C+R]
+    rows = cache.rows[layer, cache.page_table]         # [b, pages, ps, row]
     rows = rows.reshape(b, -1, rows.shape[-1])
-    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(rows.dtype)
+    q = pad_last(jnp.concatenate([q_lat, q_rope], axis=-1),
+                  rows.shape[-1]).astype(rows.dtype)
     sc = jnp.einsum("bhw,bkw->bhk", q, rows,
                     preferred_element_type=_f32) * jnp.float32(scale)
     kpos = jnp.arange(rows.shape[1], dtype=jnp.int32)
@@ -441,16 +444,17 @@ def latent_chunk_attention(q_nope: jax.Array, q_rope: jax.Array,
         # the decode step's numerics: absorbed queries and probabilities
         # in the cache's dtype, float32 accumulation
         dt = cache.rows.dtype
-        q = jnp.concatenate(
+        q = pad_last(jnp.concatenate(
             [jnp.einsum("bqhn,chn->bqhc", q_nope, w_kb,
                         preferred_element_type=_f32).astype(dt),
-             q_rope.astype(dt)], axis=-1)                  # [b, t, h, C+R]
+             q_rope.astype(dt)], axis=-1),
+            cache.rows.shape[-1])                          # [b, t, h, row]
 
         def body(i, carry):
             m, den, num, lat = carry
             pages = jax.lax.dynamic_index_in_dim(
                 cache.page_table, i, axis=1, keepdims=False)
-            rows = cache.rows[layer, pages]                # [b, ps, C+R]
+            rows = cache.rows[layer, pages]                # [b, ps, row]
             kpos = i * ps + jnp.arange(ps, dtype=jnp.int32)
             reach = (kpos[None, :] < start[:, None])[:, None, None, :]
             sc = jnp.where(reach, jnp.einsum(

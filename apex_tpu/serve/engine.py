@@ -2,7 +2,15 @@
 paged).
 
 The engine owns three compiled artifacts and NOTHING else touches the
-device:
+device. It owns the cache's buffers too: **the pool is resident**.
+Every program below that takes the cache and returns it is given it by
+donation, and the pool's shape (whole tiles a token: ``kv_cache``, "the
+resident pool") makes the runtime's default layout the one the programs
+work in, so a call's writes land in the buffers it was handed and no
+call copies a pool; whoever kept ``engine.cache`` across a call holds
+deleted arrays, and a call that fails after the donation raises
+:class:`PoolLost` over a re-initialised pool (docs/serving.md, "Who owns
+the pool"):
 
 - ``decode_step`` — ONE jitted function, ``[num_slots]`` tokens in,
   ``[num_slots]`` sampled tokens out. Admission, completion, eviction, and
@@ -24,7 +32,7 @@ device:
   under full-sequence prefill to rounding, not to the bit
   (docs/serving.md has what stays bit-exact).
 - ``evict`` — a mask-shaped length reset (kv_cache.evict_slots), one
-  compile total.
+  compile total; only ``lengths`` goes through it.
 
 **Paged mode** (``EngineConfig(page_size=...)``) swaps the per-slot
 ``max_len`` reservation for a shared block pool
@@ -186,6 +194,22 @@ class EngineConfig:
     kv_quant: Optional[str] = None
 
 
+class PoolLost(RuntimeError):
+    """A serving call raised after the cache had been donated to it: the
+    pool's buffers are gone, the engine has re-initialised the pool
+    empty, and every request that was resident must be re-prefilled
+    (``ServeScheduler.recover``) or failed (docs/serving.md, "Who owns
+    the pool")."""
+
+
+def _donating_jit(fn, cache_arg: int):
+    """``jax.jit`` of a program that takes the cache as positional
+    argument ``cache_arg`` and returns it: the cache is donated, so the
+    program writes into the buffers it was handed. The caller rebinds its
+    cache to the result; the arrays it passed are deleted."""
+    return jax.jit(fn, donate_argnums=cache_arg)
+
+
 class Engine:
     """A servable model: static cache + compiled prefill/decode.
 
@@ -198,6 +222,10 @@ class Engine:
     ``models.deepseek_v3.DeepseekV3Config`` (its weights are held in
     ``compute_dtype``). A model refuses, at build, the engine modes it has
     no mechanism for.
+
+    ``engine.cache`` is the engine's to rebind: a serving call donates
+    it and binds the call's result, so read it after a call, never
+    across one.
     """
 
     def __init__(self, model_cfg, params,
@@ -334,7 +362,11 @@ class Engine:
         self.prefill_traces = 0
         self.verify_traces = 0
 
-        self._decode = jax.jit(self._decode_fn)
+        # per program, what its compiled text says of the pool
+        # (kv_cache.pool_facts); filled where the engine holds the
+        # executable, at aot_compile
+        self._pool_facts: Dict[Any, Dict[str, int]] = {}
+        self._decode = _donating_jit(self._decode_fn, 1)
         self._decode_aot = None
         self._decode_lowered = None    # kept so collective counting and
         #                                postmortems never re-trace
@@ -344,13 +376,15 @@ class Engine:
         #                                contract as _decode_lowered: the
         #                                cost ledger reads prefill costs
         #                                without re-lowering after reset()
-        self._verify = jax.jit(self._make_verify()) if self._spec_k \
-            else None
+        self._verify = self._make_verify() if self._spec_k else None
         self._verify_aot = None
         self._verify_lowered = None    # retention contract shared with
         #                                _decode_lowered: cost_ledger()
         #                                prices verify after reset()
         #                                without ever re-tracing
+        # the host-called mutators, given the pool as the programs are
+        self._copy_page = _donating_jit(kv_cache.copy_page, 0)
+        self._install_page = _donating_jit(kv_cache.install_page, 0)
         if self._tp > 1:
             publish_event(
                 "serve_tp_mesh_ready", tp=self._tp,
@@ -431,14 +465,14 @@ class Engine:
             rng, sub = jax.random.split(rng)
             next_tokens = self._sample(logits, sub, pol)
         cache = kv_cache.advance(cache, active)
-        return (next_tokens, logits, cache, rng, *counters)
+        return next_tokens, logits, cache, rng, tuple(counters)
 
     def _make_prefill(self, bucket: int):
         """The ``prefill_<bucket>`` program: ONE ``[num_slots, bucket]``
         forward. Every admitted prompt's positions go through the layers
         together, their K/V take one masked write a layer at ``start +
         t``, and each admitted slot's first token is drawn in-program
-        from the logits of its last real position."""
+        from the logits of its last real position. The cache is donated."""
         keep = self.config.keep_prefill_logits
 
         def prefill_fn(weights, cache, tokens, admit, start, tail_lens,
@@ -464,9 +498,9 @@ class Engine:
                 rng, sub = jax.random.split(rng)
                 first_tokens = self._sample(last_logits, sub, pol)
             return (cache, first_tokens, last_logits, all_logits, rng,
-                    *counters)
+                    tuple(counters))
 
-        return jax.jit(prefill_fn)
+        return _donating_jit(prefill_fn, 1)
 
     def _make_verify(self):
         """The speculative verify step: a scan of the one-token decode
@@ -484,7 +518,7 @@ class Engine:
         the position argument, the same mechanism evict relies on).
         Per-slot ``draft_lens`` is also data, so capacity- or
         budget-clamped slots (down to plain one-token steps at
-        ``draft_lens == 0``) ride the same trace."""
+        ``draft_lens == 0``) ride the same trace. The cache is donated."""
         k = self._spec_k
         width = k + 1
 
@@ -532,7 +566,7 @@ class Engine:
                                              start + committed)
             return targets, committed, next_tokens, cache, rng
 
-        return verify_fn
+        return _donating_jit(verify_fn, 1)
 
     # -------------------------------------------------------------- AOT
     def _policy_args(self):
@@ -591,6 +625,8 @@ class Engine:
             self._decode_lowered = self._decode.lower(
                 *self._decode_args())
             self._decode_aot = self._decode_lowered.compile()
+            self._pool_facts["decode"] = kv_cache.pool_facts(
+                self._decode_aot, self.cache)
             publish_compiled_memory(
                 "serve_decode", self._decode_aot,
                 num_slots=self.config.num_slots, max_len=self.max_len,
@@ -608,6 +644,8 @@ class Engine:
                 lowered = fn.lower(*self._prefill_args(bucket))
                 self._prefill_lowered[bucket] = lowered
                 self._prefill_aot[bucket] = lowered.compile()
+                self._pool_facts[bucket] = kv_cache.pool_facts(
+                    self._prefill_aot[bucket], self.cache)
                 publish_compiled_memory(
                     "serve_prefill", self._prefill_aot[bucket],
                     bucket=bucket, num_slots=self.config.num_slots,
@@ -619,6 +657,8 @@ class Engine:
             self._verify_lowered = self._verify.lower(
                 *self._verify_args())
             self._verify_aot = self._verify_lowered.compile()
+            self._pool_facts["verify"] = kv_cache.pool_facts(
+                self._verify_aot, self.cache)
             publish_compiled_memory(
                 "serve_verify", self._verify_aot,
                 draft_len=self._spec_k,
@@ -631,9 +671,41 @@ class Engine:
         """ALL mutable serving state lives here (shared by __init__ and
         :meth:`reset` so a drain/restart can never miss a field)."""
         b = self.config.num_slots
+        self._init_pool()
+        self.rng = jax.random.PRNGKey(seed)
+        self.last_tokens = np.zeros((b,), np.int32)
+        # prefix-cache accounting (tier-1 asserts a prefix hit SKIPS
+        # prefill work via these, not via wall clock)
+        self.decode_calls = 0            # decode_step executions
+        self.prefill_calls = 0           # host prefill() invocations
+        self.prefill_requests = 0        # slot-prompts prefilled
+        self.prefill_scanned_tokens = 0  # positions paid: bucket a call
+        self.prefix_hits = 0             # prompts that reused >=1 page
+        self.prefix_hit_tokens = 0       # tokens served from the index
+        if self._policy is not None:
+            # per-slot policy knobs (host mirrors of the jit-argument
+            # arrays): reset() restores the engine-default policy
+            self._pol_temps = np.full((b,), self._policy.temperature,
+                                      np.float32)
+            self._pol_top_ps = np.full((b,), self._policy.top_p,
+                                       np.float32)
+            self._pol_min_ps = np.full((b,), self._policy.min_p,
+                                       np.float32)
+
+    def _init_pool(self) -> None:
+        """The pool and everything that says what it holds: the cache,
+        the allocator, the prefix index, the page tables and the host's
+        mirror of ``lengths``. What :meth:`_donating` rebuilds when a
+        failed call took the buffers with it."""
+        b = self.config.num_slots
         self.cache: Any = self.model.init_cache(
             b, self.max_len, self.config.page_size,
-            self._num_pages if self._paged else None, self._kv_quant)
+            self._num_pages if self._paged else None, self._kv_quant,
+            self._tp)
+        if self.mesh is not None:
+            # head-sharded K/V pools, replicated bookkeeping — placed at
+            # init so the compiled step never pays a layout move
+            self.cache = shard_cache(self.cache, self.mesh)
         if self._paged:
             ps = int(self.config.page_size)
             self.pool: Optional[PagePool] = PagePool(self._num_pages, ps)
@@ -649,34 +721,34 @@ class Engine:
             self.prefix = None
             self._slot_pages = [[] for _ in range(b)]
             self._slot_capacity = np.full((b,), self.max_len, np.int64)
-        if self.mesh is not None:
-            # head-sharded K/V pools, replicated bookkeeping — placed at
-            # init so the compiled step never pays a layout move
-            self.cache = shard_cache(self.cache, self.mesh)
-        self.rng = jax.random.PRNGKey(seed)
-        self.last_tokens = np.zeros((b,), np.int32)
         # host mirror of cache.lengths (advanced deterministically by
         # prefill/decode/evict) — lets decode_step enforce the context
         # bound without a per-step device fetch
         self._host_lengths = np.zeros((b,), np.int64)
-        # prefix-cache accounting (tier-1 asserts a prefix hit SKIPS
-        # prefill work via these, not via wall clock)
-        self.decode_calls = 0            # decode_step executions
-        self.prefill_calls = 0           # host prefill() invocations
-        self.prefill_requests = 0        # slot-prompts prefilled
-        self.prefill_scanned_tokens = 0  # positions paid: bucket a call
-        self.prefix_hits = 0             # prompts that reused >=1 page
-        self.prefix_hit_tokens = 0       # tokens served from the index
         self.last_prefill_stats: Dict[int, Dict[str, int]] = {}
-        if self._policy is not None:
-            # per-slot policy knobs (host mirrors of the jit-argument
-            # arrays): reset() restores the engine-default policy
-            self._pol_temps = np.full((b,), self._policy.temperature,
-                                      np.float32)
-            self._pol_top_ps = np.full((b,), self._policy.top_p,
-                                       np.float32)
-            self._pol_min_ps = np.full((b,), self._policy.min_p,
-                                       np.float32)
+
+    def _donating(self, fn, *args):
+        """Call a program that is given the pool by donation. A call
+        that raises before its arguments are consumed (a refusal, a
+        trace or compile error) leaves the engine as it was. One that
+        raises AFTER has taken the pool's buffers with it: that is fatal
+        for the resident requests, so the pool is re-initialised
+        (:meth:`_init_pool`: zero pages, empty allocator and prefix
+        index, every slot free; programs, weights, the PRNG key and the
+        counters stay) and :class:`PoolLost` is raised from the cause —
+        ``self.cache`` never points at deleted buffers."""
+        try:
+            return fn(*args)
+        except Exception as err:
+            if not any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(self.cache)):
+                raise
+            self._init_pool()
+            raise PoolLost(
+                f"a serving call failed after the cache was donated to "
+                f"it ({type(err).__name__}: {err}); the pool's buffers "
+                f"went with it, so every resident request is lost and "
+                f"the pool has been re-initialised empty") from err
 
     def reset(self, seed: int = 0, *,
               keep_prefix_cache: bool = False) -> "Engine":
@@ -801,14 +873,18 @@ class Engine:
         return plan["new_pages"]
 
     # ------------------------------------------------------------- calls
-    def _occupancy(self, act_np: np.ndarray) -> Dict[str, int]:
+    def _occupancy(self, act_np: np.ndarray,
+                   program: str = "decode") -> Dict[str, int]:
         """What a decode step's span carries: slots fed and held, tokens
-        resident before the step, and (paged) pool pages out of the free
-        list. Host ints the engine already keeps; nothing is read from
-        the device."""
+        resident before the step, (paged) pool pages out of the free
+        list, and what the compiled ``program`` says of the pool
+        (``pool_aliased_bytes``, ``pool_copies``: there once
+        :meth:`aot_compile` holds the executable). Host ints the engine
+        already keeps; nothing is read from the device."""
         attrs = {"active": int(act_np.sum()),
                  "slots": self.config.num_slots,
-                 "resident": self.resident_tokens}
+                 "resident": self.resident_tokens,
+                 **self._pool_facts.get(program, {})}
         if self._paged:
             attrs["pages_in_use"] = (self.pool.capacity
                                      - self.pool.free_count)
@@ -870,7 +946,8 @@ class Engine:
             bucket = pow2_ceil(max(len(t) for t in tails.values()))
             with annotate("apex.prefill.launch", bucket=bucket, slots=b,
                           real_positions=sum(len(t) for t in tails.values()),
-                          hit_tokens=int(starts.sum()), new_pages=new_pages):
+                          hit_tokens=int(starts.sum()), new_pages=new_pages,
+                          **self._pool_facts.get(bucket, {})):
                 tokens = np.zeros((b, bucket), np.int32)
                 admit = np.zeros((b,), bool)
                 lens = np.zeros((b,), np.int32)
@@ -889,7 +966,7 @@ class Engine:
                 if self._policy is not None:
                     args += (self._policy_args(),)
                 (self.cache, first, last_logits, all_logits, self.rng,
-                 *counters) = fn(*args)
+                 counters) = self._donating(fn, *args)
             self.prefill_calls += 1
             self.prefill_requests += len(prompts)
             self.prefill_scanned_tokens += int(bucket)
@@ -968,8 +1045,9 @@ class Engine:
                     # copy-on-write: the tail starts mid-page, so the
                     # slot gets its own writable copy of the boundary
                     # page (one compiled op; identical bytes)
-                    self.cache = kv_cache.copy_page(
-                        self.cache, plan["cow_src"], fresh[0])
+                    self.cache = self._donating(
+                        self._copy_page, self.cache, plan["cow_src"],
+                        fresh[0])
                 row = shared + fresh
                 self._page_table[slot, :] = paging.NULL_PAGE
                 self._page_table[slot, :len(row)] = row
@@ -1019,8 +1097,8 @@ class Engine:
                 args = (self._weights, self.cache, lt, act, self.rng)
                 if self._policy is not None:
                     args += (self._policy_args(),)
-                next_tokens, logits, self.cache, self.rng, *counters = \
-                    fn(*args)
+                next_tokens, logits, self.cache, self.rng, counters = \
+                    self._donating(fn, *args)
             self.decode_calls += 1
             with annotate("apex.decode_step.fetch"):
                 next_np = np.asarray(next_tokens)
@@ -1085,7 +1163,8 @@ class Engine:
                 "spec_decode_step needs EngineConfig(spec_draft_len >= "
                 "1); use decode_step on the one-token engine")
         act_np = np.asarray(active, bool)
-        with annotate("apex.spec_decode_step", **self._occupancy(act_np)):
+        with annotate("apex.spec_decode_step",
+                      **self._occupancy(act_np, "verify")):
             dl_np = np.asarray(draft_lens, np.int64)
             if ((dl_np < 0) | (dl_np > self._spec_k)).any():
                 raise ValueError(
@@ -1117,7 +1196,7 @@ class Engine:
                 if self._policy is not None:
                     args += (self._policy_args(),)
                 committed, counts, next_tokens, self.cache, self.rng = \
-                    fn(*args)
+                    self._donating(fn, *args)
             self.decode_calls += 1
             with annotate("apex.spec_decode_step.fetch"):
                 committed_np = np.asarray(committed)
@@ -1133,6 +1212,8 @@ class Engine:
         (index-pinned prefix pages stay resident)."""
         mask = np.zeros((self.config.num_slots,), bool)
         mask[np.asarray(list(slots), np.int64)] = True
+        # only ``lengths`` goes through a program: the pool's arrays are
+        # the same buffers before and after
         self.cache = kv_cache.evict_slots(self.cache, jnp.asarray(mask))
         self._host_lengths = np.where(mask, 0, self._host_lengths)
         if self._paged:
@@ -1145,8 +1226,8 @@ class Engine:
         into another replica's pool: ``[{chain_hash, k, v, digest}, ...]``
         in chain order, one entry per consecutive indexed full chunk.
         Payload arrays are host copies ``[n_layer, page_size, heads,
-        head_dim]`` (under tensor parallelism ``device_get`` gathers the
-        head shards — page indices are rank-invariant, payloads are
+        head_dim]`` with the pool's padded head axis (under tensor
+        parallelism ``device_get`` gathers the head shards — page indices are rank-invariant, payloads are
         whole pages). The digest is stamped here, over the exact bytes
         exported (:func:`~apex_tpu.serve.paging.page_payload_digest`), so
         the receiver can certify the transfer. ``touch=False``: an
@@ -1207,8 +1288,7 @@ class Engine:
                 "index)")
         self._refuse_page_migration()
         ps = int(self.config.page_size)
-        shape = (self.model.n_layer, ps, self.model.heads,
-                 self.model.head_dim)
+        shape = (self.model.n_layer, ps) + tuple(self.cache.k.shape[3:])
         stats = {"installed": 0, "duplicate": 0, "no_capacity": 0}
         for p in payloads:
             if tuple(np.shape(p["k"])) != shape or \
@@ -1235,15 +1315,12 @@ class Engine:
                     stats["installed"] + stats["duplicate"])
                 break
             page = self.pool.alloc(1)[0]
-            if self._kv_quant is not None:
-                self.cache = kv_cache.install_page(
-                    self.cache, page, jnp.asarray(p["k"]),
-                    jnp.asarray(p["v"]), jnp.asarray(p["k_scale"]),
-                    jnp.asarray(p["v_scale"]))
-            else:
-                self.cache = kv_cache.install_page(
-                    self.cache, page, jnp.asarray(p["k"]),
-                    jnp.asarray(p["v"]))
+            planes = (p["k"], p["v"]) + (
+                (p["k_scale"], p["v_scale"])
+                if self._kv_quant is not None else ())
+            self.cache = self._donating(
+                self._install_page, self.cache, page,
+                *(jnp.asarray(a) for a in planes))
             self.prefix.insert(p["chain_hash"], page, self.pool)
             # index-only residency (refcount 1): admission shares it
             # read-only like any local prefix hit; LRU can reclaim it

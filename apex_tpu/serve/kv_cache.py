@@ -21,14 +21,28 @@ jitted decode step that closes over either pytree compiles exactly once:
   entries read its zeros (discarded by the attention reachability mask).
 
 - :class:`PagedLatentCache` — the paged pool of a latent-attention (MLA)
-  model: ONE array ``rows`` ``[n_layer, num_pages, page_size, width]``
+  model: ONE array ``rows`` ``[n_layer, num_pages, page_size, row]``
   (a token's normalised key-value latent and its one rotated key, shared
   by every head: no head axis, no second array) with the same
   ``page_table`` and ``lengths``, so the allocator, the prefix index and
   every length mutator below serve it unchanged.
 
-All mutators are pure functions returning a new cache (the engine's
-jitted callables donate nothing and alias nothing). Masked writes
+**A token's row is whole tiles** in all three: the ``heads`` axis of
+``k``/``v`` (and of their scale planes) is allocated as whole groups of
+8 sublanes (:func:`padded_heads`: 25 heads lie in 32, the rest zeros:
+:func:`pad_heads`; attention runs over all of them and drops the
+padding's outputs), the latent row as whole 128-lane tiles
+(:func:`pad_last`). That shape is the pool's device layout: see "the
+resident pool" below.
+
+All mutators are pure functions returning a new cache: that is true of
+the functions and, inside the engine, false of the buffers. **The pool
+is resident**: every engine program that takes a cache and returns one
+is given it by donation, so its writes land in the buffers it was
+handed and no call copies a pool. A donated cache is gone: whoever kept
+a reference to one across an engine call holds deleted arrays. The
+functions here donate nothing themselves (a test may
+``jax.jit(write_token)`` and keep its input). Masked writes
 read-modify-write the existing token so an inactive slot's bytes are
 untouched — slot isolation is structural, not best-effort.
 
@@ -46,18 +60,44 @@ existed.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Optional
 
 import flax.struct
 import jax
 import jax.numpy as jnp
 
+SUBLANES, LANES = 8, 128    # the tile of every TPU array layout
+
+
+def padded_heads(heads: int, shards: int = 1) -> int:
+    """The size of a K/V pool's head axis for ``heads`` heads split over
+    ``shards`` tensor-parallel ranks: each rank's heads in whole groups
+    of :data:`SUBLANES` (its real heads first)."""
+    return shards * (-(-(heads // shards) // SUBLANES) * SUBLANES)
+
+
+def pad_heads(tok: jax.Array, size: int) -> jax.Array:
+    """``[..., heads, head_dim]`` zero-padded on the head axis to
+    ``size``: a token, or a query, as the pool holds its heads. The
+    padding attends over zeros to zeros; the caller drops it."""
+    pad = size - tok.shape[-2]
+    return jnp.pad(tok, [(0, 0)] * (tok.ndim - 2) + [(0, pad), (0, 0)])
+
+
+def pad_last(x: jax.Array, size: int) -> jax.Array:
+    """``x`` zero-padded on its last axis to ``size``: a token's scales
+    beside its padded heads; a latent row as it lies in its pool (whole
+    :data:`LANES` wide), and a query widened to meet it (the padding
+    multiplies zeros, so no score moves)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, size - x.shape[-1])])
+
 
 @flax.struct.dataclass
 class KVCache:
     """Pytree of the serving cache; see module docstring for shapes."""
 
-    k: jax.Array        # [n_layer, num_slots, max_len, heads, head_dim]
+    k: jax.Array        # [n_layer, num_slots, max_len, padded heads, head_dim]
     v: jax.Array        # same shape as k
     lengths: jax.Array  # [num_slots] int32 — tokens resident per slot
     # per-(token, head) fp32 codec scales when kv_quant is armed:
@@ -80,12 +120,15 @@ class KVCache:
 
 def init_cache(n_layer: int, num_slots: int, max_len: int, heads: int,
                head_dim: int, dtype: Any = jnp.float32,
-               kv_quant: Optional[str] = None) -> KVCache:
+               kv_quant: Optional[str] = None, shards: int = 1) -> KVCache:
     """Allocate an empty cache. ``max_len`` bounds every request's total
     context (prompt + generated); the scheduler terminates a request that
     reaches it. With ``kv_quant`` the payload arrays take the codec's
-    storage dtype and the fp32 scale planes are allocated alongside."""
-    shape = (n_layer, num_slots, max_len, heads, head_dim)
+    storage dtype and the fp32 scale planes are allocated alongside.
+    ``shards``: the tensor-parallel ranks the head axis will be split
+    over (:func:`padded_heads`)."""
+    shape = (n_layer, num_slots, max_len, padded_heads(heads, shards),
+             head_dim)
     lengths = jnp.zeros((num_slots,), jnp.int32)
     if kv_quant is None:
         return KVCache(k=jnp.zeros(shape, dtype),
@@ -134,17 +177,19 @@ def write_token(cache: KVCache, layer: int, k_tok: jax.Array,
             from apex_tpu.quant.kv import encode_kv
 
             tok, scales = encode_kv(codec, tok.astype(jnp.float32))
-        buf = getattr(cache, name)[layer]              # [B, L, h, d]
-        cur = jax.vmap(_read)(buf, pos)                # [B, h, d]
-        new = jnp.where(mask[:, None, None], tok.astype(buf.dtype), cur)
+        buf = getattr(cache, name)[layer]              # [B, L, H, d]
+        cur = jax.vmap(_read)(buf, pos)                # [B, H, d]
+        new = jnp.where(mask[:, None, None],
+                        pad_heads(tok.astype(buf.dtype), buf.shape[-2]),
+                        cur)
         out[name] = getattr(cache, name).at[layer].set(
             jax.vmap(_one)(buf, new, pos))
         if scales is not None:
             sname = name + "_scale"
-            sbuf = getattr(cache, sname)[layer]        # [B, L, h]
-            scur = jax.vmap(_read)(sbuf, pos)          # [B, h]
-            snew = jnp.where(mask[:, None], scales.astype(sbuf.dtype),
-                             scur)
+            sbuf = getattr(cache, sname)[layer]        # [B, L, H]
+            scur = jax.vmap(_read)(sbuf, pos)          # [B, H]
+            snew = jnp.where(mask[:, None], pad_last(
+                scales.astype(sbuf.dtype), sbuf.shape[-1]), scur)
             out[sname] = getattr(cache, sname).at[layer].set(
                 jax.vmap(_one)(sbuf, snew, pos))
     return cache.replace(**out)
@@ -171,17 +216,17 @@ def set_lengths(cache: KVCache, mask: jax.Array,
                           cache.lengths).astype(jnp.int32))
 
 
-# host-callable eviction: ONE jitted (mask-shaped) op, compiled once per
-# engine (once per cache pytree structure — slot and paged engines each
-# hold their own entry) — freeing a slot between decode steps cannot
-# recompile anything
-@jax.jit
+# host-callable eviction: ``lengths`` alone goes through a (mask-shaped)
+# op, compiled once per slot count — freeing a slot between decode steps
+# cannot recompile anything, and the pool's arrays never enter a program
+# (a jitted pass-through of an undonated pool is a whole copy of it)
 def evict_slots(cache, mask: jax.Array):
     """Free masked slots. Data is left in place; only ``lengths`` moves —
     the attention mask (``key_pos <= position``) makes the stale rows
-    unreachable, and the next insert overwrites them. Works on either
+    unreachable, and the next insert overwrites them. Works on every
     cache layout (it only touches ``lengths``; a paged slot's page
-    *indices* are host bookkeeping, freed by the allocator)."""
+    *indices* are host bookkeeping, freed by the allocator). The
+    returned cache holds the SAME pool arrays as ``cache``."""
     return reset_slots(cache, mask)
 
 
@@ -192,8 +237,9 @@ def evict_slots(cache, mask: jax.Array):
 class PagedKVCache:
     """Pytree of the paged serving cache; see module docstring."""
 
-    k: jax.Array           # [n_layer, num_pages, page_size, heads, head_dim]
-    v: jax.Array           # same shape as k
+    # k, v: [n_layer, num_pages, page_size, padded heads, head_dim]
+    k: jax.Array
+    v: jax.Array
     lengths: jax.Array     # [num_slots] int32 — tokens resident per slot
     page_table: jax.Array  # [num_slots, max_pages_per_slot] int32
     # per-(token, head) fp32 codec scales when kv_quant is armed:
@@ -232,7 +278,8 @@ class PagedKVCache:
 def init_paged_cache(n_layer: int, num_slots: int, max_len: int,
                      page_size: int, num_pages: int, heads: int,
                      head_dim: int, dtype: Any = jnp.float32,
-                     kv_quant: Optional[str] = None) -> PagedKVCache:
+                     kv_quant: Optional[str] = None,
+                     shards: int = 1) -> PagedKVCache:
     """Allocate an empty page pool. ``max_len`` (must be a multiple of
     ``page_size``) bounds every request's total context; ``num_pages``
     bounds the *pool* — sizing it below ``num_slots * max_len /
@@ -249,7 +296,8 @@ def init_paged_cache(n_layer: int, num_slots: int, max_len: int,
             f"num_pages={num_pages} cannot hold even one full-context "
             f"request: need max_len/page_size + 1 null page = "
             f"{max_pages + 1}")
-    shape = (n_layer, num_pages, page_size, heads, head_dim)
+    shape = (n_layer, num_pages, page_size, padded_heads(heads, shards),
+             head_dim)
     lengths = jnp.zeros((num_slots,), jnp.int32)
     table = jnp.zeros((num_slots, max_pages), jnp.int32)
     if kv_quant is None:
@@ -296,16 +344,18 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
             from apex_tpu.quant.kv import encode_kv
 
             tok, scales = encode_kv(codec, tok.astype(jnp.float32))
-        buf = getattr(cache, name)                     # [L, P, S, h, d]
-        cur = buf[layer, pages, offs]                  # [B, h, d]
-        new = jnp.where(mask[:, None, None], tok.astype(buf.dtype), cur)
+        buf = getattr(cache, name)                     # [L, P, S, H, d]
+        cur = buf[layer, pages, offs]                  # [B, H, d]
+        new = jnp.where(mask[:, None, None],
+                        pad_heads(tok.astype(buf.dtype), buf.shape[-2]),
+                        cur)
         out[name] = buf.at[layer, pages, offs].set(new)
         if scales is not None:
             sname = name + "_scale"
-            sbuf = getattr(cache, sname)               # [L, P, S, h]
-            scur = sbuf[layer, pages, offs]            # [B, h]
-            snew = jnp.where(mask[:, None], scales.astype(sbuf.dtype),
-                             scur)
+            sbuf = getattr(cache, sname)               # [L, P, S, H]
+            scur = sbuf[layer, pages, offs]            # [B, H]
+            snew = jnp.where(mask[:, None], pad_last(
+                scales.astype(sbuf.dtype), sbuf.shape[-1]), scur)
             out[sname] = sbuf.at[layer, pages, offs].set(snew)
     return cache.replace(**out)
 
@@ -334,7 +384,8 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
     encodes it (one scale per token and head) and the scales take the
     same scatter. Returns ``(cache, k_read, v_read)``: the cache, and the
     rows as a later read of the cache returns them (fp32 decoded codes,
-    or the rows in the cache's dtype) — what the chunk's own attention
+    or the rows in the cache's dtype; the head axis padded as the
+    cache's is) — what the chunk's own attention
     attends over, so that prefill sees the values decode will see.
     """
     pos = positions.astype(jnp.int32)
@@ -352,13 +403,15 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
     for name, rows in (("k", k_rows), ("v", v_rows)):
         buf = getattr(cache, name)
         if codec is None:
-            rows = read[name] = rows.astype(buf.dtype)
+            rows = read[name] = pad_heads(rows.astype(buf.dtype),
+                                          buf.shape[-2])
         else:
             from apex_tpu.quant.kv import decode_kv, encode_kv
 
             rows, scales = encode_kv(codec, rows.astype(jnp.float32))
             sbuf = getattr(cache, name + "_scale")
-            rows, scales = rows.astype(buf.dtype), scales.astype(sbuf.dtype)
+            rows = pad_heads(rows.astype(buf.dtype), buf.shape[-2])
+            scales = pad_last(scales.astype(sbuf.dtype), sbuf.shape[-1])
             read[name] = decode_kv(rows, scales)
             out[name + "_scale"] = sbuf.at[index].set(scales, mode="drop")
         out[name] = buf.at[index].set(rows, mode="drop")
@@ -369,7 +422,8 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
 #
 # Both cache layouts shard the SAME axis under tensor parallelism: axis 3
 # is `heads` in `[n_layer, num_slots, max_len, heads, head_dim]` and in
-# `[n_layer, num_pages, page_size, heads, head_dim]` alike. Everything
+# `[n_layer, num_pages, page_size, heads, head_dim]` alike (a rank's
+# stretch of it holds its own heads first, then its padding). Everything
 # host-indexed — `lengths`, the page table, page/slot indices — stays
 # replicated data, which is why the allocator, prefix index, scheduler,
 # and journal are mesh-agnostic: a page index addresses every rank's
@@ -396,17 +450,11 @@ def tp_cache_specs(cache, axis: str = "tp"):
 def shard_cache(cache, mesh, axis: str = "tp"):
     """Place a freshly-initialized cache onto the serving mesh per
     :func:`tp_cache_specs` (head-sharded K/V pools, replicated
-    bookkeeping). Heads must divide over the mesh axis."""
-    import jax
+    bookkeeping). The caller has checked that heads divide over the mesh
+    axis and allocated the head axis for it (``init_cache(...,
+    shards=tp)``)."""
     from jax.sharding import NamedSharding
 
-    heads = cache.k.shape[3]
-    tp = int(mesh.shape[axis])
-    if heads % tp:
-        raise ValueError(
-            f"tp={tp} must divide n_head={heads}: the serving mesh "
-            f"shards whole heads (pick a tp that divides the head "
-            f"count)")
     # ONE spelling of the layout: the placement derives from the same
     # spec tree shard_map consumes, so the two can never drift
     specs = tp_cache_specs(cache, axis)
@@ -439,10 +487,59 @@ def cache_bytes(cache) -> int:
                for name in _token_arrays(cache))
 
 
-# host-callable copy-on-write: ONE jitted op (page indices are traced
-# scalars), compiled once per engine — sharing a partially-used prefix
-# page costs a page copy, never a recompile
-@jax.jit
+# ------------------------------------------------------ the resident pool
+#
+# The TPU runtime's default layout for an array is the one that pads
+# least, not the row-major one. 25 heads do not fill their sublane tiles,
+# so ``bf16[L, 17, 64, 25, 64]`` lies as ``{4,2,3,1,0}`` (heads outside a
+# page's rows), and a 576-wide latent row puts the PAGE index innermost
+# (``bf16[L, 1089, 64, 576]{1,3,2,0}``). The serving programs index pages
+# and rows and move whole token rows (a scatter wants its window minor),
+# so XLA relaid each whole pool on the way into a program and again on
+# the way out, and copied an undonated pool once more before an in-place
+# scatter: four whole-pool copies a decode step, and donation alone
+# removed none (PERF.md, PR 31).
+#
+# A layout asked for through ``jax.experimental.layout`` does not survive
+# the persistent compile cache on this JAX (0.9.0: an array that a LOADED
+# executable returns reports the default layout whatever its bytes are,
+# and the next call refuses it), so the layout is not asked for: it is
+# the SHAPE. The head axis is allocated in whole groups of
+# :data:`SUBLANES` (:func:`padded_heads`: 25 -> 32) and the latent row in
+# whole :data:`LANES` (576 -> 640): row-major then pads no more than any
+# other order of the minor axes, the runtime breaks the tie for it, and
+# its default IS the layout the programs work in. The bytes are those the
+# row-major layout of the unpadded shape would take (it pads to the same
+# tiles). A ``page_size`` or a slot cache's ``max_len`` that is a
+# multiple of 128 still wins the minor axis (the engine's ``pool_copies``
+# says what a compiled program got). With the cache donated to every
+# program that returns it, a write lands in the buffer it was handed and
+# nothing is copied.
+
+
+def pool_facts(compiled, cache) -> dict:
+    """What a compiled program's own text says of the pool:
+    ``pool_aliased_bytes``, the bytes of its arguments that alias a
+    result (at least :func:`cache_bytes` once every pool array is written
+    in place), and ``pool_copies``, the ``copy`` instructions whose shape
+    is a whole pool array's, or a rank's whole shard of one (none, where
+    the pool is resident)."""
+    arrays = [getattr(cache, name) for name in _token_arrays(cache)]
+    # a program over the serving mesh names a rank's shard of the array
+    dims = {",".join(map(str, a.sharding.shard_shape(a.shape)))
+            for a in arrays}
+    copies = sum(m.group(1) in dims for m in re.finditer(
+        r"\[([\d,]+)\](?:\{[^}]*\})? copy\(", compiled.as_text()))
+    memory = compiled.memory_analysis()
+    return {"pool_aliased_bytes": int(
+                getattr(memory, "alias_size_in_bytes", 0) or 0),
+            "pool_copies": copies}
+
+
+# copy-on-write: page indices are traced scalars, so the engine's ONE
+# jitted op (the pool donated, a page written in place) serves every
+# pair — sharing a partially-used prefix page costs a page copy, never a
+# recompile and never a copy of the pool
 def copy_page(cache, src, dst):
     """Copy page ``src`` onto page ``dst`` across every layer and every
     paged array of the cache (K and V with their scale planes, or the
@@ -455,21 +552,21 @@ def copy_page(cache, src, dst):
         for name in _token_arrays(cache)})
 
 
-# host-callable page install: ONE jitted op (the page index is a traced
-# scalar, the payload a fixed-shape array), compiled once per engine —
-# landing a migrated page from another replica's pool costs one scatter,
-# never a recompile. The inverse of reading `cache.k[:, page]` out: the
-# disaggregated prefill→decode handoff streams `[n_layer, page_size,
-# heads, head_dim]` payloads and this op parks them under a pool index
-# the receiving allocator chose.
-@jax.jit
+# page install: the page index is a traced scalar, the payload a
+# fixed-shape array, so the engine's ONE jitted op lands every migrated
+# page from another replica's pool with one scatter into the donated
+# pool, never a recompile. The inverse of reading `cache.k[:, page]` out:
+# the disaggregated prefill→decode handoff streams `[n_layer, page_size,
+# heads (padded), head_dim]` payloads and this op parks them under a pool
+# index the receiving allocator chose.
 def install_page(cache: PagedKVCache, page, k_page: jax.Array,
                  v_page: jax.Array, k_scale_page=None,
                  v_scale_page=None) -> PagedKVCache:
     """Write a whole page's K/V payload into pool slot ``page`` across
     every layer. ``k_page``/``v_page``: ``[n_layer, page_size, heads,
-    head_dim]``; on a quantized cache the caller also supplies the
-    page's scale planes ``[n_layer, page_size, heads]``. The caller
+    head_dim]`` with the pool's padded head axis; on a quantized cache
+    the caller also supplies the page's scale planes ``[n_layer,
+    page_size, heads]``. The caller
     owns ``page`` (freshly allocated, refcount held), so the scatter
     can never alias a live slot's append."""
     page = jnp.asarray(page, jnp.int32)
@@ -491,11 +588,12 @@ def install_page(cache: PagedKVCache, page, k_page: jax.Array,
 @flax.struct.dataclass
 class PagedLatentCache:
     """Pytree of a latent-attention model's paged cache: one row a token
-    a layer, ``[c_kv, k_rope]`` (for DeepSeek-V3 widths 512 + 64 = 576),
-    which every head reads. Page indices, the null page and ``lengths``
+    a layer, ``[c_kv, k_rope]`` (for DeepSeek-V3 widths 512 + 64 = 576)
+    padded with zeros to whole lanes (640: :func:`pad_last`), which
+    every head reads. Page indices, the null page and ``lengths``
     mean what they mean in :class:`PagedKVCache`."""
 
-    rows: jax.Array        # [n_layer, num_pages, page_size, width]
+    rows: jax.Array        # [n_layer, num_pages, page_size, row]
     lengths: jax.Array     # [num_slots] int32 — tokens resident per slot
     page_table: jax.Array  # [num_slots, max_pages_per_slot] int32
 
@@ -527,8 +625,8 @@ class PagedLatentCache:
 def init_paged_latent_cache(n_layer: int, num_slots: int, max_len: int,
                             page_size: int, num_pages: int, width: int,
                             dtype: Any = jnp.float32) -> PagedLatentCache:
-    """Allocate an empty latent pool; the geometry rules are
-    :func:`init_paged_cache`'s."""
+    """Allocate an empty latent pool for rows ``width`` wide (stored
+    whole lanes wide); the geometry rules are :func:`init_paged_cache`'s."""
     if max_len % page_size:
         raise ValueError(
             f"page_size={page_size} must divide max_len={max_len}")
@@ -538,7 +636,8 @@ def init_paged_latent_cache(n_layer: int, num_slots: int, max_len: int,
             f"num_pages={num_pages} cannot hold one full-context request "
             f"plus the null page (need >= {max_pages + 1})")
     return PagedLatentCache(
-        rows=jnp.zeros((n_layer, num_pages, page_size, width), dtype),
+        rows=jnp.zeros((n_layer, num_pages, page_size,
+                        -(-width // LANES) * LANES), dtype),
         lengths=jnp.zeros((num_slots,), jnp.int32),
         page_table=jnp.zeros((num_slots, max_pages), jnp.int32))
 
@@ -563,4 +662,5 @@ def write_latent(cache: PagedLatentCache, layer: int, rows: jax.Array,
         slot, jnp.clip(pos // ps, 0, cache.max_pages_per_slot - 1)]
     index = (layer, jnp.where(live, pages, cache.num_pages), pos % ps)
     return cache.replace(rows=cache.rows.at[index].set(
-        rows.astype(cache.rows.dtype), mode="drop"))
+        pad_last(rows.astype(cache.rows.dtype), cache.rows.shape[-1]),
+        mode="drop"))

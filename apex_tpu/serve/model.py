@@ -10,9 +10,10 @@ model gives it, through one object:
   mode the model has no mechanism for, naming the mechanism;
 - ``block_k(max_len, config, tp)``: the decode-attention chunk, resolved
   once at build;
-- ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant)``: its
-  cache pytree, with ``lengths`` (and ``page_table`` where paged) as
-  ``serve/kv_cache.py`` and ``serve/paging.py`` expect them;
+- ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant, tp)``:
+  its cache pytree, with ``lengths`` (and ``page_table`` where paged) as
+  ``serve/kv_cache.py`` and ``serve/paging.py`` expect them, allocated
+  for ``tp`` ranks;
 - ``forward(weights, cache, tokens, positions, mask, logits_at=None, *,
   block_k, kv_quant, final_scope)``: its token forward in the two shapes
   (``[slots]``: decode and the verify scan's body; ``[slots, T]`` with
@@ -57,14 +58,16 @@ class GPT2Serving:
                                self.compute_dtype, config.block_k,
                                page_size=config.page_size, tp_shards=tp)
 
-    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant):
+    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
+                   tp=1):
         if page_size is None:
             return kv_cache.init_cache(
                 self.n_layer, num_slots, max_len, self.heads, self.head_dim,
-                self.compute_dtype, kv_quant=kv_quant)
+                self.compute_dtype, kv_quant=kv_quant, shards=tp)
         return kv_cache.init_paged_cache(
             self.n_layer, num_slots, max_len, page_size, num_pages,
-            self.heads, self.head_dim, self.compute_dtype, kv_quant=kv_quant)
+            self.heads, self.head_dim, self.compute_dtype, kv_quant=kv_quant,
+            shards=tp)
 
     def forward(self, weights, cache, *data, **kw):
         return gpt2_token_forward(self.cfg, weights, cache, *data, **kw)
@@ -123,7 +126,8 @@ class DeepseekV3Serving:
                 f"reads a page at a time (page_size={config.page_size})")
         return int(config.page_size)
 
-    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant):
+    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
+                   tp=1):
         return kv_cache.init_paged_latent_cache(
             self.n_layer, num_slots, max_len, page_size, num_pages,
             self.cfg.latent_width, self.compute_dtype)

@@ -11,7 +11,8 @@ a 1-D ``NamedSharding`` mesh (axis ``"tp"``):
   replicated;
 - **KV cache** — both layouts shard their ``heads`` axis (axis 3 of the
   slot cache's ``[n_layer, num_slots, max_len, heads, head_dim]`` and of
-  the paged pool's ``[n_layer, num_pages, page_size, heads, head_dim]``);
+  the paged pool's ``[n_layer, num_pages, page_size, heads, head_dim]``;
+  allocated for the mesh, ``kv_cache.padded_heads``);
   ``lengths`` and the **page table stay replicated data** — page indices
   address every rank's shard simultaneously, so the host-side allocator,
   prefix index, and scheduler need zero changes;

@@ -48,12 +48,13 @@ import os
 import sys
 import time
 
-# the geometry that fits one 16 GB v5e with room while the cache is not
+# the geometry that fitted one 16 GB v5e with room while the cache was not
 # donated, the weights are held in fp32 and re-cast per call, and the page
-# pool's tiled layout is 1.97x its data (PERF.md "Where the time goes"):
-# the prefill program is the high-water mark, 13.9 GB by memory_analysis
-# of the 16.91 GB the allocator offers. 33 pages = 32 usable pages of 64
-# tokens; each request here pins 3 of them.
+# pool's tiled layout was 1.97x its data (PERF.md "Where the time goes"):
+# the prefill program was the high-water mark, 13.9 GB by memory_analysis
+# of the 16.91 GB the allocator offers (PR 21; since PR 31 the pool is
+# donated and 1.04x its data: not measured again at this geometry).
+# 33 pages = 32 usable pages of 64 tokens; each request here pins 3.
 XL = dict(config="xl", dtype="bf16", num_slots=8, max_len=1024,
           page_size=64, num_pages=33, requests=12, prompt_len=128,
           max_new_tokens=32)

@@ -501,6 +501,42 @@ def test_compile_cache_is_one_fixed_place(monkeypatch):
                          "tests/test_monitor.py"}, offenders
 
 
+def test_programs_out_of_the_compile_cache_serve_as_compiled_ones_do(
+        tmp_path):
+    """What undid the first attempt at this (PERF.md, PR 31): an
+    executable that is LOADED from the persistent compile cache hands
+    back arrays that report the runtime's default layout, so a pool kept
+    in any other layout was refused by the call after. The pool's layout
+    is its shape now: a second engine, whose programs all come out of
+    the cache the first one filled, serves the same streams over a
+    donated pool, call after call."""
+    from jax._src import compilation_cache
+
+    from test_serve_resident_pool import _serve, _tiny_engine
+
+    knobs = {"jax_compilation_cache_dir": str(tmp_path),
+             "jax_enable_compilation_cache": True,
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {name: getattr(jax.config, name) for name in knobs}
+    try:
+        for name, value in knobs.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+        want = _serve(_tiny_engine("paged"))
+        filled = len(os.listdir(tmp_path))
+        assert filled > 0
+        eng = _tiny_engine("paged")
+        first = eng.cache
+        assert _serve(eng) == want
+        assert first.lengths.is_deleted()
+        assert len(os.listdir(tmp_path)) == filled     # nothing compiled
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
 def test_bench_atomic_write_leaves_no_partial_file(tmp_path):
     sys.path.insert(0, ROOT)
     try:

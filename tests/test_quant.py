@@ -263,7 +263,8 @@ def test_quant_cache_write_is_masked_and_bounded():
     cache = init_cache(n_layer=1, num_slots=4, max_len=8, heads=2,
                        head_dim=16, kv_quant="int8")
     assert cache.k.dtype == jnp.int8
-    assert cache.k_scale.shape == (1, 4, 8, 2)
+    # 2 heads in a head axis allocated as a whole group of 8
+    assert cache.k_scale.shape == (1, 4, 8, 8)
     x = np.random.RandomState(0).randn(4, 2, 16).astype(np.float32)
     pos = jnp.zeros((4,), jnp.int32)
     mask = jnp.array([True, False, True, False])
@@ -271,10 +272,12 @@ def test_quant_cache_write_is_masked_and_bounded():
                   static_argnums=(1, 6))(cache, 0, jnp.asarray(x),
                                          jnp.asarray(x), pos, mask,
                                          "int8")
-    got = np.asarray(out.k[0, 0, 0]).astype(np.float32) \
-        * np.asarray(out.k_scale[0, 0, 0])[..., None]
-    bound = int8_error_bound(np.asarray(out.k_scale[0, 0, 0])[..., None],
+    got = np.asarray(out.k[0, 0, 0, :2]).astype(np.float32) \
+        * np.asarray(out.k_scale[0, 0, 0, :2])[..., None]
+    bound = int8_error_bound(np.asarray(out.k_scale[0, 0, 0, :2])[..., None],
                              16, x[0].shape)
+    assert not np.asarray(out.k[..., 2:, :]).any()
+    assert not np.asarray(out.k_scale[..., 2:]).any()
     assert (np.abs(got - x[0]) <= bound).all()
     np.testing.assert_array_equal(np.asarray(out.k[0, 1]),
                                   np.asarray(cache.k[0, 1]))

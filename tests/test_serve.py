@@ -115,6 +115,9 @@ def test_kv_cache_ops_are_static_and_masked():
     mask = jnp.array([True, False, True, False])
     out = jax.jit(write_token, static_argnums=1)(cache, 0, k, k, pos, mask)
     assert out.k.shape == cache.k.shape  # static shapes, whatever the mask
+    # the head axis is allocated in whole groups of 8; the rest stays zero
+    assert cache.k.shape == (2, 4, 16, 8, 8)
+    assert not np.asarray(out.k[:, :, :, 2:]).any()
     got = np.asarray(out.k[0, :, 0, 0, 0])
     np.testing.assert_array_equal(got, [1.0, 0.0, 3.0, 0.0])
     # masked-off slots' bytes are bit-untouched
@@ -173,7 +176,7 @@ def test_chunk_attention_matches_a_plain_softmax(layout):
     loop runs to the longest, 19 rows = 3 chunks of 8, and masks the
     rest); the paged head read through a shuffled page table."""
     from apex_tpu.serve.attention import chunk_attention
-    from apex_tpu.serve.kv_cache import init_paged_cache
+    from apex_tpu.serve.kv_cache import init_paged_cache, pad_heads
 
     b, t, h, d, max_len, ps = 3, 4, 2, 8, 32, 8
     rng = np.random.RandomState(0)
@@ -181,14 +184,16 @@ def test_chunk_attention_matches_a_plain_softmax(layout):
     q, k, v = rng.randn(3, b, t, h, d).astype(np.float32)
     start = np.array([0, 19, 8], np.int32)
     if layout == "slot":
-        cache = init_cache(2, b, max_len, h, d).replace(
-            k=jnp.zeros((2, b, max_len, h, d)).at[1].set(head_k),
-            v=jnp.zeros((2, b, max_len, h, d)).at[1].set(head_v))
+        slots = init_cache(2, b, max_len, h, d)
+        cache = slots.replace(
+            k=slots.k.at[1].set(pad_heads(head_k, slots.k.shape[-2])),
+            v=slots.v.at[1].set(pad_heads(head_v, slots.v.shape[-2])))
     else:
         table = rng.permutation(np.arange(1, 13)).reshape(b, 4)
         pool = init_paged_cache(2, b, max_len, ps, 13, h, d)
 
         def paged(rows):              # [b, max_len, ...] -> [13, ps, ...]
+            rows = np.asarray(pad_heads(rows, pool.k.shape[-2]))
             out = np.zeros((13, ps) + rows.shape[2:], np.float32)
             out[table.reshape(-1)] = rows.reshape((-1, ps) + rows.shape[2:])
             return out
@@ -197,9 +202,13 @@ def test_chunk_attention_matches_a_plain_softmax(layout):
             k=pool.k.at[1].set(paged(head_k)),
             v=pool.v.at[1].set(paged(head_v)),
             page_table=jnp.asarray(table, jnp.int32))
+    # the cache's head axis is padded: so are the chunk's queries, keys
+    # and values, and the padding's outputs are dropped (gpt2.py does so)
+    full = cache.k.shape[-2]
     got = np.asarray(jax.jit(
-        lambda *a: chunk_attention(*a[:3], cache, 1, a[3], block_k=8))(
-            q, k, v, start))
+        lambda *a: chunk_attention(*(pad_heads(x, full) for x in a[:3]),
+                                   cache, 1, a[3], block_k=8))(
+            q, k, v, start))[:, :, :h]
     for i in range(b):
         for j in range(t):
             keys = np.concatenate([head_k[i, :start[i]], k[i, :j + 1]])
@@ -265,7 +274,9 @@ def test_batched_prefill_leaves_the_cache_decode_would(params, kind):
     assert batched.lengths.tolist() == stepped.lengths.tolist() == [0, 13]
     for field in ("k", "v"):
         a, b = _resident(batched, 1, field), _resident(stepped, 1, field)
-        assert a.shape == b.shape == (CFG.n_layer, 13, 2, 16)
+        # 2 heads in a head axis of 8: the padding is never written
+        assert a.shape == b.shape == (CFG.n_layer, 13, 8, 16)
+        assert not a[:, :, 2:].any() and not b[:, :, 2:].any()
         if kind != "paged-int8":
             np.testing.assert_allclose(a, b, err_msg=field, **BORDER)
             continue
