@@ -973,12 +973,12 @@ def _serve_bench(steps: int, num_slots: int = 4,
                          "shed_policy": shed_policy,
                          # pool geometry provenance: a capture whose
                          # capacity/hit-rate numbers were shaped by a
-                         # different page_size (or no paging at all) is
-                         # identifiable, never silently gated against
+                         # different page_size is identifiable, never
+                         # silently gated against (the engine's resolved
+                         # geometry: one max_len page a slot by default)
                          "max_len": max_len,
-                         "page_size": page_size or 0,
-                         "num_pages": engine._num_pages
-                         if page_size else 0,
+                         "page_size": engine.page_size,
+                         "num_pages": engine._num_pages,
                          "prefix_cache": bool(prefix_cache),
                          "prompt_len": prompt_len,
                          "shared_prefix": shared_prefix,

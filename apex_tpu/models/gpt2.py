@@ -10,7 +10,6 @@ fp32 params by default (amp O1 shape).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -160,9 +159,9 @@ def _affine_layer_norm(x, scale, bias, eps: float = 1e-5):
 
 def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
                        kv_quant):
-    """One layer's cache append and attention, for either cache layout
-    and either form of the forward. ``q``/``k``/``v``: ``[rows, heads,
-    head_dim]`` with ``rows = pos.size``; ``pos``/``write_mask``:
+    """One layer's cache append and attention, for either form of the
+    forward. ``q``/``k``/``v``: ``[rows, heads, head_dim]`` with
+    ``rows = pos.size``; ``pos``/``write_mask``:
     ``[num_slots]`` (one token a slot: appended at ``pos``, attending
     over cached ``0..pos``) or ``[num_slots, T]`` (a chunk a slot).
     Returns ``(o [rows, heads, head_dim], cache)``. The cache's head
@@ -170,10 +169,9 @@ def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
     queries are padded with zero heads to meet it, attention runs over
     all of them (the same tiles either way), and the padding's outputs
     are dropped."""
-    from apex_tpu.serve.attention import (cached_attention,
-                                          chunk_attention, paged_attention)
+    from apex_tpu.serve.attention import chunk_attention, paged_attention
     from apex_tpu.serve.kv_cache import (pad_heads, paged_write_token,
-                                         write_rows, write_token)
+                                         write_rows)
 
     heads = q.shape[-2]
     q = pad_heads(q, cache.k.shape[-2])
@@ -185,24 +183,13 @@ def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
         o = chunk_attention(q.reshape(pos.shape + q.shape[1:]), k_read,
                             v_read, cache, layer, pos[:, 0], block_k=block_k)
         return o.reshape(q.shape)[:, :heads], cache
-    # layout dispatch is structural, NOT isinstance: these imports are
-    # function-local (the serve package imports this module), so a
-    # purge-and-reimport of apex_tpu.serve.kv_cache mid-process would
-    # make isinstance(cache, PagedKVCache) compare against a fresh class
-    # and silently route a paged cache down the slot path
-    if hasattr(cache, "page_table"):
-        cache = paged_write_token(cache, layer, k, v, pos, write_mask,
-                                  codec=kv_quant)
-        attend = functools.partial(paged_attention, q, cache.k[layer],
-                                   cache.v[layer], cache.page_table, pos)
-    else:
-        cache = write_token(cache, layer, k, v, pos, write_mask,
-                            codec=kv_quant)
-        attend = functools.partial(cached_attention, q, cache.k[layer],
-                                   cache.v[layer], pos)
-    o = attend(block_k=block_k,
-               k_scale=None if kv_quant is None else cache.k_scale[layer],
-               v_scale=None if kv_quant is None else cache.v_scale[layer])
+    cache = paged_write_token(cache, layer, k, v, pos, write_mask,
+                              codec=kv_quant)
+    o = paged_attention(
+        q, cache.k[layer], cache.v[layer], cache.page_table, pos,
+        block_k=block_k,
+        k_scale=None if kv_quant is None else cache.k_scale[layer],
+        v_scale=None if kv_quant is None else cache.v_scale[layer])
     return o[:, :heads], cache
 
 
@@ -238,13 +225,10 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
     verify step's own cost — is attributed to the verify phase and
     phase reconciliation stays exact (monitor/costs.py).
 
-    ``cache`` may be either layout: the slot-contiguous
-    :class:`~apex_tpu.serve.kv_cache.KVCache` or the paged
-    :class:`~apex_tpu.serve.kv_cache.PagedKVCache`. The dispatch is
-    static (an ``isinstance`` on the pytree class at trace time); the
-    attention chunk arithmetic is shared, so the two layouts are
-    bit-identical in fp32 on identical resident bytes at equal
-    ``block_k`` (the chunk size orders the softmax partial sums).
+    ``cache`` is a :class:`~apex_tpu.serve.kv_cache.PagedKVCache`;
+    where its pages lie does not enter the arithmetic, so two page
+    sizes are bit-identical in fp32 on identical resident tokens at
+    equal ``block_k`` (the chunk size orders the softmax partial sums).
 
     ``kv_quant`` (``"int8"``/``"mxfp8"``, static trace-time string) arms
     the block-scale KV codec: each appended token's K/V is encoded with

@@ -8,10 +8,8 @@ read-modify-write as the payload, and scales inherit every page
 behaviour (prefix sharing, COW, LRU eviction, export/import streaming)
 by living in arrays shaped like the payload minus the head_dim axis:
 
-- slot layout:   k/v ``[n_layer, slots, max_len, heads, head_dim]``
-                 scales ``[n_layer, slots, max_len, heads]``
-- paged layout:  k/v ``[n_layer, pages, page_size, heads, head_dim]``
-                 scales ``[n_layer, pages, page_size, heads]``
+- k/v    ``[n_layer, pages, page_size, heads, head_dim]``
+- scales ``[n_layer, pages, page_size, heads]``
 
 Scales are fp32. Per head_dim=D that is ``D * storage + 4`` bytes per
 (token, head) vs ``4 * D`` unquantized — e.g. D=64: 68 vs 256 bytes,

@@ -8,17 +8,16 @@ punishes anything that changes shapes mid-stream with a recompile that
 costs more than the tokens it produces. This package is built around that
 one invariant:
 
-- :mod:`~apex_tpu.serve.kv_cache` — a slot-addressed, static-shape KV
-  cache pytree (``[n_layer, num_slots, max_len, heads, head_dim]`` plus a
-  per-slot length vector). ``insert``/``append``/``evict`` are pure,
-  jittable, mask-driven ops: batch membership changes (a request finishes,
-  another backfills its slot) never change a shape and therefore never
-  trigger a recompile.
-- :mod:`~apex_tpu.serve.engine` — AOT-lowered ``prefill`` and the ONE
-  jitted ``decode_step``: every token in the system, prefill or decode,
-  flows through the same ``[num_slots, 1]`` forward, so incremental decode
-  is bit-identical to prefill in fp32 and slots are arithmetically
-  isolated from each other.
+- :mod:`~apex_tpu.serve.kv_cache` — the static-shape KV cache pytree: a
+  paged pool (``[n_layer, num_pages, page_size, heads, head_dim]``), a
+  per-slot page table and a per-slot length vector; one page a slot by
+  default. Appends and evictions are pure, jittable, mask-driven ops:
+  batch membership changes (a request finishes, another backfills its
+  slot) never change a shape and therefore never trigger a recompile.
+- :mod:`~apex_tpu.serve.engine` — the AOT-lowered batched ``prefill``
+  (one ``[num_slots, bucket]`` forward a call) and the ONE jitted
+  ``decode_step``; incremental decode matches prefill to fp32 rounding,
+  and slots are arithmetically isolated from each other.
 - :mod:`~apex_tpu.serve.scheduler` — continuous batching: an admission
   queue, slot assignment, per-request EOS/max-token termination, eviction
   and backfill between decode steps, with TTFT/latency/throughput
@@ -66,8 +65,9 @@ from apex_tpu.serve.engine import Engine, EngineConfig  # noqa: F401
 from apex_tpu.serve.fleet import (EngineReplica,  # noqa: F401
                                   FleetController, FleetStats,
                                   FleetTraceHarness, ReplicaRegistry)
-from apex_tpu.serve.kv_cache import (KVCache, evict_slots,  # noqa: F401
-                                     init_cache, write_token)
+from apex_tpu.serve.kv_cache import (PagedKVCache,  # noqa: F401
+                                     evict_slots, init_paged_cache,
+                                     paged_write_token)
 from apex_tpu.serve.metrics import ServeMetrics  # noqa: F401
 from apex_tpu.serve.resilience import (SHED_POLICIES,  # noqa: F401
                                        AdmissionController,
@@ -76,8 +76,9 @@ from apex_tpu.serve.scheduler import (Request, ServeScheduler,  # noqa: F401
                                       ServeStats)
 
 __all__ = [
-    "Engine", "EngineConfig", "KVCache", "init_cache", "write_token",
-    "evict_slots", "Request", "ServeScheduler", "ServeStats",
+    "Engine", "EngineConfig", "PagedKVCache", "init_paged_cache",
+    "paged_write_token", "evict_slots", "Request", "ServeScheduler",
+    "ServeStats",
     "AdmissionController", "TickJournal", "ServeSupervisor",
     "SHED_POLICIES", "ServeMetrics",
     "FleetController", "EngineReplica", "ReplicaRegistry", "FleetStats",

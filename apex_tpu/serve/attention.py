@@ -1,11 +1,12 @@
-"""Attention over the static KV cache — slot-contiguous or paged: the
-decode step's (one query a slot) and the batched prefill's (a chunk a
-slot, :func:`chunk_attention`, at the end of this file).
+"""Attention over the paged KV cache: the decode step's (one query a
+slot) and the batched prefill's (a chunk a slot, :func:`chunk_attention`,
+at the end of this file). Every function here sees one layout: a pool
+``[pages, page_size, heads, head_dim]``, a ``page_table`` and
+``positions``.
 
 **Decode.** One query token per slot against that slot's cached
-keys/values. The key
-axis is static (the slot cache's ``max_len``, or the paged cache's
-``max_pages_per_slot * page_size`` virtual axis); reachability is a mask
+keys/values. The key axis is static (the cache's ``max_pages_per_slot *
+page_size`` virtual axis); reachability is a mask
 (``key_pos <= position``), never a shape — so the op compiles once and a
 slot's result depends only on that slot's bytes (reductions run within a
 slot; other slots' values cannot perturb the arithmetic, which is what
@@ -24,18 +25,15 @@ bit-identical. The batched prefill does not come through here: its
 chunk's own keys never touch the cache's key axis, so it matches the
 decode step to float32 rounding and not to the bit (docs/serving.md).
 
-**The paged path shares the slot path's arithmetic verbatim**: the only
-difference is where a chunk's K/V rows are fetched from (a contiguous
-slice of the slot's buffer vs. a page-table gather — ``block_k`` divides
-``page_size``, so every chunk lives inside exactly one page). Scores,
-masking, the max combine, and the sum order are the same code, which is
-why a paged engine is bit-exact in fp32 against the slot engine on
-identical traces **at the same block_k** (tier-1 asserts, with the slot
-cache as the oracle). The *default* chunk differs per layout — the
-heuristic/tuner unit is ``max_len`` for the slot cache but ``page_size``
-for the pool — and a different ``block_k`` reorders the partial sums by
-design (±1 ulp), exactly as it does between two ``block_k`` values on
-the same layout; pin ``block_k`` to compare layouts bitwise.
+**A chunk lives inside one page** (``block_k`` divides ``page_size``),
+so its fetch is one page gather plus a static in-page slice; scores,
+masking, the max combine and the sum order do not depend on where the
+pages lie. Two engines with different page sizes are therefore bit-exact
+in fp32 on identical traces **at the same block_k** (tier-1 asserts:
+several pages a slot against one page a slot). The *default* chunk
+follows the page (the heuristic/tuner unit is ``page_size``), and a
+different ``block_k`` reorders the partial sums by design (±1 ulp); pin
+``block_k`` to compare page sizes bitwise.
 
 All math fp32 (max-subtracted softmax; the row's own token is always
 reachable, so the denominator is never empty); IO dtype preserved.
@@ -65,10 +63,10 @@ def resolve_block_k(max_len: int, heads: int, head_dim: int, dtype,
     autotuned winner for this (max_len, page_size, heads, head_dim,
     tp_shards, dtype, chip), else the committed heuristic.
 
-    With a paged cache (``page_size`` set) the chunk must additionally
-    divide ``page_size`` so every chunk's rows live inside one page —
-    the fetch is then a single page gather plus a static slice, and the
-    geometry the autotuner times is the true streamed working set.
+    The chunk must divide ``page_size`` (``None``: one page a slot,
+    ``max_len``) so every chunk's rows live inside one page — the fetch
+    is then a single page gather plus a static slice, and the geometry
+    the autotuner times is the true streamed working set.
 
     ``tp_shards`` is the tensor-parallel mesh size the attention runs
     under (1 = single chip): a sharded engine passes its PER-SHARD head
@@ -78,12 +76,11 @@ def resolve_block_k(max_len: int, heads: int, head_dim: int, dtype,
     count (collective pressure and VMEM headroom differ), so winners
     never leak across mesh shapes.
     """
-    if page_size is not None:
-        ps = int(page_size)
-        if ps <= 0 or max_len % ps:
-            raise ValueError(
-                f"page_size={ps} must be positive and divide the cache "
-                f"max_len={max_len}")
+    ps = int(max_len if page_size is None else page_size)
+    if ps <= 0 or max_len % ps:
+        raise ValueError(
+            f"page_size={ps} must be positive and divide the cache "
+            f"max_len={max_len}")
     if block_k is not None:
         bk = int(block_k)
         if bk <= 0 or max_len % bk:
@@ -91,9 +88,9 @@ def resolve_block_k(max_len: int, heads: int, head_dim: int, dtype,
                 f"block_k={bk} must be positive and divide the cache "
                 f"max_len={max_len} (the chunked softmax tiles the static "
                 f"key axis exactly)")
-        if page_size is not None and int(page_size) % bk:
+        if ps % bk:
             raise ValueError(
-                f"block_k={bk} must divide page_size={page_size}: each "
+                f"block_k={bk} must divide page_size={ps}: each "
                 f"chunked-softmax tile must live inside one KV page "
                 f"(pick a block_k that divides the page, or a page_size "
                 f"that is a multiple of the tuned block)")
@@ -102,19 +99,15 @@ def resolve_block_k(max_len: int, heads: int, head_dim: int, dtype,
     # layout-defining engine constant and the winner must divide it — a
     # bucketed key would warm entries that can never validate for
     # non-pow2 cache lengths. page_size is a geometry axis of the same
-    # kind (0 = slot cache): a winner tuned for one page size cannot
-    # apply to another.
-    ps = int(page_size) if page_size is not None else 0
-    unit = ps if ps else int(max_len)
+    # kind: a winner tuned for one page size cannot apply to another.
     p = tuned_params(
         "decode_attention",
         (("max_len", int(max_len)), ("page_size", ps), ("heads", heads),
          ("d", head_dim), ("tp_shards", int(tp_shards))),
-        {"block_k": decode_attention_block(unit)},
+        {"block_k": decode_attention_block(ps)},
         dtype=dtype, interpret=interpret,
         validate=lambda pr: (pr["block_k"] > 0
-                             and max_len % pr["block_k"] == 0
-                             and (not ps or ps % pr["block_k"] == 0)))
+                             and ps % pr["block_k"] == 0))
     return int(p["block_k"])
 
 
@@ -123,13 +116,11 @@ def _combine_chunks(q: jax.Array, positions: jax.Array, L: int, bk: int,
                     fetch: Callable[[int], Tuple[jax.Array, jax.Array]],
                     ) -> jax.Array:
     """The shared chunked-softmax core: ``fetch(i)`` returns chunk ``i``'s
-    ``(k_rows, v_rows)`` as ``[b, block_k, heads, head_dim]`` — a
-    contiguous slice for the slot cache, a page gather for the paged pool.
-    Everything numeric happens HERE, identically for both layouts: each
+    ``(k_rows, v_rows)`` as ``[b, block_k, heads, head_dim]``, a page
+    gather. Everything numeric happens HERE, whatever the page size: each
     score's reduction runs over ``d`` (not ``L``), the global row max
     equals the max over chunk maxima bit-for-bit, and only the SUM order
-    depends on ``block_k`` — identically in decode and verify, and
-    identically in slot and paged engines.
+    depends on ``block_k`` — identically in decode and verify.
     """
     b, h, d = q.shape
     q32 = q.astype(_f32)
@@ -159,47 +150,6 @@ def _combine_chunks(q: jax.Array, positions: jax.Array, L: int, bk: int,
     return (num / den[..., None]).astype(q.dtype)
 
 
-def cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     positions: jax.Array, *,
-                     scale: Optional[float] = None,
-                     block_k: Optional[int] = None,
-                     interpret: Optional[bool] = None,
-                     k_scale: Optional[jax.Array] = None,
-                     v_scale: Optional[jax.Array] = None) -> jax.Array:
-    """Single-token attention over slot-contiguous cached K/V.
-
-    ``q``: ``[num_slots, heads, head_dim]`` (this step's query per slot);
-    ``k_cache``/``v_cache``: ``[num_slots, max_len, heads, head_dim]``;
-    ``positions``: ``[num_slots]`` int32 — slot ``b`` attends to cached
-    positions ``0 .. positions[b]`` inclusive (its own just-appended token
-    is position ``positions[b]``). Returns ``[num_slots, heads, head_dim]``
-    in ``q.dtype``.
-
-    ``k_scale``/``v_scale`` (``[num_slots, max_len, heads]`` fp32, from a
-    ``kv_quant`` cache) arm per-(token, head) dequantization INSIDE the
-    chunk fetch: each streamed ``[block_k]`` tile is decoded to fp32 as
-    it is read, so the scores/combine arithmetic below never changes and
-    the dequant working set is bounded by the same ``block_k`` tile.
-    """
-    b, L, h, d = k_cache.shape
-    bk = resolve_block_k(L, h, d, q.dtype, block_k, interpret)
-    s = jnp.float32(scale if scale is not None else 1.0 / (d ** 0.5))
-
-    # fully chunked over the key axis: scores, masking, exp, and the
-    # V-side accumulation all touch one [block_k] tile of K and V per
-    # step, so block_k genuinely bounds the streamed working set (the
-    # premise the decode_attention autotuner times)
-    def fetch(i):
-        sl = slice(i * bk, (i + 1) * bk)
-        ks, vs = k_cache[:, sl], v_cache[:, sl]
-        if k_scale is not None:
-            ks = ks.astype(_f32) * k_scale[:, sl][..., None]
-            vs = vs.astype(_f32) * v_scale[:, sl][..., None]
-        return ks, vs
-
-    return _combine_chunks(q, positions, L, bk, s, fetch)
-
-
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     page_table: jax.Array, positions: jax.Array, *,
                     scale: Optional[float] = None,
@@ -217,15 +167,17 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     lives inside page ``page_table[:, (i * block_k) // page_size]``
     (``block_k`` divides ``page_size``), so the fetch is one page gather
     plus a static in-page slice — the working set per partial reduction
-    is the same ``[block_k, head_dim]`` tile as the slot path, and the
-    combine is the SAME code, bit-for-bit. Unmapped table entries point
+    is one ``[block_k, head_dim]`` tile (the premise the
+    ``decode_attention`` autotuner times). Unmapped table entries point
     at the null page; its rows sit past every live position, so the
     reachability mask discards them.
 
     ``k_scale``/``v_scale`` (``[num_pages, page_size, heads]`` fp32, one
     layer of a ``kv_quant`` pool's scale planes) dequantize each fetched
-    tile through the SAME page gather as the payload — the scales ride
-    the page table, so sharing/COW/eviction need no quant-aware code.
+    ``[block_k]`` tile to fp32 as it is read, through the SAME page
+    gather as the payload — the scores/combine arithmetic never changes,
+    and the scales ride the page table, so sharing/COW/eviction need no
+    quant-aware code.
     """
     P, ps, h, d = k_pool.shape
     L = int(page_table.shape[1]) * ps
@@ -250,7 +202,7 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
                     layer: int, start: jax.Array, *,
                     block_k: int) -> jax.Array:
     """Attention of a chunk of ``T`` consecutive tokens per slot — the
-    batched prefill's attention, for either cache layout.
+    batched prefill's attention.
 
     ``q``/``k``/``v``: ``[num_slots, T, heads, head_dim]``, the chunk's
     own queries, keys and values (``k``/``v`` as a read of the cache
@@ -265,8 +217,8 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
         the chunk alone; and
     (b) over the cached positions ``< start[b]`` (a prompt's head that
         the prefix index served), fetched ``block_k`` rows at a time from
-        layer ``layer`` of ``cache`` — through the page table on a paged
-        cache — and folded into (a)'s running max, sum and weighted sum.
+        layer ``layer`` of ``cache`` through the page table and folded
+        into (a)'s running max, sum and weighted sum.
 
     (b) is a loop whose trip count is DATA: ``ceil(max(start) /
     block_k)`` chunks, so a call with no hit runs none of it and a call
@@ -282,8 +234,7 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
     heads, head_dim]`` in ``q.dtype``.
     """
     b, t, h, d = q.shape
-    paged = hasattr(cache, "page_table")
-    ps = cache.page_size if paged else None
+    ps = cache.page_size
     bk = resolve_block_k(cache.max_len, h, d, q.dtype, block_k,
                          page_size=ps)
     s = jnp.float32(1.0 / (d ** 0.5))
@@ -317,14 +268,10 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
         """Rows ``r0 .. r0 + block_k`` of every slot's key axis out of
         the STACKED buffer in one indexing op: slicing the layer out
         first would be loop-invariant, hoisted, and paid by every call."""
-        if paged:
-            pages = jax.lax.dynamic_index_in_dim(
-                cache.page_table, r0 // ps, axis=1, keepdims=False)
-            return jax.lax.dynamic_slice_in_dim(
-                buf[layer, pages], r0 % ps, bk, axis=1)
-        return jax.lax.dynamic_slice(
-            buf, (layer, 0, r0) + (0,) * (buf.ndim - 3),
-            (1, b, bk) + buf.shape[3:])[0]
+        pages = jax.lax.dynamic_index_in_dim(
+            cache.page_table, r0 // ps, axis=1, keepdims=False)
+        return jax.lax.dynamic_slice_in_dim(
+            buf[layer, pages], r0 % ps, bk, axis=1)
 
     def body(i, carry):
         m, den, num = carry

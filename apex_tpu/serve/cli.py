@@ -28,9 +28,10 @@ supervisor so a fatal tick exception recovers instead of killing every
 in-flight request.
 
 Paged KV pool (docs/serving.md "Paged KV pool and prefix caching"):
-``--page-size N`` swaps the per-slot ``max_len`` reservation for a
-shared block pool (``--num-pages`` sizes it; default = same token
-capacity as the slot cache, size it smaller to overcommit), and
+``--page-size N`` cuts the KV pool's pages from a slot's whole
+``max_len`` (the default: one page a slot) to N tokens, shared by all
+slots (``--num-pages`` sizes the pool; default = every slot's whole
+context, size it smaller to overcommit), and
 ``--prefix-cache`` shares read-only prompt-prefix pages across requests
 so a repeated system prompt is prefilled once. The summary's
 ``prefix_hit_rate`` / ``peak_resident_tokens`` report what the pool
@@ -435,15 +436,14 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "exception (tick journal + recovery; 0 = fail "
                          "fast, the pre-PR-8 behavior)")
     ap.add_argument("--page-size", type=int, default=None,
-                    help="tokens per KV page: enables the paged block "
-                         "pool (must divide --max-len; the tuned decode "
-                         "block_k must divide it). Default: per-slot "
-                         "max_len reservation")
+                    help="tokens per KV page (must divide --max-len; "
+                         "the tuned decode block_k must divide it). "
+                         "Default: --max-len, one page a slot")
     ap.add_argument("--num-pages", type=int, default=None,
                     help="pool capacity in pages incl. the reserved null "
-                         "page (default: same token capacity as the slot "
-                         "cache; smaller overcommits — the point of "
-                         "paging). Needs --page-size")
+                         "page (default: every slot's whole context; "
+                         "smaller overcommits — the point of paging). "
+                         "Needs --page-size")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="share read-only prompt-prefix pages across "
                          "requests (hash-indexed, page-granular; needs "

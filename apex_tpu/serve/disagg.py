@@ -188,7 +188,7 @@ class DisaggController(FleetController):
                         f"prefix index: disaggregation streams pages "
                         f"through it — build every replica's engine "
                         f"with page_size + prefix_cache=True")
-            sizes = {int(h.engine.config.page_size)
+            sizes = {int(h.engine.page_size)
                      for h in self.handles}
             if len(sizes) != 1:
                 raise ValueError(

@@ -1,5 +1,4 @@
-"""AOT batched prefill + one-jit decode over the static KV cache (slot or
-paged).
+"""AOT batched prefill + one-jit decode over the static, paged KV cache.
 
 The engine owns three compiled artifacts and NOTHING else touches the
 device. It owns the cache's buffers too: **the pool is resident**.
@@ -34,15 +33,19 @@ the pool"):
 - ``evict`` — a mask-shaped length reset (kv_cache.evict_slots), one
   compile total; only ``lengths`` goes through it.
 
-**Paged mode** (``EngineConfig(page_size=...)``) swaps the per-slot
-``max_len`` reservation for a shared block pool
-(:class:`~apex_tpu.serve.kv_cache.PagedKVCache`): the per-slot page table
-is DATA threaded through the same compiled calls, host-side allocation
-lives in :mod:`apex_tpu.serve.paging`, and the attention chunk arithmetic
-is shared with the slot path — so a paged engine is **bit-exact in fp32
-against the slot engine** on identical request traces at the same
-``block_k`` (the slot cache is the oracle in tier-1; the default chunk
-is tuned per layout, so pin ``block_k`` for bitwise comparison). With ``prefix_cache=True`` a hash-based prefix
+**The cache is a paged pool**
+(:class:`~apex_tpu.serve.kv_cache.PagedKVCache`): ``page_size`` tokens a
+page, a per-slot page table that is DATA threaded through the compiled
+calls, host-side allocation in :mod:`apex_tpu.serve.paging`. With no
+``EngineConfig.page_size`` a page is ``max_len`` tokens: one page a
+slot, ``num_slots + 1`` pages, every slot's whole context reserved and
+no admission ever short of pages. A smaller page lets mixed-length
+traffic share the pool (``num_pages`` sizes it). Where the pages lie
+does not enter the attention arithmetic, so two page sizes are
+**bit-exact in fp32 against each other** on identical request traces at
+the same ``block_k`` (tier-1 holds several pages a slot against one; the
+default chunk follows the page, so pin ``block_k`` for bitwise
+comparison). With ``prefix_cache=True`` a hash-based prefix
 index shares read-only prompt pages across requests: a request whose
 prompt prefix is already resident skips prefill for those pages (the
 call covers only the tail; a partially-used boundary page is
@@ -56,7 +59,7 @@ bottleneck).
 **Tensor-parallel mode** (``EngineConfig(tp=N)``) shards the whole
 engine over a 1-D ``NamedSharding`` mesh on the **head axis**: params
 (q/k/v columns, output-projection rows, MLP slices — see
-:mod:`apex_tpu.serve.tp`) and both cache layouts' K/V bytes shard per
+:mod:`apex_tpu.serve.tp`) and the pool's K/V bytes shard per
 head block, while ``lengths``, the page table, and every scheduler-side
 structure stay replicated data — so the allocator, prefix index,
 journal, and scheduler are mesh-agnostic and the one-compile invariant
@@ -92,8 +95,8 @@ K/V beyond ``lengths`` is unreachable because attention reachability is
 keyed on the position argument). Draft width is a static shape, the
 accepted length is data, so the invariant extends to one decode trace
 PLUS one verify trace per mesh shape (``verify_traces``), and a greedy
-speculative stream is bit-identical to the one-token engine — slot and
-paged, tp=1 and tp=2-exact. The ``DecodePolicy`` seam
+speculative stream is bit-identical to the one-token engine — at any
+page size, tp=1 and tp=2-exact. The ``DecodePolicy`` seam
 (``EngineConfig(decode_policy=...)``, :mod:`apex_tpu.serve.spec`)
 threads per-slot temperature/top_p/min_p as DATA through the same
 compiled calls for per-request policy mixing in one batch.
@@ -140,15 +143,17 @@ class EngineConfig:
     temperature: float = 1.0           # 0 => greedy argmax
     top_k: int = 0                     # 0 => full vocab
     block_k: Optional[int] = None      # decode-attention KV chunk (tuned)
-    # paged KV pool: tokens per page (None => per-slot slot cache). Must
-    # divide max_len; the tuned decode_attention block_k must divide it.
+    # paged KV pool: tokens per page (None => max_len: one page a slot).
+    # Must divide max_len; the tuned decode_attention block_k must
+    # divide it.
     page_size: Optional[int] = None
-    # pool capacity in pages INCLUDING the reserved null page. Default
-    # num_slots * (max_len / page_size) + 1 — same token capacity as the
-    # slot cache; size it SMALLER to overcommit (the point of paging:
-    # mixed-length traffic shares the pool).
+    # pool capacity in pages INCLUDING the reserved null page (needs
+    # page_size). Default num_slots * (max_len / page_size) + 1: every
+    # slot's whole context; size it SMALLER to overcommit (the point of
+    # paging: mixed-length traffic shares the pool).
     num_pages: Optional[int] = None
-    # hash-based prompt-prefix sharing across requests (paged mode only)
+    # hash-based prompt-prefix sharing across requests, a page at a time
+    # (needs page_size: a page of max_len tokens is never shared)
     prefix_cache: bool = False
     # keep per-position prefill logits (parity tests / scoring). O(P*B*V)
     # memory — leave False for real vocabularies.
@@ -239,29 +244,33 @@ class Engine:
             raise ValueError(
                 f"max_len={self.max_len} exceeds the model's "
                 f"n_positions={self.model.max_positions}")
-        self._paged = config.page_size is not None
-        if self._paged:
-            ps = int(config.page_size)
-            if ps <= 0 or self.max_len % ps:
+        if config.page_size is None:
+            if config.prefix_cache:
                 raise ValueError(
-                    f"page_size={config.page_size} must be positive and "
-                    f"divide max_len={self.max_len}")
-            self._max_pages = self.max_len // ps
-            self._num_pages = int(
-                config.num_pages
-                or config.num_slots * self._max_pages + 1)
-            if self._num_pages < self._max_pages + 1:
+                    "prefix_cache=True needs page_size (prefix sharing is "
+                    "page-granular, and the default page is a slot's "
+                    "whole max_len)")
+            if config.num_pages is not None:
                 raise ValueError(
-                    f"num_pages={self._num_pages} cannot hold one "
-                    f"full-context request plus the null page (need "
-                    f">= {self._max_pages + 1})")
-        elif config.prefix_cache:
+                    "num_pages needs page_size (the default pool is one "
+                    "max_len page a slot)")
+        # tokens a page: with none named, one page holds a slot's whole
+        # context (one page a slot, num_slots + 1 pages)
+        ps = self.page_size = int(
+            self.max_len if config.page_size is None else config.page_size)
+        if ps <= 0 or self.max_len % ps:
             raise ValueError(
-                "prefix_cache=True needs the paged pool: set page_size "
-                "(prefix sharing is page-granular)")
-        elif config.num_pages is not None:
-            raise ValueError("num_pages needs page_size (paged mode)")
-        self.model.refuse(config, self._paged)
+                f"page_size={config.page_size} must be positive and "
+                f"divide max_len={self.max_len}")
+        self._max_pages = self.max_len // ps
+        self._num_pages = int(
+            config.num_pages or config.num_slots * self._max_pages + 1)
+        if self._num_pages < self._max_pages + 1:
+            raise ValueError(
+                f"num_pages={self._num_pages} cannot hold one "
+                f"full-context request plus the null page (need "
+                f">= {self._max_pages + 1})")
+        self.model.refuse(config)
         h = self.model.heads
         # tensor-parallel mesh (docs/serving.md "Tensor-parallel
         # decode"): every geometry error is a build-time ValueError,
@@ -303,13 +312,14 @@ class Engine:
             self.params = jax.tree_util.tree_map(jnp.asarray, params)
         # resolve the tuned geometry ONCE at engine build (cache lookups
         # at trace time inside scan would re-announce per position);
-        # paged mode validates block_k against page_size here — a tuned
+        # block_k is validated against page_size here — a tuned
         # or explicit chunk that does not divide the page is a clear
         # ValueError at build, never a bad gather at trace time. A
         # sharded engine tunes at its PER-SHARD head count with the
         # shard count as its own key axis (winners never leak across
         # mesh shapes)
-        self.block_k = self.model.block_k(self.max_len, config, self._tp)
+        self.block_k = self.model.block_k(self.max_len, ps, config,
+                                          self._tp)
         # speculative decoding + the DecodePolicy seam: every bad knob is
         # a build-time ValueError (both CLIs surface them as exit 2
         # before any compile)
@@ -572,7 +582,7 @@ class Engine:
     def _policy_args(self):
         """Per-slot policy knobs as a jit-argument pytree (DATA — new
         values never retrace); None when the seam is unarmed, which
-        keeps every legacy trace signature byte-identical."""
+        keeps the unarmed engine's trace signature as it is."""
         if self._policy is None:
             return None
         return {"temps": jnp.asarray(self._pol_temps),
@@ -611,8 +621,8 @@ class Engine:
         Each fresh compile publishes its static XLA memory reservation as
         an ``hbm_snapshot`` event (``apex_tpu.monitor.memory``) — the
         serving AOT points are where the engine's HBM budget is decided,
-        and the paged-vs-slot capacity comparison reads them — together
-        with the lowered module's size and ``main`` argument count
+        and a capacity comparison of two pool geometries reads them —
+        together with the lowered module's size and ``main`` argument count
         (``monitor.costs.module_facts``: the weights must be arguments).
         """
         from apex_tpu.monitor.costs import module_facts
@@ -630,7 +640,7 @@ class Engine:
             publish_compiled_memory(
                 "serve_decode", self._decode_aot,
                 num_slots=self.config.num_slots, max_len=self.max_len,
-                page_size=self.config.page_size or 0,
+                page_size=self.page_size,
                 kv_cache_bytes=self.kv_cache_bytes,
                 **module_facts(self._decode_lowered.as_text()))
         for bucket in prompt_buckets:
@@ -663,7 +673,7 @@ class Engine:
                 "serve_verify", self._verify_aot,
                 draft_len=self._spec_k,
                 num_slots=self.config.num_slots, max_len=self.max_len,
-                page_size=self.config.page_size or 0,
+                page_size=self.page_size,
                 **module_facts(self._verify_lowered.as_text()))
         return self
 
@@ -699,28 +709,20 @@ class Engine:
         failed call took the buffers with it."""
         b = self.config.num_slots
         self.cache: Any = self.model.init_cache(
-            b, self.max_len, self.config.page_size,
-            self._num_pages if self._paged else None, self._kv_quant,
-            self._tp)
+            b, self.max_len, self.page_size, self._num_pages,
+            self._kv_quant, self._tp)
         if self.mesh is not None:
             # head-sharded K/V pools, replicated bookkeeping — placed at
             # init so the compiled step never pays a layout move
             self.cache = shard_cache(self.cache, self.mesh)
-        if self._paged:
-            ps = int(self.config.page_size)
-            self.pool: Optional[PagePool] = PagePool(self._num_pages, ps)
-            self.prefix: Optional[PrefixIndex] = \
-                PrefixIndex(ps) if self.config.prefix_cache else None
-            self._page_table = np.zeros((b, self._max_pages), np.int32)
-            self._slot_pages = [[] for _ in range(b)]
-            # per-slot admitted token capacity (pages reserved at
-            # admission × page_size); slot engines use max_len flat
-            self._slot_capacity = np.zeros((b,), np.int64)
-        else:
-            self.pool = None
-            self.prefix = None
-            self._slot_pages = [[] for _ in range(b)]
-            self._slot_capacity = np.full((b,), self.max_len, np.int64)
+        self.pool = PagePool(self._num_pages, self.page_size)
+        self.prefix: Optional[PrefixIndex] = \
+            PrefixIndex(self.page_size) if self.config.prefix_cache else None
+        self._page_table = np.zeros((b, self._max_pages), np.int32)
+        self._slot_pages = [[] for _ in range(b)]
+        # per-slot admitted token capacity: pages reserved at admission
+        # × page_size
+        self._slot_capacity = np.zeros((b,), np.int64)
         # host mirror of cache.lengths (advanced deterministically by
         # prefill/decode/evict) — lets decode_step enforce the context
         # bound without a per-step device fetch
@@ -757,7 +759,7 @@ class Engine:
         A server drain/restart costs zero recompiles; tests reuse one
         compiled engine across scenarios.
 
-        Paged engines reset the page-pool free list and the prefix index
+        The page-pool free list and the prefix index are reset
         too (a leaked refcount would poison the next scenario — tier-1
         regression-tests this). ``keep_prefix_cache=True`` (warm restart)
         instead releases every slot's page references but keeps the pool
@@ -765,7 +767,7 @@ class Engine:
         crash cannot have corrupted them, and recovery re-prefills only
         the unshared tail of each surviving slot.
         """
-        if keep_prefix_cache and self._paged and self.prefix is not None:
+        if keep_prefix_cache and self.prefix is not None:
             b = self.config.num_slots
             for slot in range(b):
                 self._release_slot_pages(slot)
@@ -811,15 +813,13 @@ class Engine:
         self.rng = jnp.asarray(np.asarray(state["rng"], np.uint32))
         self.last_tokens = np.asarray(state["last_tokens"], np.int32)
 
-    def paging_state(self) -> Optional[Dict[str, Any]]:
-        """The page-accounting view a tick journal records (None for a
-        slot engine): per-slot page tables, pool refcounts, and the
-        prefix-index size — the postmortem answer to "where did the HBM
-        go" and the integrity cross-check for paged recovery."""
-        if not self._paged:
-            return None
+    def paging_state(self) -> Dict[str, Any]:
+        """The page-accounting view a tick journal records: per-slot
+        page tables, pool refcounts, and the prefix-index size — the
+        postmortem answer to "where did the HBM go" and the integrity
+        cross-check for recovery."""
         return {
-            "page_size": int(self.config.page_size),
+            "page_size": self.page_size,
             "num_pages": self._num_pages,
             "free_pages": self.pool.free_count,
             "refcounts": list(self.pool.refcount),
@@ -832,8 +832,6 @@ class Engine:
     def _release_slot_pages(self, slot: int) -> None:
         """Drop the slot's page references (completion, eviction, or the
         re-prefill prologue); index-pinned prefix pages survive."""
-        if not self._paged:
-            return
         for page in self._slot_pages[slot]:
             self.pool.release(page)
         self._slot_pages[slot] = []
@@ -854,13 +852,11 @@ class Engine:
         a later member, or prefill's eviction (which protects the whole
         batch's hits) would free fewer pages than the probes assumed
         and fail allocation mid-batch. Never touches the pool — the
-        scheduler probes before popping a request. Slot engines always
-        fit (cost 0)."""
-        if not self._paged:
-            return 0
+        scheduler probes before popping a request. With the default
+        geometry (one page a slot) a free slot always has its page."""
         plan = paging.plan_admission(
-            tokens, budget, self.max_len, int(self.config.page_size),
-            self.prefix, touch=False)
+            tokens, budget, self.max_len, self.page_size, self.prefix,
+            touch=False)
         hits = {pg for _, pg in plan["hits"]}
         protect_all = hits | (protect or set())
         avail = self.pool.free_count
@@ -876,20 +872,17 @@ class Engine:
     def _occupancy(self, act_np: np.ndarray,
                    program: str = "decode") -> Dict[str, int]:
         """What a decode step's span carries: slots fed and held, tokens
-        resident before the step, (paged) pool pages out of the free
+        resident before the step, pool pages out of the free
         list, and what the compiled ``program`` says of the pool
         (``pool_aliased_bytes``, ``pool_copies``: there once
         :meth:`aot_compile` holds the executable). Host ints the engine
         already keeps; nothing is read from the device."""
-        attrs = {"active": int(act_np.sum()),
-                 "slots": self.config.num_slots,
-                 "resident": self.resident_tokens,
-                 **self._pool_facts.get(program, {})}
-        if self._paged:
-            attrs["pages_in_use"] = (self.pool.capacity
-                                     - self.pool.free_count)
-            attrs["pages"] = self.pool.capacity
-        return attrs
+        return {"active": int(act_np.sum()),
+                "slots": self.config.num_slots,
+                "resident": self.resident_tokens,
+                **self._pool_facts.get(program, {}),
+                "pages_in_use": self.pool.capacity - self.pool.free_count,
+                "pages": self.pool.capacity}
 
     def _note_counters(self, span: str, counters, real_rows: int) -> None:
         """Where the model's forward returned counters (an expert
@@ -913,7 +906,7 @@ class Engine:
         ``(first_tokens [B], last_logits [B, vocab], all_logits [P, B,
         vocab] | None)``; only the admitted slots' rows are meaningful.
 
-        Paged mode: ``budgets[slot]`` (default: worst case ``max_len -
+        ``budgets[slot]`` (default: worst case ``max_len -
         len(prompt)``) sizes the page reservation — pages for the whole
         admitted budget are taken here so decode never allocates. With a
         prefix index, the longest indexed prefix is shared read-only and
@@ -977,8 +970,8 @@ class Engine:
             full_lens = starts + lens
             self._host_lengths = np.where(admit, full_lens,
                                           self._host_lengths)
-            if self._paged and self.prefix is not None:
-                ps = int(self.config.page_size)
+            if self.prefix is not None:
+                ps = self.page_size
                 with annotate("apex.prefill.index"):
                     for slot, toks in prompts.items():
                         upto = (cacheable or {}).get(slot, len(toks))
@@ -986,7 +979,7 @@ class Engine:
                         for i, h in enumerate(
                                 paging.chunk_hashes(list(toks[:upto]), ps)):
                             self.prefix.insert(h, row[i], self.pool)
-            if self._paged and self._kv_quant is not None:
+            if self._kv_quant is not None:
                 # quantized-capacity provenance: these pages now hold
                 # codec bytes + scales, not fp32 rows — counted so a bench
                 # capture can prove its resident_tokens_per_hbm_byte came
@@ -1005,70 +998,65 @@ class Engine:
         tails: Dict[int, Sequence[int]] = dict(prompts)
         self.last_prefill_stats = {}
         new_pages = 0
-        if self._paged:
-            ps = int(self.config.page_size)
-            for slot in prompts:
-                # the slot may still hold pages (same-tick backfill
-                # defers the device-side evict; tests re-prefill
-                # directly) — release before re-planning
-                self._release_slot_pages(slot)
-            # two passes: plan every slot BEFORE any eviction, so one
-            # slot's LRU eviction can never free a page another batch
-            # member planned to share (the probe counted those hits —
-            # evicting them would make its page math wrong mid-batch)
-            plans = {}
-            for slot in sorted(prompts):
-                toks = prompts[slot]
-                budget = (budgets or {}).get(slot)
-                if budget is None:
-                    budget = self.max_len - len(toks)
-                plans[slot] = paging.plan_admission(
-                    toks, budget, self.max_len, ps, self.prefix,
-                    touch=True)
-            protect_all = {pg for plan in plans.values()
-                           for _, pg in plan["hits"]}
-            for slot in sorted(prompts):
-                plan = plans[slot]
-                shared = [pg for _, pg
-                          in plan["hits"][:plan["shared_pages"]]]
-                if plan["new_pages"] > self.pool.free_count \
-                        and self.prefix is not None:
-                    self.prefix.evict(
-                        self.pool,
-                        plan["new_pages"] - self.pool.free_count,
-                        protect=protect_all)
-                fresh = self.pool.alloc(plan["new_pages"])
-                new_pages += len(fresh)
-                for pg in shared:
-                    self.pool.retain(pg)
-                if plan["cow_src"] is not None:
-                    # copy-on-write: the tail starts mid-page, so the
-                    # slot gets its own writable copy of the boundary
-                    # page (one compiled op; identical bytes)
-                    self.cache = self._donating(
-                        self._copy_page, self.cache, plan["cow_src"],
-                        fresh[0])
-                row = shared + fresh
-                self._page_table[slot, :] = paging.NULL_PAGE
-                self._page_table[slot, :len(row)] = row
-                self._slot_pages[slot] = row
-                self._slot_capacity[slot] = plan["total_pages"] * ps
-                starts[slot] = plan["use"]
-                tails[slot] = plan["tail"]
-                if plan["use"]:
-                    self.prefix_hits += 1
-                    self.prefix_hit_tokens += plan["use"]
-                self.last_prefill_stats[slot] = {
-                    "hit_tokens": plan["use"],
-                    "hit_pages": plan["shared_pages"],
-                    "scanned": len(plan["tail"]),
-                }
-            self.cache = self.cache.replace(
-                page_table=jnp.asarray(self._page_table))
-        else:
-            for slot, toks in prompts.items():
-                self.last_prefill_stats[slot] = {
-                    "hit_tokens": 0, "hit_pages": 0, "scanned": len(toks)}
+        ps = self.page_size
+        for slot in prompts:
+            # the slot may still hold pages (same-tick backfill
+            # defers the device-side evict; tests re-prefill
+            # directly) — release before re-planning
+            self._release_slot_pages(slot)
+        # two passes: plan every slot BEFORE any eviction, so one
+        # slot's LRU eviction can never free a page another batch
+        # member planned to share (the probe counted those hits —
+        # evicting them would make its page math wrong mid-batch)
+        plans = {}
+        for slot in sorted(prompts):
+            toks = prompts[slot]
+            budget = (budgets or {}).get(slot)
+            if budget is None:
+                budget = self.max_len - len(toks)
+            plans[slot] = paging.plan_admission(
+                toks, budget, self.max_len, ps, self.prefix,
+                touch=True)
+        protect_all = {pg for plan in plans.values()
+                       for _, pg in plan["hits"]}
+        for slot in sorted(prompts):
+            plan = plans[slot]
+            shared = [pg for _, pg
+                      in plan["hits"][:plan["shared_pages"]]]
+            if plan["new_pages"] > self.pool.free_count \
+                    and self.prefix is not None:
+                self.prefix.evict(
+                    self.pool,
+                    plan["new_pages"] - self.pool.free_count,
+                    protect=protect_all)
+            fresh = self.pool.alloc(plan["new_pages"])
+            new_pages += len(fresh)
+            for pg in shared:
+                self.pool.retain(pg)
+            if plan["cow_src"] is not None:
+                # copy-on-write: the tail starts mid-page, so the
+                # slot gets its own writable copy of the boundary
+                # page (one compiled op; identical bytes)
+                self.cache = self._donating(
+                    self._copy_page, self.cache, plan["cow_src"],
+                    fresh[0])
+            row = shared + fresh
+            self._page_table[slot, :] = paging.NULL_PAGE
+            self._page_table[slot, :len(row)] = row
+            self._slot_pages[slot] = row
+            self._slot_capacity[slot] = plan["total_pages"] * ps
+            starts[slot] = plan["use"]
+            tails[slot] = plan["tail"]
+            if plan["use"]:
+                self.prefix_hits += 1
+                self.prefix_hit_tokens += plan["use"]
+            self.last_prefill_stats[slot] = {
+                "hit_tokens": plan["use"],
+                "hit_pages": plan["shared_pages"],
+                "scanned": len(plan["tail"]),
+            }
+        self.cache = self.cache.replace(
+            page_table=jnp.asarray(self._page_table))
         return starts, tails, new_pages
 
     def decode_step(self, last_tokens, active):
@@ -1080,9 +1068,9 @@ class Engine:
         with annotate("apex.decode_step", **self._occupancy(act_np)):
             full = act_np & (self._host_lengths >= self._slot_capacity)
             if full.any():
-                # the cache write would silently clip (slot cache) or land
-                # in an unreserved page (paged) and corrupt the newest K/V
-                # row — refuse instead; the scheduler terminates at
+                # the cache write would land in an unreserved page, or be
+                # clipped onto the newest K/V row at max_len, and corrupt
+                # it — refuse instead; the scheduler terminates at
                 # context-full / budget before ever reaching this
                 raise ValueError(
                     f"slot(s) {np.flatnonzero(full).tolist()} are at their "
@@ -1172,9 +1160,9 @@ class Engine:
                     f"[0, spec_draft_len={self._spec_k}]")
             # capacity backstop, mirroring decode_step's refusal: the
             # verify scan writes positions length..length+draft_len, and
-            # commits up to draft_len + 1 tokens — an overrun would clip
-            # (slot cache) or land in an unreserved page (paged) and
-            # corrupt K/V rows
+            # commits up to draft_len + 1 tokens — an overrun would land
+            # in an unreserved page (or clip at max_len) and corrupt K/V
+            # rows
             need = self._host_lengths + np.where(act_np, dl_np + 1, 0)
             over = act_np & (need > self._slot_capacity)
             if over.any():
@@ -1207,18 +1195,17 @@ class Engine:
             return committed_np, counts_np
 
     def evict(self, slots) -> None:
-        """Free the given slot indices (mask-shaped op, compiled once);
-        paged engines return the slots' page references to the pool
-        (index-pinned prefix pages stay resident)."""
+        """Free the given slot indices (mask-shaped op, compiled once)
+        and return the slots' page references to the pool (index-pinned
+        prefix pages stay resident)."""
         mask = np.zeros((self.config.num_slots,), bool)
         mask[np.asarray(list(slots), np.int64)] = True
         # only ``lengths`` goes through a program: the pool's arrays are
         # the same buffers before and after
         self.cache = kv_cache.evict_slots(self.cache, jnp.asarray(mask))
         self._host_lengths = np.where(mask, 0, self._host_lengths)
-        if self._paged:
-            for slot in np.flatnonzero(mask):
-                self._release_slot_pages(int(slot))
+        for slot in np.flatnonzero(mask):
+            self._release_slot_pages(int(slot))
 
     # --------------------- page migration (disaggregated prefill→decode)
     def export_prefix_pages(self, tokens: Sequence[int]):
@@ -1232,9 +1219,9 @@ class Engine:
         exported (:func:`~apex_tpu.serve.paging.page_payload_digest`), so
         the receiver can certify the transfer. ``touch=False``: an
         export is a read, not a use — it must not reorder the donor's
-        LRU. Empty when not paged / no prefix index / no indexed prefix.
+        LRU. Empty when there is no prefix index / no indexed prefix.
         """
-        if not self._paged or self.prefix is None:
+        if self.prefix is None:
             return []
         self._refuse_page_migration()
         out = []
@@ -1269,8 +1256,8 @@ class Engine:
         "no_capacity"}`` counts. Certification (chain-hash + payload
         digest) is the CALLER's job — the disaggregation controller
         refuses un-certified pages before they reach here; this method
-        enforces only the structural contract (paged + prefix engine,
-        exact payload shape).
+        enforces only the structural contract (an engine with a prefix
+        index, exact payload shape).
 
         Exactly-once by construction: a payload whose chain hash is
         already indexed is a duplicate stream (failover replay, a second
@@ -1281,14 +1268,14 @@ class Engine:
         prefix pages, and the next admission of the migrated prompt
         shares them read-only exactly as a local prefix hit.
         """
-        if not self._paged or self.prefix is None:
+        if self.prefix is None:
             raise ValueError(
-                "import_prefix_pages needs a paged engine with "
+                "import_prefix_pages needs an engine with page_size and "
                 "prefix_cache=True (page migration lands in the prefix "
                 "index)")
         self._refuse_page_migration()
-        ps = int(self.config.page_size)
-        shape = (self.model.n_layer, ps) + tuple(self.cache.k.shape[3:])
+        shape = (self.model.n_layer, self.page_size) \
+            + tuple(self.cache.k.shape[3:])
         stats = {"installed": 0, "duplicate": 0, "no_capacity": 0}
         for p in payloads:
             if tuple(np.shape(p["k"])) != shape or \
@@ -1343,10 +1330,6 @@ class Engine:
     @property
     def lengths(self) -> np.ndarray:
         return np.asarray(self.cache.lengths)
-
-    @property
-    def paged(self) -> bool:
-        return self._paged
 
     # ------------------------------------------------- tensor parallel
     @property
@@ -1415,7 +1398,7 @@ class Engine:
             "model": self.model.name,
             "num_slots": int(self.config.num_slots),
             "max_len": int(self.max_len),
-            "page_size": int(self.config.page_size or 0),
+            "page_size": self.page_size,
             "dtype": dtype.name,
             "dtype_bytes": int(dtype.itemsize),
             "block_k": int(self.block_k),
@@ -1447,13 +1430,11 @@ class Engine:
     @property
     def free_page_frac(self) -> float:
         """Fraction of the pool allocatable RIGHT NOW: free pages plus
-        index-only cached pages an LRU sweep could evict on demand (1.0
-        for slot engines — they have no pool to pressure). Counting
-        evictable pages matters: a warm prefix cache deliberately keeps
-        the free list near empty, so raw free_count reads as permanent
-        pressure on an engine that actually has plenty of headroom."""
-        if not self._paged:
-            return 1.0
+        index-only cached pages an LRU sweep could evict on demand.
+        Counting evictable pages matters: a warm prefix cache
+        deliberately keeps the free list near empty, so raw free_count
+        reads as permanent pressure on an engine that actually has plenty
+        of headroom."""
         free = self.pool.free_count
         if self.prefix is not None:
             free += self.prefix.evictable(self.pool)
@@ -1476,9 +1457,8 @@ class Engine:
 
     @property
     def kv_cache_bytes(self) -> int:
-        """Resident bytes of the KV buffers — the slot cache's
-        ``num_slots * max_len`` reservation, or the paged pool's
-        ``num_pages * page_size``, INCLUDING the fp32 scale planes when
+        """Resident bytes of the KV buffers — the pool's
+        ``num_pages * page_size`` tokens, INCLUDING the fp32 scale planes when
         ``kv_quant`` is armed (the capacity win must be priced net of
         its scale overhead); stamped into the serving AOT
         ``hbm_snapshot`` and the bench's

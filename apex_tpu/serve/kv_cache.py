@@ -1,24 +1,24 @@
-"""Static-shape KV caches: slot-addressed, and the paged block pool.
+"""The static-shape KV cache: a paged block pool behind a page table.
 
-Two layouts, one contract — every array shape is fixed at engine build
-and request admission/completion/eviction only move *values*, so the
-jitted decode step that closes over either pytree compiles exactly once:
+Every array shape is fixed at engine build, and request admission,
+completion and eviction only move *values*, so the jitted decode step
+that takes the pytree compiles exactly once:
 
-- :class:`KVCache` — per-slot reservation: ``k``/``v`` are
-  ``[n_layer, num_slots, max_len, heads, head_dim]`` plus per-slot
-  ``lengths``. Simple, but every slot pays ``max_len`` tokens of HBM
-  whatever its request actually uses.
 - :class:`PagedKVCache` — a shared block pool: ``k``/``v`` are
   ``[n_layer, num_pages, page_size, heads, head_dim]`` plus a per-slot
-  page table ``[num_slots, max_pages_per_slot]`` of pool indices and the
-  same ``lengths``. A slot's virtual key axis is its page-table row laid
-  end to end; position ``p`` lives at ``(page_table[slot, p // page_size],
-  p % page_size)``. Page indices are DATA (host-allocated in
+  page table ``[num_slots, max_pages_per_slot]`` of pool indices and
+  per-slot ``lengths``. A slot's virtual key axis is its page-table row
+  laid end to end; position ``p`` lives at ``(page_table[slot, p //
+  page_size], p % page_size)``. Page indices are DATA (host-allocated in
   :mod:`apex_tpu.serve.paging`, threaded through the compiled call),
   never shapes — so paging multiplies resident requests per HBM byte
   without touching the one-compile invariant. Page 0 is the reserved
   null page: masked-off writes are routed there and unmapped table
   entries read its zeros (discarded by the attention reachability mask).
+  How a slot's tokens lie in HBM is decided here and nowhere else: a
+  per-slot reservation of ``max_len`` tokens is this pool with
+  ``page_size == max_len`` (one page a slot, ``num_slots + 1`` pages),
+  which is what an engine built without a ``page_size`` allocates.
 
 - :class:`PagedLatentCache` — the paged pool of a latent-attention (MLA)
   model: ONE array ``rows`` ``[n_layer, num_pages, page_size, row]``
@@ -27,7 +27,7 @@ jitted decode step that closes over either pytree compiles exactly once:
   ``page_table`` and ``lengths``, so the allocator, the prefix index and
   every length mutator below serve it unchanged.
 
-**A token's row is whole tiles** in all three: the ``heads`` axis of
+**A token's row is whole tiles** in both: the ``heads`` axis of
 ``k``/``v`` (and of their scale planes) is allocated as whole groups of
 8 sublanes (:func:`padded_heads`: 25 heads lie in 32, the rest zeros:
 :func:`pad_heads`; attention runs over all of them and drops the
@@ -42,7 +42,7 @@ is given it by donation, so its writes land in the buffers it was
 handed and no call copies a pool. A donated cache is gone: whoever kept
 a reference to one across an engine call holds deleted arrays. The
 functions here donate nothing themselves (a test may
-``jax.jit(write_token)`` and keep its input). Masked writes
+``jax.jit(paged_write_token)`` and keep its input). Masked writes
 read-modify-write the existing token so an inactive slot's bytes are
 untouched — slot isolation is structural, not best-effort.
 
@@ -54,8 +54,7 @@ like the payload minus the head_dim axis, so scales ride every page
 behaviour (prefix sharing, COW, eviction, export/import, tp head
 sharding) through the exact same code paths as the payload. On an
 unquantized cache both fields are ``None`` — an empty pytree node, so
-legacy pytrees are structurally identical to before the feature
-existed.
+an unquantized pytree has no leaf for them.
 """
 
 from __future__ import annotations
@@ -93,123 +92,24 @@ def pad_last(x: jax.Array, size: int) -> jax.Array:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, size - x.shape[-1])])
 
 
-@flax.struct.dataclass
-class KVCache:
-    """Pytree of the serving cache; see module docstring for shapes."""
-
-    k: jax.Array        # [n_layer, num_slots, max_len, padded heads, head_dim]
-    v: jax.Array        # same shape as k
-    lengths: jax.Array  # [num_slots] int32 — tokens resident per slot
-    # per-(token, head) fp32 codec scales when kv_quant is armed:
-    # [n_layer, num_slots, max_len, heads]; None when unquantized
-    k_scale: Optional[jax.Array] = None
-    v_scale: Optional[jax.Array] = None
-
-    @property
-    def n_layer(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def num_slots(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[2]
+# ``lengths`` is all the four below touch: they serve PagedKVCache and
+# PagedLatentCache alike
 
 
-def init_cache(n_layer: int, num_slots: int, max_len: int, heads: int,
-               head_dim: int, dtype: Any = jnp.float32,
-               kv_quant: Optional[str] = None, shards: int = 1) -> KVCache:
-    """Allocate an empty cache. ``max_len`` bounds every request's total
-    context (prompt + generated); the scheduler terminates a request that
-    reaches it. With ``kv_quant`` the payload arrays take the codec's
-    storage dtype and the fp32 scale planes are allocated alongside.
-    ``shards``: the tensor-parallel ranks the head axis will be split
-    over (:func:`padded_heads`)."""
-    shape = (n_layer, num_slots, max_len, padded_heads(heads, shards),
-             head_dim)
-    lengths = jnp.zeros((num_slots,), jnp.int32)
-    if kv_quant is None:
-        return KVCache(k=jnp.zeros(shape, dtype),
-                       v=jnp.zeros(shape, dtype), lengths=lengths)
-    from apex_tpu.quant.kv import kv_storage_dtype
-
-    sdtype = kv_storage_dtype(kv_quant)
-    return KVCache(
-        k=jnp.zeros(shape, sdtype), v=jnp.zeros(shape, sdtype),
-        lengths=lengths,
-        k_scale=jnp.zeros(shape[:-1], jnp.float32),
-        v_scale=jnp.zeros(shape[:-1], jnp.float32))
-
-
-@jax.named_scope("kv_write")
-def write_token(cache: KVCache, layer: int, k_tok: jax.Array,
-                v_tok: jax.Array, positions: jax.Array,
-                mask: jax.Array, codec: Optional[str] = None) -> KVCache:
-    """Write one token's K/V per slot at ``positions[slot]`` where
-    ``mask[slot]`` — the append primitive of both prefill and decode.
-
-    ``k_tok``/``v_tok``: ``[num_slots, heads, head_dim]``; ``positions``:
-    ``[num_slots]`` int32; ``mask``: ``[num_slots]`` bool. ``layer`` is a
-    python int (the model unrolls its layers), so the layer slice is
-    static. Masked-off slots get their current token written back
-    bit-for-bit; shapes never change, so this is recompile-free under jit.
-
-    With ``codec`` the token is block-scale encoded (one scale per head)
-    and codes + scales land in the same masked read-modify-write — the
-    scale write obeys the identical slot-isolation contract as the
-    payload write.
-    """
-    def _one(buf, tok, pos):       # buf [L, ...], tok [...]
-        return jax.lax.dynamic_update_slice(
-            buf, tok[None], (pos,) + (0,) * tok.ndim)
-
-    def _read(buf, pos):
-        return jax.lax.dynamic_slice(
-            buf, (pos,) + (0,) * (buf.ndim - 1), (1,) + buf.shape[1:])[0]
-
-    pos = jnp.clip(positions.astype(jnp.int32), 0, cache.max_len - 1)
-    out = {}
-    for name, tok in (("k", k_tok), ("v", v_tok)):
-        scales = None
-        if codec is not None:
-            from apex_tpu.quant.kv import encode_kv
-
-            tok, scales = encode_kv(codec, tok.astype(jnp.float32))
-        buf = getattr(cache, name)[layer]              # [B, L, H, d]
-        cur = jax.vmap(_read)(buf, pos)                # [B, H, d]
-        new = jnp.where(mask[:, None, None],
-                        pad_heads(tok.astype(buf.dtype), buf.shape[-2]),
-                        cur)
-        out[name] = getattr(cache, name).at[layer].set(
-            jax.vmap(_one)(buf, new, pos))
-        if scales is not None:
-            sname = name + "_scale"
-            sbuf = getattr(cache, sname)[layer]        # [B, L, H]
-            scur = jax.vmap(_read)(sbuf, pos)          # [B, H]
-            snew = jnp.where(mask[:, None], pad_last(
-                scales.astype(sbuf.dtype), sbuf.shape[-1]), scur)
-            out[sname] = getattr(cache, sname).at[layer].set(
-                jax.vmap(_one)(sbuf, snew, pos))
-    return cache.replace(**out)
-
-
-def advance(cache: KVCache, mask: jax.Array) -> KVCache:
+def advance(cache, mask: jax.Array):
     """Bump ``lengths`` by one for masked slots (after a decode append)."""
     return cache.replace(
         lengths=cache.lengths + mask.astype(jnp.int32))
 
 
-def reset_slots(cache: KVCache, mask: jax.Array) -> KVCache:
+def reset_slots(cache, mask: jax.Array):
     """Zero masked slots' lengths — insertion prologue: the slot's stale
     bytes stay in place and are unreachable behind ``lengths``."""
     return cache.replace(
         lengths=jnp.where(mask, 0, cache.lengths).astype(jnp.int32))
 
 
-def set_lengths(cache: KVCache, mask: jax.Array,
-                new_lengths: jax.Array) -> KVCache:
+def set_lengths(cache, mask: jax.Array, new_lengths: jax.Array):
     """Set masked slots' lengths (prefill epilogue: prompt lengths)."""
     return cache.replace(
         lengths=jnp.where(mask, new_lengths,
@@ -223,9 +123,8 @@ def set_lengths(cache: KVCache, mask: jax.Array,
 def evict_slots(cache, mask: jax.Array):
     """Free masked slots. Data is left in place; only ``lengths`` moves —
     the attention mask (``key_pos <= position``) makes the stale rows
-    unreachable, and the next insert overwrites them. Works on every
-    cache layout (it only touches ``lengths``; a paged slot's page
-    *indices* are host bookkeeping, freed by the allocator). The
+    unreachable, and the next insert overwrites them. A slot's page
+    *indices* are host bookkeeping, freed by the allocator. The
     returned cache holds the SAME pool arrays as ``cache``."""
     return reset_slots(cache, mask)
 
@@ -319,10 +218,15 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
                       v_tok: jax.Array, positions: jax.Array,
                       mask: jax.Array,
                       codec: Optional[str] = None) -> PagedKVCache:
-    """The paged analog of :func:`write_token`: append one token's K/V
-    per slot at virtual position ``positions[slot]`` — physical page
-    ``page_table[slot, pos // page_size]``, row ``pos % page_size`` —
-    where ``mask[slot]``.
+    """Append one token's K/V per slot at virtual position
+    ``positions[slot]`` — physical page ``page_table[slot, pos //
+    page_size]``, row ``pos % page_size`` — where ``mask[slot]``: the
+    decode step's write.
+
+    ``k_tok``/``v_tok``: ``[num_slots, heads, head_dim]``; ``positions``:
+    ``[num_slots]`` int32; ``mask``: ``[num_slots]`` bool. ``layer`` is a
+    python int (the model unrolls its layers), so the layer index is
+    static; shapes never change, so this is recompile-free under jit.
 
     Masked-off slots are routed to the null page (page 0) and write back
     its current row bit-for-bit: a stale page-table entry on an inactive
@@ -330,6 +234,10 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
     the same scatter. Live slots' target pages are uniquely owned by
     construction (the host allocator never maps one writable page into
     two tables), so the scatter indices of real writes never alias.
+
+    With ``codec`` the token is block-scale encoded (one scale per head)
+    and codes + scales land through the same indices — the scale write
+    obeys the identical slot-isolation contract as the payload write.
     """
     ps = cache.page_size
     pos = jnp.clip(positions.astype(jnp.int32), 0, cache.max_len - 1)
@@ -368,37 +276,34 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
                positions: jax.Array, mask: jax.Array,
                codec: Optional[str] = None):
     """Append a chunk of tokens' K/V per slot in one masked scatter —
-    the batched prefill's write, for either cache layout.
+    the batched prefill's write.
 
     ``k_rows``/``v_rows``: ``[num_slots, T, heads, head_dim]``;
     ``positions``/``mask``: ``[num_slots, T]`` (int32 absolute position,
-    bool). Row ``(b, t)`` lands at ``positions[b, t]`` of slot ``b`` —
-    through the page table on a paged cache — where ``mask[b, t]``. A
+    bool). Row ``(b, t)`` lands at ``positions[b, t]`` of slot ``b``,
+    through the page table, where ``mask[b, t]``. A
     masked-off row (a slot this call does not admit, or a prompt's
-    padding) is given an out-of-range index and DROPPED by the scatter:
+    padding) is given an out-of-range page and DROPPED by the scatter:
     no byte of a decoding neighbour, of the null page, or of a row past
     the prompt is touched. Real rows never alias (one slot's positions
     are distinct, and the allocator maps no writable page twice).
 
-    With ``codec`` each row is encoded exactly as :func:`write_token`
-    encodes it (one scale per token and head) and the scales take the
-    same scatter. Returns ``(cache, k_read, v_read)``: the cache, and the
-    rows as a later read of the cache returns them (fp32 decoded codes,
-    or the rows in the cache's dtype; the head axis padded as the
-    cache's is) — what the chunk's own attention
-    attends over, so that prefill sees the values decode will see.
+    With ``codec`` each row is encoded exactly as
+    :func:`paged_write_token` encodes it (one scale per token and head)
+    and the scales take the same scatter. Returns ``(cache, k_read,
+    v_read)``: the cache, and the rows as a later read of the cache
+    returns them (fp32 decoded codes, or the rows in the cache's dtype;
+    the head axis padded as the cache's is) — what the chunk's own
+    attention attends over, so that prefill sees the values decode will
+    see.
     """
     pos = positions.astype(jnp.int32)
     live = mask & (pos >= 0) & (pos < cache.max_len)
     slot = jnp.arange(pos.shape[0], dtype=jnp.int32)[:, None]
-    if hasattr(cache, "page_table"):
-        ps = cache.page_size
-        pages = cache.page_table[
-            slot, jnp.clip(pos // ps, 0, cache.max_pages_per_slot - 1)]
-        index = (layer, jnp.where(live, pages, cache.num_pages), pos % ps)
-    else:
-        index = (layer, jnp.broadcast_to(slot, pos.shape),
-                 jnp.where(live, pos, cache.max_len))
+    ps = cache.page_size
+    pages = cache.page_table[
+        slot, jnp.clip(pos // ps, 0, cache.max_pages_per_slot - 1)]
+    index = (layer, jnp.where(live, pages, cache.num_pages), pos % ps)
     out, read = {}, {}
     for name, rows in (("k", k_rows), ("v", v_rows)):
         buf = getattr(cache, name)
@@ -420,19 +325,18 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
 
 # ------------------------------------------------- tensor-parallel layout
 #
-# Both cache layouts shard the SAME axis under tensor parallelism: axis 3
-# is `heads` in `[n_layer, num_slots, max_len, heads, head_dim]` and in
-# `[n_layer, num_pages, page_size, heads, head_dim]` alike (a rank's
-# stretch of it holds its own heads first, then its padding). Everything
+# Tensor parallelism shards the pool's head axis: axis 3 of
+# `[n_layer, num_pages, page_size, heads, head_dim]` (a rank's stretch of
+# it holds its own heads first, then its padding). Everything
 # host-indexed — `lengths`, the page table, page/slot indices — stays
 # replicated data, which is why the allocator, prefix index, scheduler,
 # and journal are mesh-agnostic: a page index addresses every rank's
 # shard of that page simultaneously.
 
 
-def tp_cache_specs(cache, axis: str = "tp"):
+def tp_cache_specs(cache: PagedKVCache, axis: str = "tp") -> PagedKVCache:
     """``PartitionSpec`` pytree for a TP-sharded cache: ``k``/``v`` on
-    the head axis, ``lengths`` (and the page table) replicated. Shaped
+    the head axis, ``lengths`` and the page table replicated. Shaped
     like the cache pytree itself, so it serves as ``shard_map``
     in/out_specs and as the ``device_put`` placement recipe."""
     from jax.sharding import PartitionSpec as P
@@ -441,39 +345,28 @@ def tp_cache_specs(cache, axis: str = "tp"):
     # scale planes end on the head axis — scales shard with their pages
     # on the tp head axis by construction, not by a separate code path
     sc = None if cache.k_scale is None else P(None, None, None, axis)
-    if hasattr(cache, "page_table"):
-        return PagedKVCache(k=kv, v=kv, lengths=P(), page_table=P(),
-                            k_scale=sc, v_scale=sc)
-    return KVCache(k=kv, v=kv, lengths=P(), k_scale=sc, v_scale=sc)
+    return PagedKVCache(k=kv, v=kv, lengths=P(), page_table=P(),
+                        k_scale=sc, v_scale=sc)
 
 
-def shard_cache(cache, mesh, axis: str = "tp"):
+def shard_cache(cache: PagedKVCache, mesh, axis: str = "tp") -> PagedKVCache:
     """Place a freshly-initialized cache onto the serving mesh per
     :func:`tp_cache_specs` (head-sharded K/V pools, replicated
     bookkeeping). The caller has checked that heads divide over the mesh
-    axis and allocated the head axis for it (``init_cache(...,
+    axis and allocated the head axis for it (``init_paged_cache(...,
     shards=tp)``)."""
     from jax.sharding import NamedSharding
 
     # ONE spelling of the layout: the placement derives from the same
     # spec tree shard_map consumes, so the two can never drift
-    specs = tp_cache_specs(cache, axis)
-
-    def put(field):
-        return jax.device_put(getattr(cache, field),
-                              NamedSharding(mesh, getattr(specs, field)))
-
-    out = cache.replace(k=put("k"), v=put("v"), lengths=put("lengths"))
-    if hasattr(cache, "page_table"):
-        out = out.replace(page_table=put("page_table"))
-    if cache.k_scale is not None:
-        out = out.replace(k_scale=put("k_scale"), v_scale=put("v_scale"))
-    return out
+    return jax.tree_util.tree_map(
+        lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
+        cache, tp_cache_specs(cache, axis))
 
 
 def _token_arrays(cache) -> tuple:
-    """Names of the cache's arrays that hold tokens (axis 1 is the slot,
-    or the page of a paged layout)."""
+    """Names of the cache's arrays that hold tokens (axis 1 is the
+    page)."""
     if hasattr(cache, "rows"):
         return ("rows",)
     return ("k", "v") + (("k_scale", "v_scale")
@@ -482,7 +375,7 @@ def _token_arrays(cache) -> tuple:
 
 def cache_bytes(cache) -> int:
     """Resident bytes of the cache's token storage (scale planes
-    included), whatever the layout."""
+    included)."""
     return sum(int(getattr(cache, name).nbytes)
                for name in _token_arrays(cache))
 
@@ -510,11 +403,14 @@ def cache_bytes(cache) -> int:
 # other order of the minor axes, the runtime breaks the tie for it, and
 # its default IS the layout the programs work in. The bytes are those the
 # row-major layout of the unpadded shape would take (it pads to the same
-# tiles). A ``page_size`` or a slot cache's ``max_len`` that is a
-# multiple of 128 still wins the minor axis (the engine's ``pool_copies``
-# says what a compiled program got). With the cache donated to every
-# program that returns it, a write lands in the buffer it was handed and
-# nothing is copied.
+# tiles). That holds while no other axis makes a cheaper minor one: a
+# ``page_size`` that is a multiple of 128 (one page a slot at ``max_len``
+# 1024, say) pads nothing as the minor axis where row-major pads
+# ``head_dim`` 64 to 128, and a page axis of 65 pads less than that too,
+# so such pools are still relaid (the engine's ``pool_copies`` says what
+# a compiled program got; PERF.md, PR 32). With the cache donated to
+# every program that returns it, a write lands in the buffer it was
+# handed and nothing is copied.
 
 
 def pool_facts(compiled, cache) -> dict:

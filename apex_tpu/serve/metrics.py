@@ -124,7 +124,7 @@ class ServeMetrics:
             "serve_resident_tokens", "KV tokens resident across slots")
         self.free_page_frac = r.gauge(
             "serve_free_page_frac",
-            "paged-pool free fraction (1.0 on slot engines)", agg="min")
+            "KV pool free-page fraction", agg="min")
         obj = ("objective",)
         self.slo_burn_short = r.gauge(
             "serve_slo_burn_short",

@@ -6,12 +6,13 @@ model gives it, through one object:
 
 - ``name``, ``cfg``, ``max_positions``, ``n_layer``, ``vocab_size``,
   ``compute_dtype``, and ``workload()``: what a cost ledger says of it;
-- ``refuse(config, paged)``: a build-time ``ValueError`` for every engine
+- ``refuse(config)``: a build-time ``ValueError`` for every engine
   mode the model has no mechanism for, naming the mechanism;
-- ``block_k(max_len, config, tp)``: the decode-attention chunk, resolved
-  once at build;
+- ``block_k(max_len, page_size, config, tp)``: the decode-attention
+  chunk, resolved once at build (``page_size`` is the engine's resolved
+  one: ``max_len`` where the config names none);
 - ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant, tp)``:
-  its cache pytree, with ``lengths`` (and ``page_table`` where paged) as
+  its cache pytree, a paged pool with ``lengths`` and ``page_table`` as
   ``serve/kv_cache.py`` and ``serve/paging.py`` expect them, allocated
   for ``tp`` ranks;
 - ``forward(weights, cache, tokens, positions, mask, logits_at=None, *,
@@ -39,7 +40,7 @@ from apex_tpu.serve.attention import resolve_block_k
 
 
 class GPT2Serving:
-    """GPT-2 of any size: both cache layouts, every engine mode."""
+    """GPT-2 of any size: every engine mode."""
 
     name = "gpt2"
 
@@ -50,20 +51,16 @@ class GPT2Serving:
         self.compute_dtype = cfg.compute_dtype
         self.heads, self.head_dim = cfg.n_head, cfg.n_embd // cfg.n_head
 
-    def refuse(self, config, paged: bool) -> None:
+    def refuse(self, config) -> None:
         return None
 
-    def block_k(self, max_len: int, config, tp: int) -> int:
+    def block_k(self, max_len: int, page_size: int, config, tp: int) -> int:
         return resolve_block_k(max_len, self.heads // tp, self.head_dim,
                                self.compute_dtype, config.block_k,
-                               page_size=config.page_size, tp_shards=tp)
+                               page_size=page_size, tp_shards=tp)
 
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):
-        if page_size is None:
-            return kv_cache.init_cache(
-                self.n_layer, num_slots, max_len, self.heads, self.head_dim,
-                self.compute_dtype, kv_quant=kv_quant, shards=tp)
         return kv_cache.init_paged_cache(
             self.n_layer, num_slots, max_len, page_size, num_pages,
             self.heads, self.head_dim, self.compute_dtype, kv_quant=kv_quant,
@@ -93,12 +90,8 @@ class DeepseekV3Serving:
         self.heads, self.head_dim = (cfg.num_attention_heads,
                                      cfg.latent_width)
 
-    def refuse(self, config, paged: bool) -> None:
+    def refuse(self, config) -> None:
         missing = []
-        if not paged:
-            missing.append("page_size=None: the latent cache exists only "
-                           "as a paged pool (kv_cache.PagedLatentCache has "
-                           "no slot-contiguous twin)")
         if config.tp > 1:
             missing.append(f"tp={config.tp}: there is no per-rank forward "
                            f"that shards MLA's heads and no head axis in "
@@ -117,14 +110,14 @@ class DeepseekV3Serving:
             raise ValueError(
                 "deepseek_v3 is not served with " + "; ".join(missing))
 
-    def block_k(self, max_len: int, config, tp: int) -> int:
+    def block_k(self, max_len: int, page_size: int, config, tp: int) -> int:
         # latent attention reads whole pages; the knob is accepted where
         # it names one
-        if config.block_k not in (None, config.page_size):
+        if config.block_k not in (None, page_size):
             raise ValueError(
                 f"block_k={config.block_k}: deepseek_v3's latent attention "
-                f"reads a page at a time (page_size={config.page_size})")
-        return int(config.page_size)
+                f"reads a page at a time (page_size={page_size})")
+        return int(page_size)
 
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):

@@ -273,7 +273,7 @@ def plan_admission(tokens: Sequence[int], budget: int, max_len: int,
     """
     n = len(tokens)
     hits = index.lookup(tokens, touch=touch) if index is not None else []
-    # clamp at 0: an empty prompt (n=0, legal on the slot path) must plan
+    # clamp at 0: an empty prompt (n=0, legal for the planner) must plan
     # zero shared tokens, not use=-1 (whose tail-page remainder would
     # index hits[-1] on an empty hit list)
     use = max(0, min(len(hits) * page_size, n - 1))

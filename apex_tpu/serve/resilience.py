@@ -80,15 +80,15 @@ class AdmissionController:
     the overload signal — ``queue_depth >= queue_high`` (default
     ``ceil(queue_high_frac * max_queue)``), HBM allocator usage at
     ``hbm_frac_high`` of the device limit (fed from the PR-6
-    ``hbm_snapshot`` sampling via :meth:`note_hbm`), or the paged KV
+    ``hbm_snapshot`` sampling via :meth:`note_hbm`), or the KV
     pool's free-page fraction at or below ``pool_frac_low`` (fed from
     the scheduler via :meth:`note_pool`) — holds for ``sustain_ticks``
     consecutive ticks, newly admitted requests have ``max_new_tokens``
     clamped until the signal clears for the same number of ticks. A
     one-tick spike never flips the mode. Clamping admitted budgets is
-    doubly effective on a paged engine: the budget sizes the page
-    reservation, so degradation directly relieves the pool pressure
-    that triggered it.
+    doubly effective where pages are smaller than ``max_len``: the
+    budget sizes the page reservation, so degradation directly relieves
+    the pool pressure that triggered it.
     """
 
     def __init__(self, max_queue: Optional[int] = None,
@@ -150,8 +150,9 @@ class AdmissionController:
             self._hbm_frac = stats.get("bytes_in_use", 0) / float(limit)
 
     def note_pool(self, free_frac: Optional[float]) -> None:
-        """Feed the paged KV pool's free-page fraction (the scheduler
-        forwards ``Engine.free_page_frac`` per tick on paged engines) —
+        """Feed the KV pool's free-page fraction (the scheduler
+        forwards ``Engine.free_page_frac`` per tick where a page is
+        smaller than a slot's context) —
         the low-watermark overload signal for KV capacity."""
         if free_frac is not None:
             self._pool_free_frac = float(free_frac)
@@ -249,12 +250,10 @@ class TickJournal:
                         "prompt_tokens": len(r.tokens)}
                        for r in snap["queued"]],
         }
-        # paged engines: page tables + pool refcounts + prefix-index size
+        # page tables + pool refcounts + prefix-index size
         # (docs/serving.md "Paged KV pool" — the postmortem answer to
-        # "where did the HBM go"; absent entirely for slot engines so
-        # pre-paging journal consumers see an unchanged document)
-        if snap.get("paging") is not None:
-            out["paging"] = snap["paging"]
+        # "where did the HBM go")
+        out["paging"] = snap["paging"]
         return out
 
     def save(self, path: Optional[str] = None) -> str:

@@ -47,9 +47,9 @@ picks them up with zero wiring:
   — a warm restart recovered the fleet after a fatal tick exception
 - ``serve_prefix_hit``        {request_id, slot, hit_tokens, hit_pages,
   scanned_tokens} — an admission reused resident read-only prefix pages
-  and skipped prefilling them (paged engines with ``prefix_cache``)
+  and skipped prefilling them (engines with ``prefix_cache``)
 - ``serve_page_alloc_fail``   {seconds, queue_depth, free_page_frac} —
-  admission stalled because the paged KV pool had no free pages;
+  admission stalled because the KV pool had no free pages;
   ``seconds`` (the whole head-of-queue stall window) is a timed loss
   cause distinct from plain ``serve_queue_wait`` — capacity lost to KV
   bytes, not to slot count
@@ -247,10 +247,10 @@ class ServeStats:
             "restarts": self.restarts,
             "decode_steps": self.decode_steps,
             "new_tokens": self.total_new_tokens,
-            # paged-pool effectiveness: what fraction of admissions were
+            # pool effectiveness: what fraction of admissions were
             # served partly from shared prefix pages, and the densest the
-            # cache ever got (the capacity number the paged pool
-            # multiplies; divide by the engine's kv_cache_bytes for the
+            # cache ever got (the capacity number small pages
+            # multiply; divide by the engine's kv_cache_bytes for the
             # bench's resident_tokens_per_hbm_byte)
             "prefix_hits": self.prefix_hits,
             "prefix_hit_rate": round(self.prefix_hits / self.admitted, 4)
@@ -351,9 +351,9 @@ class ServeScheduler:
         self.spec_accepted = 0        # draft tokens the oracle accepted
         self.admitted = 0             # requests that reached a slot
         self.prefix_hits = 0          # admissions served partly from the
-        #                               paged prefix index
+        #                               prefix index
         self.peak_resident_tokens = 0
-        # head-of-queue page-allocation stall window (paged engines):
+        # head-of-queue page-allocation stall window:
         # opened when admission is blocked on pool pages, closed + charged
         # to serve_page_alloc_fail when pages free up (or at drain)
         self._alloc_stall_t0: Optional[float] = None
@@ -446,7 +446,7 @@ class ServeScheduler:
         (per shared pow2 bucket) and record each admitted request's first
         sampled token.
 
-        Paged engines are probed FIRST (``Engine.admission_page_cost``):
+        The engine is probed FIRST (``Engine.admission_page_cost``):
         a request whose page reservation does not fit stays at the head
         of the queue — FIFO order holds, the stall is charged to
         ``serve_page_alloc_fail`` once pages free up, and the batched
@@ -455,6 +455,11 @@ class ServeScheduler:
         free = [i for i, r in enumerate(self.slots) if r is None]
         if free and self.queue:
             with annotate("apex.sched.admit"):
+                # a slot freed since the last flush (an abort, a deadline)
+                # still holds its pages: give them back before the probe,
+                # so a free slot is never short of the pages it held
+                if self._to_evict:
+                    self._flush_evictions()
                 self._admit_into(free)
 
     def _admit_into(self, free: List[int]) -> None:
@@ -926,11 +931,13 @@ class ServeScheduler:
             if self.admission is not None:
                 if self.memory is not None:
                     self.admission.note_hbm(self.memory.last)
-                if self.engine.paged:
+                if self.engine.page_size < self.engine.max_len:
                     # pool occupancy is the serving-side memory-pressure
                     # signal (the allocator stats above are process-wide):
                     # a drained free list degrades admitted budgets just
-                    # like a deep queue does
+                    # like a deep queue does. Not where a page is a
+                    # slot's whole context: a smaller budget frees no
+                    # page there, and a drained pool is only busy slots
                     self.admission.note_pool(self.engine.free_page_frac)
                 flip = self.admission.on_tick(len(self.queue))
                 if flip is not None:
@@ -944,8 +951,8 @@ class ServeScheduler:
             active = np.array([r is not None for r in self.slots], bool)
             if not active.any():
                 # no decode step will run this tick, so the end-of-tick
-                # eviction flush below is unreachable — flush HERE or a
-                # paged engine livelocks: pages of slots freed by the
+                # eviction flush below is unreachable — flush HERE or
+                # the engine livelocks: pages of slots freed by the
                 # deadline sweep / an abort stay refcounted, the queue
                 # head's page probe keeps failing, and no decode step
                 # ever advances decode_steps toward max_steps
@@ -1048,10 +1055,10 @@ class ServeScheduler:
             "spec_proposed": self.spec_proposed,
             "spec_accepted": self.spec_accepted,
             "engine": self.engine.sampling_state(),
-            # page accounting (None for slot engines): page tables +
-            # refcounts, for the postmortem journal and the paged-recovery
-            # integrity story — recovery itself re-derives allocation by
-            # re-prefilling, sharing whatever prefix pages survived
+            # page accounting: page tables + refcounts, for the
+            # postmortem journal and the recovery integrity story —
+            # recovery itself re-derives allocation by re-prefilling,
+            # sharing whatever prefix pages survived
             "paging": self.engine.paging_state(),
             "slots": [None if r is None else {
                 "req": r, "request_id": r.request_id,
@@ -1087,7 +1094,7 @@ class ServeScheduler:
                     "(...)) — there is no snapshot to roll back to")
             snap = self.journal.snapshot
             self.restarts += 1
-            # state drop; compiled artifacts kept. Paged engines with a
+            # state drop; compiled artifacts kept. Engines with a
             # prefix index keep the pool bytes + index too: shared prefix
             # pages are read-only (a crash cannot have torn them), so
             # recovery re-prefills ONLY the unshared pages of each

@@ -9,9 +9,8 @@ a 1-D ``NamedSharding`` mesh (axis ``"tp"``):
   ``tp``-slice of the last axis is one rank's whole local q|k|v block);
   embeddings, layer norms, and biases added after a collective stay
   replicated;
-- **KV cache** — both layouts shard their ``heads`` axis (axis 3 of the
-  slot cache's ``[n_layer, num_slots, max_len, heads, head_dim]`` and of
-  the paged pool's ``[n_layer, num_pages, page_size, heads, head_dim]``;
+- **KV cache** — the pool shards its ``heads`` axis (axis 3 of
+  ``[n_layer, num_pages, page_size, heads, head_dim]``;
   allocated for the mesh, ``kv_cache.padded_heads``);
   ``lengths`` and the **page table stay replicated data** — page indices
   address every rank's shard simultaneously, so the host-side allocator,

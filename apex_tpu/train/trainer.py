@@ -399,7 +399,7 @@ class Trainer:
         zeros = lambda p: jnp.zeros_like(p, jnp.float32)  # noqa: E731
         self.m = jax.tree_util.tree_map(zeros, self.params)
         self.v = jax.tree_util.tree_map(zeros, self.params)
-        self.sstate: ScalerState = self.scaler.init()
+        self.sstate: ScalerState = self._on_mesh(self.scaler.init())
 
         self._next_step = 0           # the step not yet run
         self.hwm = int(hwm)           # job-scope exactly-once watermark
@@ -560,16 +560,11 @@ class Trainer:
         step, tree = out
         self.params, self.m, self.v = (tree["params"], tree["m"],
                                        tree["v"])
-        sc = tree["scaler"]
-        if self.mesh is not None:
-            # restored leaves come back committed to the restore
-            # target's devices; params/m/v restore onto the tp mesh (the
-            # _tree(0) template is mesh-placed) but the scaler scalars'
-            # template is the plain single-device init — re-place them
-            # replicated on the mesh or the jitted step would see two
-            # committed device sets and refuse
-            rep = NamedSharding(self.mesh, P())
-            sc = {k: jax.device_put(v, rep) for k, v in sc.items()}
+        # restored leaves come back committed to the restore target's
+        # devices; params/m/v restore onto the tp mesh (the _tree(0)
+        # template is mesh-placed), the scaler scalars are placed as a
+        # fresh trainer's are
+        sc = self._on_mesh(tree["scaler"])
         self.sstate = ScalerState(sc["scale"], sc["growth"], sc["hyst"])
         meta = tree["meta"]
         r = self._resilient
@@ -600,6 +595,18 @@ class Trainer:
                                                     saved_world)),
                     to_world=self.world)
         return step
+
+    def _on_mesh(self, tree):
+        """``tree`` (the scaler's scalars) replicated on the tp mesh; as
+        it is at tp=1. A fresh trainer's and a restored one's go through
+        here, so the jitted step sees ONE argument type for the life of
+        a job: an array's mesh is part of its type, and a scalar that is
+        on the mesh only after a restore is a second trace of the whole
+        model's gradient (and, committed to one device beside
+        mesh-committed params, a refusal)."""
+        if self.mesh is None:
+            return tree
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     # ---- the step -------------------------------------------------------
     def _step(self, t: int):

@@ -50,7 +50,10 @@ CODE_VERSIONS = {
     # per-shard head count changes the best block shapes) — v2 entries,
     # keyed without it, must invalidate rather than apply to a mesh
     # shape they were never timed on
-    "decode_attention": 3,
+    # v4: the slot-contiguous layout (page_size key 0) went; one page a
+    # slot is keyed page_size == max_len — v3's slot-layout winners were
+    # timed on another fetch and must not apply
+    "decode_attention": 4,
     "fused_adam": 1,
     "fused_sgd": 1,
     "fused_lamb": 1,

@@ -337,27 +337,26 @@ def _fa_build(shape, dtype, params, interpret=None):
 def _da_shape_key(shape) -> ShapeKey:
     # max_len keyed exactly (layout-defining static engine constant; the
     # winner must divide it) — matches serve.attention.resolve_block_k.
-    # page_size is a second exact geometry axis (0 = slot cache): a paged
-    # chunk must live inside one page, so a winner tuned at one page size
-    # cannot apply to another (or to the slot layout) — CODE_VERSIONS
-    # bumped to 2 when this axis landed so v1 entries invalidate.
+    # page_size is a second exact geometry axis (absent: one page a
+    # slot, max_len): a chunk must live inside one page, so a winner
+    # tuned at one page size cannot apply to another — CODE_VERSIONS
+    # bumped to 2 when this axis landed so v1 entries invalidate, and to
+    # 4 when its value 0 (a slot-contiguous layout) went.
     # tp_shards (1 = single chip) is a third: a tensor-parallel engine
     # runs this kernel per mesh rank with `heads` = its PER-SHARD head
     # count, and a winner timed unsharded must not apply to a sharded
     # instance (or vice versa) — CODE_VERSIONS bumped to 3 with it so v2
     # entries invalidate cleanly.
     return (("max_len", int(shape["max_len"])),
-            ("page_size", int(shape.get("page_size", 0))),
+            ("page_size", _da_unit(shape)),
             ("heads", int(shape["heads"])),
             ("d", int(shape["d"])),
             ("tp_shards", int(shape.get("tp_shards", 1))))
 
 
 def _da_unit(shape) -> int:
-    """The span a chunk must divide: the page (paged) or the whole key
-    axis (slot cache)."""
-    ps = int(shape.get("page_size", 0))
-    return ps if ps else int(shape["max_len"])
+    """The span a chunk must divide: the page."""
+    return int(shape.get("page_size") or shape["max_len"])
 
 
 def _da_defaults(shape):
@@ -383,37 +382,25 @@ def _da_build(shape, dtype, params, interpret=None):
     b = int(shape.get("b", 8))
     L, h, d = (int(shape["max_len"]), int(shape["heads"]),
                int(shape["d"]))
-    ps = int(shape.get("page_size", 0))
+    ps = _da_unit(shape)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, h, d), dtype) * 0.2
     positions = jnp.full((b,), L - 1, jnp.int32)  # worst case: full cache
     bk = params["block_k"]
 
-    if ps:
-        # paged layout: time the page-table gather path at full residency
-        # (every slot's table maps distinct live pages, like a busy pool)
-        from apex_tpu.serve.attention import paged_attention
+    # time the page-table gather path at full residency (every slot's
+    # table maps distinct live pages, like a busy pool)
+    from apex_tpu.serve.attention import paged_attention
 
-        mp = L // ps
-        P = b * mp + 1                         # +1: the reserved null page
-        kc = jax.random.normal(ks[1], (P, ps, h, d), dtype) * 0.2
-        vc = jax.random.normal(ks[2], (P, ps, h, d), dtype) * 0.2
-        table = jnp.arange(1, P, dtype=jnp.int32).reshape(b, mp)
-
-        def step(i, q, kc, vc):
-            return paged_attention(q, kc, vc, table, positions,
-                                   block_k=bk, interpret=interpret)
-
-        return step, q, (kc, vc)
-
-    from apex_tpu.serve.attention import cached_attention
-
-    kc = jax.random.normal(ks[1], (b, L, h, d), dtype) * 0.2
-    vc = jax.random.normal(ks[2], (b, L, h, d), dtype) * 0.2
+    mp = L // ps
+    P = b * mp + 1                             # +1: the reserved null page
+    kc = jax.random.normal(ks[1], (P, ps, h, d), dtype) * 0.2
+    vc = jax.random.normal(ks[2], (P, ps, h, d), dtype) * 0.2
+    table = jnp.arange(1, P, dtype=jnp.int32).reshape(b, mp)
 
     def step(i, q, kc, vc):
-        return cached_attention(q, kc, vc, positions, block_k=bk,
-                                interpret=interpret)
+        return paged_attention(q, kc, vc, table, positions,
+                               block_k=bk, interpret=interpret)
 
     return step, q, (kc, vc)
 
@@ -581,11 +568,8 @@ _register(KernelSpec(
 _register(KernelSpec(
     "decode_attention", _da_shape_key, _da_defaults, _da_candidates,
     _da_build,
-    # both layouts warm by default: the slot cache and the paged pool at
-    # the serving default page size (page_size=0 means slot layout)
-    default_shapes=({"b": 8, "max_len": 2048, "heads": 16, "d": 64},
-                    {"b": 8, "max_len": 2048, "page_size": 256,
-                     "heads": 16, "d": 64})))
+    default_shapes=({"b": 8, "max_len": 2048, "page_size": 256,
+                     "heads": 16, "d": 64},)))
 _register(KernelSpec(
     "fused_adam", _flat_shape_key, _flat_defaults, _flat_candidates,
     _adam_build, default_shapes=({"numel": 134_217_728},),

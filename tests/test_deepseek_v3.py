@@ -361,8 +361,6 @@ def test_yarn_frequencies_against_the_closed_form():
     (dict(tp=2), "tp=2: there is no per-rank forward"),
     (dict(spec_draft_len=2), "spec_draft_len=2: the verify scan"),
     (dict(kv_quant="int8"), "kv_quant='int8': the block-scale codec"),
-    (dict(page_size=None, num_pages=None, prefix_cache=False),
-     "page_size=None: the latent cache exists only as a paged pool"),
     (dict(block_k=8), "block_k=8"),
 ])
 def test_engine_modes_this_model_lacks_are_refused_at_build(knobs, names):
@@ -370,6 +368,34 @@ def test_engine_modes_this_model_lacks_are_refused_at_build(knobs, names):
     params = reference.make_params(cfg, 1)
     with pytest.raises(ValueError, match=names):
         engine_of(cfg, params, **knobs)
+
+
+def test_the_latent_cache_serves_with_one_page_a_slot():
+    """No ``page_size``: a page is a slot's whole ``max_len`` (5 pages
+    for 4 slots). The decode step gathers a slot's whole key axis
+    whatever the page, and a prefill with no hit never reads the cache,
+    so the logits are those of the 16-token pages, to the bit."""
+    cfg = tiny()
+    params = reference.make_params(cfg, 7)
+    rng = np.random.default_rng(1)
+    prompts = {s: rng.integers(0, 512, n).tolist()
+               for s, n in enumerate((11, 16, 9))}
+    active = np.array([True, True, True, False])
+    got = {}
+    for name, knobs in (("pages", {}), ("one", dict(
+            page_size=None, num_pages=None, prefix_cache=False))):
+        engine = engine_of(cfg, params, **knobs)
+        first, last_logits, _ = engine.prefill(prompts)
+        rows = [np.asarray(last_logits)]
+        for _ in range(20):
+            _, logits = engine.decode_step(engine.last_tokens, active)
+            rows.append(np.asarray(logits))
+        got[name] = (first.tolist(), np.stack(rows)[:, :3])
+        assert engine.decode_traces == 1
+    assert engine.page_size == 128 and engine.cache.rows.shape[1:3] == (5, 128)
+    assert engine.paging_state()["free_pages"] == 1
+    assert got["one"][0] == got["pages"][0]
+    np.testing.assert_array_equal(got["one"][1], got["pages"][1])
 
 
 def test_latent_pages_do_not_migrate_and_the_ledger_names_the_model():

@@ -52,7 +52,7 @@ from apex_tpu.resilience.fault_injection import FaultInjector
 from apex_tpu.serve.disagg import DisaggController
 from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
 from apex_tpu.serve.fleet import EngineReplica
-from apex_tpu.serve.kv_cache import init_cache, write_token
+from apex_tpu.serve.kv_cache import init_paged_cache, paged_write_token
 from apex_tpu.serve.scheduler import Request, ServeScheduler
 # bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session (see test_serve for the history)
@@ -259,30 +259,37 @@ def test_mx_layer_norm_matches_dequant_reference():
 def test_quant_cache_write_is_masked_and_bounded():
     """kv_cache surgery unit: a quantized write stores codec bytes +
     scales under the SAME mask discipline — masked-off slots' payload
-    AND scale bytes stay bit-untouched."""
-    cache = init_cache(n_layer=1, num_slots=4, max_len=8, heads=2,
-                       head_dim=16, kv_quant="int8")
+    AND scale bytes stay bit-untouched. One page a slot: slot ``b``
+    owns page ``b + 1`` under the null page."""
+    cache = init_paged_cache(n_layer=1, num_slots=4, max_len=8,
+                             page_size=8, num_pages=5, heads=2,
+                             head_dim=16, kv_quant="int8")
+    cache = cache.replace(
+        page_table=jnp.arange(1, 5, dtype=jnp.int32)[:, None],
+        # a masked-off slot's page holds codes and scales of its own
+        k=cache.k.at[0, 2].set(3), k_scale=cache.k_scale.at[0, 2].set(0.5))
     assert cache.k.dtype == jnp.int8
     # 2 heads in a head axis allocated as a whole group of 8
-    assert cache.k_scale.shape == (1, 4, 8, 8)
+    assert cache.k_scale.shape == (1, 5, 8, 8)
     x = np.random.RandomState(0).randn(4, 2, 16).astype(np.float32)
     pos = jnp.zeros((4,), jnp.int32)
     mask = jnp.array([True, False, True, False])
-    out = jax.jit(write_token,
+    out = jax.jit(paged_write_token,
                   static_argnums=(1, 6))(cache, 0, jnp.asarray(x),
                                          jnp.asarray(x), pos, mask,
                                          "int8")
-    got = np.asarray(out.k[0, 0, 0, :2]).astype(np.float32) \
-        * np.asarray(out.k_scale[0, 0, 0, :2])[..., None]
-    bound = int8_error_bound(np.asarray(out.k_scale[0, 0, 0, :2])[..., None],
+    got = np.asarray(out.k[0, 1, 0, :2]).astype(np.float32) \
+        * np.asarray(out.k_scale[0, 1, 0, :2])[..., None]
+    bound = int8_error_bound(np.asarray(out.k_scale[0, 1, 0, :2])[..., None],
                              16, x[0].shape)
-    assert not np.asarray(out.k[..., 2:, :]).any()
-    assert not np.asarray(out.k_scale[..., 2:]).any()
+    assert not np.asarray(out.v[..., 2:, :]).any()
+    assert not np.asarray(out.v_scale[..., 2:]).any()
     assert (np.abs(got - x[0]) <= bound).all()
-    np.testing.assert_array_equal(np.asarray(out.k[0, 1]),
-                                  np.asarray(cache.k[0, 1]))
-    np.testing.assert_array_equal(np.asarray(out.k_scale[0, 1]),
-                                  np.asarray(cache.k_scale[0, 1]))
+    for page in (0, 2, 4):        # the null page and the masked-off slots'
+        np.testing.assert_array_equal(np.asarray(out.k[0, page]),
+                                      np.asarray(cache.k[0, page]))
+        np.testing.assert_array_equal(np.asarray(out.k_scale[0, page]),
+                                      np.asarray(cache.k_scale[0, page]))
 
 
 def _mixed_requests(n=5, seed0=0, max_new=5):

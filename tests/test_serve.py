@@ -42,7 +42,7 @@ from apex_tpu.models.gpt2 import GPT2Config
 from apex_tpu.monitor.goodput import GoodputLedger
 from apex_tpu.resilience.fault_injection import FaultInjector
 from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
-from apex_tpu.serve.kv_cache import init_cache, write_token
+from apex_tpu.serve.kv_cache import init_paged_cache, paged_write_token
 from apex_tpu.serve.scheduler import Request, ServeScheduler
 # bound at collection time: a test that purges apex_tpu.* from
 # sys.modules mid-session, and a function-local re-import after that
@@ -107,22 +107,35 @@ def _tokens(n, seed=7, vocab=97):
 
 # ------------------------------------------------------------ kv cache
 
+def _one_page_a_slot(cache):
+    """``cache`` with the identity page table of the default geometry:
+    slot ``b`` owns page ``b + 1`` (page 0 is the null page)."""
+    return cache.replace(page_table=jnp.arange(
+        1, cache.num_slots + 1, dtype=jnp.int32)[:, None])
+
+
 def test_kv_cache_ops_are_static_and_masked():
-    cache = init_cache(n_layer=2, num_slots=4, max_len=16, heads=2,
-                       head_dim=8)
+    cache = _one_page_a_slot(init_paged_cache(
+        n_layer=2, num_slots=4, max_len=16, page_size=16, num_pages=5,
+        heads=2, head_dim=8))
+    # a masked-off slot's page holds bytes a write must not touch
+    cache = cache.replace(k=cache.k.at[0, 2].set(7.0))
     k = jnp.ones((4, 2, 8)) * jnp.arange(1, 5)[:, None, None]
     pos = jnp.zeros((4,), jnp.int32)
     mask = jnp.array([True, False, True, False])
-    out = jax.jit(write_token, static_argnums=1)(cache, 0, k, k, pos, mask)
+    out = jax.jit(paged_write_token, static_argnums=1)(
+        cache, 0, k, k, pos, mask)
     assert out.k.shape == cache.k.shape  # static shapes, whatever the mask
     # the head axis is allocated in whole groups of 8; the rest stays zero
-    assert cache.k.shape == (2, 4, 16, 8, 8)
-    assert not np.asarray(out.k[:, :, :, 2:]).any()
-    got = np.asarray(out.k[0, :, 0, 0, 0])
-    np.testing.assert_array_equal(got, [1.0, 0.0, 3.0, 0.0])
-    # masked-off slots' bytes are bit-untouched
-    np.testing.assert_array_equal(np.asarray(out.k[0, 1]),
-                                  np.asarray(cache.k[0, 1]))
+    assert cache.k.shape == (2, 5, 16, 8, 8)
+    assert not np.asarray(out.v[:, :, :, 2:]).any()
+    got = np.asarray(out.k[0, 1:, 0, 0, 0])          # slot b is page b + 1
+    np.testing.assert_array_equal(got, [1.0, 7.0, 3.0, 0.0])
+    # masked-off slots' bytes are bit-untouched, and so is the null page
+    # their writes are routed to
+    for page in (0, 2, 4):
+        np.testing.assert_array_equal(np.asarray(out.k[:, page]),
+                                      np.asarray(cache.k[:, page]))
 
 
 # -------------------------------------------------------------- parity
@@ -158,12 +171,10 @@ def test_prefill_last_logits_match_kept_logits(keeper3):
 
 def _resident(eng, slot, field="k"):
     """``[n_layer, lengths[slot], ...]``: the rows of ``cache.<field>`` a
-    slot's attention can reach, read through the page table if paged."""
+    slot's attention can reach, read through the page table."""
     n = int(eng.lengths[slot])
     buf = np.asarray(getattr(eng.cache, field))
-    if eng.config.page_size is None:
-        return buf[:, slot, :n]
-    ps, table = int(eng.config.page_size), eng._page_table[slot]
+    ps, table = eng.page_size, eng._page_table[slot]
     return np.stack([buf[:, table[p // ps], p % ps] for p in range(n)], 1)
 
 
@@ -171,37 +182,35 @@ def _resident(eng, slot, field="k"):
 def test_chunk_attention_matches_a_plain_softmax(layout):
     """``chunk_attention`` alone against the definition in float64: row
     ``t`` of slot ``b`` attends over the cached positions ``< start[b]``
-    and the chunk's own keys ``0..t``, one softmax over both. Either
-    layout; a slot with no cached head beside slots with one (the head's
-    loop runs to the longest, 19 rows = 3 chunks of 8, and masks the
-    rest); the paged head read through a shuffled page table."""
+    and the chunk's own keys ``0..t``, one softmax over both. One page a
+    slot (``slot``: four chunks of 8 inside the one page) and four
+    (``paged``); a slot with no cached head beside slots with one (the
+    head's loop runs to the longest, 19 rows = 3 chunks of 8, and masks
+    the rest); the head read through a shuffled page table."""
     from apex_tpu.serve.attention import chunk_attention
-    from apex_tpu.serve.kv_cache import init_paged_cache, pad_heads
+    from apex_tpu.serve.kv_cache import pad_heads
 
-    b, t, h, d, max_len, ps = 3, 4, 2, 8, 32, 8
+    b, t, h, d, max_len = 3, 4, 2, 8, 32
+    ps = max_len if layout == "slot" else 8
+    per_slot = max_len // ps
+    pages = b * per_slot + 1
     rng = np.random.RandomState(0)
     head_k, head_v = rng.randn(2, b, max_len, h, d).astype(np.float32)
     q, k, v = rng.randn(3, b, t, h, d).astype(np.float32)
     start = np.array([0, 19, 8], np.int32)
-    if layout == "slot":
-        slots = init_cache(2, b, max_len, h, d)
-        cache = slots.replace(
-            k=slots.k.at[1].set(pad_heads(head_k, slots.k.shape[-2])),
-            v=slots.v.at[1].set(pad_heads(head_v, slots.v.shape[-2])))
-    else:
-        table = rng.permutation(np.arange(1, 13)).reshape(b, 4)
-        pool = init_paged_cache(2, b, max_len, ps, 13, h, d)
+    table = rng.permutation(np.arange(1, pages)).reshape(b, per_slot)
+    pool = init_paged_cache(2, b, max_len, ps, pages, h, d)
 
-        def paged(rows):              # [b, max_len, ...] -> [13, ps, ...]
-            rows = np.asarray(pad_heads(rows, pool.k.shape[-2]))
-            out = np.zeros((13, ps) + rows.shape[2:], np.float32)
-            out[table.reshape(-1)] = rows.reshape((-1, ps) + rows.shape[2:])
-            return out
+    def paged(rows):              # [b, max_len, ...] -> [pages, ps, ...]
+        rows = np.asarray(pad_heads(rows, pool.k.shape[-2]))
+        out = np.zeros((pages, ps) + rows.shape[2:], np.float32)
+        out[table.reshape(-1)] = rows.reshape((-1, ps) + rows.shape[2:])
+        return out
 
-        cache = pool.replace(
-            k=pool.k.at[1].set(paged(head_k)),
-            v=pool.v.at[1].set(paged(head_v)),
-            page_table=jnp.asarray(table, jnp.int32))
+    cache = pool.replace(
+        k=pool.k.at[1].set(paged(head_k)),
+        v=pool.v.at[1].set(paged(head_v)),
+        page_table=jnp.asarray(table, jnp.int32))
     # the cache's head axis is padded: so are the chunk's queries, keys
     # and values, and the padding's outputs are dropped (gpt2.py does so)
     full = cache.k.shape[-2]
@@ -895,20 +904,22 @@ def test_decode_attention_registered_with_tune():
 
 @pytest.fixture(scope="module")
 def paged3(params):
-    """Shared 3-slot paged greedy engine (page_size 8, slot-equivalent
-    pool, prefix index on); tests reset() it — compiled once. The prefix
-    index only fires on page-aligned shared prompts, so parity tests
-    with distinct prompts ride the plain paged path."""
+    """Shared 3-slot greedy engine with 4 pages a slot (page_size 8, a
+    pool of every slot's whole context, prefix index on); tests reset()
+    it — compiled once. The prefix index only fires on page-aligned
+    shared prompts, so parity tests with distinct prompts share no
+    page."""
     return _engine(params, page_size=8, prefix_cache=True)
 
 
 @pytest.fixture(scope="module")
 def slot8(params):
-    """Slot-cache greedy oracle pinned to block_k=8 — the paged
-    engine's chunk geometry. Bit-exactness across layouts holds at
-    EQUAL block_k (only the K/V fetch differs then); at different
-    block_k the softmax partial-sum order differs by design, exactly
-    like two block_k values on the same layout."""
+    """The default geometry (no ``page_size``: ONE ``max_len`` page a
+    slot, several chunks inside the page) pinned
+    to block_k=8 — ``paged3``'s chunk geometry. Bit-exactness across
+    page sizes holds at EQUAL block_k (only the K/V fetch differs
+    then); at different block_k the softmax partial-sum order differs
+    by design."""
     return _engine(params, block_k=8)
 
 
@@ -928,14 +939,18 @@ def _trace_outputs(eng, reqs, injector=None):
 
 
 def test_paged_bit_exact_vs_slot_greedy(slot8, paged3):
-    """THE paged acceptance: an identical mixed-length request trace
-    through the slot engine (the oracle) and the paged engine produces
-    bit-identical greedy streams — the chunked-softmax arithmetic is
-    shared verbatim, only the K/V fetch differs. Both engines run the
-    same block_k (paged3's page-sized default): equal chunk geometry is
-    the bit-exactness precondition, and the autotuner keys it per
-    layout so a deployment pins it the same way."""
+    """Several pages equal one page a slot: an identical mixed-length
+    request trace through an engine built with no ``page_size`` and
+    through one with 8-token pages (4 a slot) produces bit-identical greedy
+    streams — where the pages lie never enters the chunked-softmax
+    arithmetic, only the K/V fetch differs. Both engines run the same
+    block_k (paged3's page-sized default): equal chunk geometry is the
+    bit-exactness precondition, and the autotuner keys it per page size
+    so a deployment pins it the same way."""
     assert paged3.block_k == slot8.block_k == 8
+    assert slot8.config.page_size is None
+    assert slot8.page_size == slot8.max_len
+    assert slot8.cache.page_table.shape == (3, 1)
     base = _trace_outputs(slot8.reset(), _mixed_requests())
     got = _trace_outputs(paged3.reset(), _mixed_requests())
     assert {k: v["generated"] for k, v in got.items()} == \
@@ -945,10 +960,10 @@ def test_paged_bit_exact_vs_slot_greedy(slot8, paged3):
 
 
 def test_paged_decode_logits_match_slot_prefill(params, paged3, slot8):
-    """Strongest oracle form: a PAGED engine's incremental decode logits
-    match the SLOT engine's full-sequence prefill logits — crossing both
-    the layout (bit-exact on its own, asserted below on the prefill
-    side) and the prefill/decode border (float32 rounding, ``BORDER``),
+    """Strongest form: the small-page engine's incremental decode logits
+    match the one-page-a-slot engine's full-sequence prefill logits —
+    crossing both the page size (bit-exact on its own, asserted below on
+    the prefill side) and the prefill/decode border (float32 rounding, ``BORDER``),
     at the shared block_k=8 chunk geometry."""
     seq = _tokens(12)
     keeper = _engine(params, keep_prefill_logits=True, block_k=8)
@@ -956,7 +971,7 @@ def test_paged_decode_logits_match_slot_prefill(params, paged3, slot8):
     all_logits = np.asarray(all_logits)          # [P, B, V]
     inc = paged3.reset()
     _, paged_last, _ = inc.prefill({1: seq[:5]})
-    # the layout alone, same program shape: the chunk's attention never
+    # the page size alone, same program shape: the chunk's attention never
     # sees where its rows are stored, so this stays bit-exact (slot8 and
     # not the keeper, whose logits product runs over every row)
     _, slot_last, _ = slot8.reset().prefill({1: seq[:5]})
@@ -1119,7 +1134,7 @@ def test_overcommitted_pool_stalls_then_completes(slot8, params):
     stalls the queue head until completions free pages — the stall is
     charged to serve_page_alloc_fail (a timed cause distinct from
     queue_wait), every request completes, and outputs still match the
-    slot oracle bit-for-bit."""
+    one-page-a-slot engine's (which never stalls) bit-for-bit."""
     base = _trace_outputs(slot8.reset(), _mixed_requests())
     # 5 allocatable pages against ~2-page reservations: two requests fit,
     # the third stalls on pages while a SLOT sits free — KV-bound, not
@@ -1250,7 +1265,7 @@ def test_combine_chunks_fetches_each_chunk_once():
     chunk's (K, V) exactly once — a second ``fetch(i)`` per chunk traced
     four page-table gathers where two suffice (and actually executed
     them under interpret=True)."""
-    from apex_tpu.serve.attention import _combine_chunks, cached_attention
+    from apex_tpu.serve.attention import _combine_chunks, paged_attention
 
     rng = np.random.RandomState(0)
     k = rng.randn(2, 16, 2, 4).astype(np.float32)
@@ -1266,15 +1281,24 @@ def test_combine_chunks_fetches_each_chunk_once():
 
     out = _combine_chunks(q, pos, 16, 4, jnp.float32(0.5), fetch)
     assert sorted(calls) == [0, 1, 2, 3], calls    # once per chunk
-    # and the single-fetch path is the SAME numbers the public slot
-    # entry point produces at the same block_k
-    ref = cached_attention(q, jnp.asarray(k), jnp.asarray(v), pos,
-                           scale=0.5, block_k=4)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    # and the single-fetch path is the SAME numbers the public entry
+    # point produces at the same block_k, whether a slot's 16 rows lie
+    # in one page (pages 1, 2 under a null page) or in four of 4 rows
+    def pool(x, ps):
+        return jnp.concatenate(
+            [jnp.zeros((1, ps) + x.shape[2:], x.dtype),
+             jnp.asarray(x).reshape((-1, ps) + x.shape[2:])])
+
+    for ps in (16, 4):
+        table = jnp.arange(1, 2 * 16 // ps + 1,
+                           dtype=jnp.int32).reshape(2, -1)
+        ref = paged_attention(q, pool(k, ps), pool(v, ps), table, pos,
+                              scale=0.5, block_k=4)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_plan_admission_empty_prompt():
-    """Review regression: an empty prompt (legal on the slot path — only
+    """Review regression: an empty prompt (legal for the planner — only
     ``ServeScheduler.submit`` rejects it) must plan zero shared tokens
     instead of ``use=-1`` whose tail-page remainder indexed ``hits[-1]``
     on an empty hit list."""
@@ -1290,30 +1314,32 @@ def test_plan_admission_empty_prompt():
 
 def test_decode_attention_page_geometry_registered():
     """Satellite: page_size is a shape-key axis of the decode_attention
-    autotuner (slot=0 and paged winners never collide), candidates must
-    divide the page, and CODE_VERSIONS invalidates v1 slot-only
-    entries."""
+    autotuner (one page a slot is keyed at ``max_len``, never 0, and
+    winners of two page sizes never collide), candidates must divide the
+    page, and CODE_VERSIONS invalidates the entries keyed on the
+    slot-contiguous layout that is gone."""
     from apex_tpu.tune import CODE_VERSIONS
     from apex_tpu.tune import registry
 
-    assert CODE_VERSIONS["decode_attention"] >= 2
+    assert CODE_VERSIONS["decode_attention"] >= 4
     spec = registry.spec("decode_attention")
     k_slot = spec.shape_key({"max_len": 64, "heads": 2, "d": 8})
     k_paged = spec.shape_key({"max_len": 64, "page_size": 16,
                               "heads": 2, "d": 8})
     assert k_slot != k_paged
-    assert ("page_size", 0) in k_slot and ("page_size", 16) in k_paged
+    assert ("page_size", 64) in k_slot and ("page_size", 16) in k_paged
     paged_shape = {"b": 2, "max_len": 64, "page_size": 16,
                    "heads": 2, "d": 8}
     cands = spec.candidates(paged_shape)
     assert cands and all(16 % c["block_k"] == 0 for c in cands)
     assert spec.defaults(paged_shape) in cands
-    # the registry's default shapes warm BOTH layouts
-    assert any(s.get("page_size") for s in spec.default_shapes)
-    # the paged build runs the real page-table gather path
-    p = spec.defaults(paged_shape)
-    step, q, consts = spec.build(paged_shape, jnp.float32, p)
-    assert step(0, q, *consts).shape == q.shape
+    assert all(s.get("page_size") for s in spec.default_shapes)
+    # the build runs the real page-table gather path, at one page a
+    # slot too
+    for shape in (paged_shape, {"b": 2, "max_len": 64, "heads": 2, "d": 8}):
+        p = spec.defaults(shape)
+        step, q, consts = spec.build(shape, jnp.float32, p)
+        assert step(0, q, *consts).shape == q.shape
 
 
 # ------------------------------------------------------------ CLIs
@@ -1598,7 +1624,8 @@ def test_paged_bench_capacity_and_gate(tmp_path, capsys):
     assert paged["workload"]["page_size"] == 8
     assert paged["workload"]["prefix_cache"] is True
     assert paged["workload"]["shared_prefix"] == 16
-    assert slot["workload"]["page_size"] == 0
+    assert slot["workload"]["page_size"] == 128      # one page a slot
+    assert slot["workload"]["num_pages"] == 5
 
     path_cur = tmp_path / "cur.json"
     path_base = tmp_path / "base.json"

@@ -377,10 +377,10 @@ def test_a_call_that_raises_after_donation_leaves_a_fresh_pool(kind, call):
     for leaf in jax.tree_util.tree_leaves(eng.cache):
         assert not leaf.is_deleted()
     assert eng.resident_tokens == 0 and int(eng.lengths.max()) == 0
-    if eng.paged:
-        assert eng.pool.free_count == eng.pool.capacity
+    assert eng.pool.free_count == eng.pool.capacity
+    assert not eng._page_table.any()
+    if eng.prefix is not None:
         assert len(eng.prefix) == 0
-        assert not eng._page_table.any()
     assert eng.decode_calls >= calls_before
     # mend the program and the engine serves as a fresh one does
     if call == "prefill":

@@ -661,14 +661,20 @@ def test_warm_restart_paged_determinism_and_journal(paged2):
     assert all(len(row) == 4 for row in pg["page_table"])
 
 
-def test_slot_journal_document_unchanged(greedy2):
-    """Pre-paging journal consumers see an unchanged document: a slot
-    engine's payload carries no 'paging' key at all."""
+def test_default_engine_journals_its_one_page_a_slot(greedy2):
+    """An engine built with no ``page_size`` has the one pool every
+    engine has, a ``max_len`` page a slot, and its journal says so: the
+    'paging' section carries that geometry, every page back in the free
+    list once both requests completed."""
     sched = ServeScheduler(greedy2.reset(), journal=TickJournal())
     for r in _requests(2, max_new=2):
         sched.submit(r)
     sched.run()
-    assert "paging" not in sched.journal.to_payload()
+    pg = sched.journal.to_payload()["paging"]
+    assert pg["page_size"] == greedy2.max_len == greedy2.page_size
+    assert pg["num_pages"] == 3 and pg["free_pages"] == 2   # + null page
+    assert pg["page_table"] == [[0], [0]] and pg["prefix_entries"] == 0
+    assert greedy2.free_page_frac == 1.0
 
 
 def test_paged_recovery_reprefills_only_unshared_pages(paged2):

@@ -77,13 +77,13 @@ def _engine(params, **kw):
 
 @pytest.fixture(scope="module")
 def base8(params, tp_devices):
-    """Single-chip slot oracle at block_k=8."""
+    """Single-chip engine, one page a slot, at block_k=8."""
     return _engine(params)
 
 
 @pytest.fixture(scope="module")
 def tp2(params, tp_devices):
-    """tp=2 slot engine, exact sync (THE sharded default)."""
+    """tp=2 engine, one page a slot, exact sync (THE sharded default)."""
     return _engine(params, tp=2)
 
 
@@ -183,8 +183,8 @@ def test_tp_decode_logits_match_single_prefill(params, tp2, tp_devices):
 def test_tp_paged_bit_exact_vs_single_chip(paged1, tp2_paged):
     """The paged pool under the mesh: head-sharded page bytes behind a
     REPLICATED page table, prefix-hit + COW churn included — greedy
-    streams bit-identical to the single-chip paged engine (itself held
-    bit-exact to the slot engine by test_serve)."""
+    streams bit-identical to the single-chip engine of the same pages (itself
+    held bit-exact to one page a slot by test_serve)."""
     sysp = _tokens(16, seed=42)                  # two full shared pages
     reqs = lambda: [Request(request_id=f"p{i}",          # noqa: E731
                             tokens=sysp + _tokens(3 + i, seed=100 + i),
@@ -352,9 +352,9 @@ def test_decode_attention_tp_shards_axis_registered():
 
     assert CODE_VERSIONS["decode_attention"] >= 3
     spec = registry.spec("decode_attention")
-    k1 = spec.shape_key({"max_len": 32, "page_size": 0, "heads": 2,
+    k1 = spec.shape_key({"max_len": 32, "page_size": 32, "heads": 2,
                          "d": 8})
-    k2 = spec.shape_key({"max_len": 32, "page_size": 0, "heads": 2,
+    k2 = spec.shape_key({"max_len": 32, "page_size": 32, "heads": 2,
                          "d": 8, "tp_shards": 2})
     assert k1 != k2
     assert ("tp_shards", 1) in k1 and ("tp_shards", 2) in k2
