@@ -157,13 +157,31 @@ def _affine_layer_norm(x, scale, bias, eps: float = 1e-5):
     return manual_layer_norm(x, scale, bias, (x.shape[-1],), eps)
 
 
+def _chunk_geometry(cache, heads, head_dim, dtype, block_k, pos, write_mask,
+                    tp=1):
+    """Once a forward: the resolved key chunk ``block_k`` and, for the
+    one-token form, the trip count of every layer's decode attention
+    (:func:`~apex_tpu.serve.attention.attended_chunks`: the chunks the
+    longest slot the step writes can reach; a chunk a slot has none, its
+    cached head sets its own)."""
+    from apex_tpu.serve.attention import attended_chunks, resolve_block_k
+
+    bk = resolve_block_k(cache.max_len, heads, head_dim, dtype, block_k,
+                         page_size=cache.page_size, tp_shards=tp)
+    if pos.ndim == 2:
+        return bk, None
+    return bk, attended_chunks(pos, write_mask, bk, cache.max_len // bk)
+
+
 def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
-                       kv_quant):
+                       trips, kv_quant):
     """One layer's cache append and attention, for either form of the
     forward. ``q``/``k``/``v``: ``[rows, heads, head_dim]`` with
     ``rows = pos.size``; ``pos``/``write_mask``:
     ``[num_slots]`` (one token a slot: appended at ``pos``, attending
-    over cached ``0..pos``) or ``[num_slots, T]`` (a chunk a slot).
+    over cached ``0..pos`` in ``trips`` chunks of ``block_k``) or
+    ``[num_slots, T]`` (a chunk a slot). Both read the STACKED pool at
+    ``layer`` inside their loop: no layer's pool is sliced out here.
     Returns ``(o [rows, heads, head_dim], cache)``. The cache's head
     axis is allocated in whole tiles (``kv_cache.padded_heads``): the
     queries are padded with zero heads to meet it, attention runs over
@@ -185,11 +203,7 @@ def _append_and_attend(cache, layer, q, k, v, pos, write_mask, block_k,
         return o.reshape(q.shape)[:, :heads], cache
     cache = paged_write_token(cache, layer, k, v, pos, write_mask,
                               codec=kv_quant)
-    o = paged_attention(
-        q, cache.k[layer], cache.v[layer], cache.page_table, pos,
-        block_k=block_k,
-        k_scale=None if kv_quant is None else cache.k_scale[layer],
-        v_scale=None if kv_quant is None else cache.v_scale[layer])
+    o = paged_attention(q, cache, layer, pos, trips, block_k=block_k)
     return o[:, :heads], cache
 
 
@@ -257,6 +271,8 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
     h, d = c.n_head, c.n_embd // c.n_head
     p = params["params"] if "params" in params else params
     pos = positions.astype(jnp.int32)
+    block_k, trips = _chunk_geometry(cache, h, d, dt, block_k, pos,
+                                     write_mask)
 
     x = (p["wte"][tokens].astype(dt)
          + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt)
@@ -281,7 +297,8 @@ def gpt2_token_forward(cfg: GPT2Config, params, cache, tokens, positions,
             v = v.reshape(-1, h, d)
         with jax.named_scope("attention"):
             o, cache = _append_and_attend(cache, i, q, k, v, pos,
-                                          write_mask, block_k, kv_quant)
+                                          write_mask, block_k, trips,
+                                          kv_quant)
             with jax.named_scope("attn_proj"):
                 o = o.reshape(-1, c.n_embd)
                 x = x + (o.astype(dt)
@@ -366,6 +383,10 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
     d = c.n_embd // c.n_head
     p = params
     pos = positions.astype(jnp.int32)
+    # positions and the mask are replicated, so every rank runs the same
+    # trips
+    block_k, trips = _chunk_geometry(cache, h_loc, d, dt, block_k, pos,
+                                     write_mask, tp)
 
     x = (p["wte"][tokens].astype(dt)
          + p["wpe"][jnp.clip(pos, 0, c.n_positions - 1)].astype(dt)
@@ -393,7 +414,8 @@ def gpt2_token_forward_tp(cfg: GPT2Config, tp: int, sync: str, params,
             # quantized pool is bit-identical to the single-chip
             # engine's same head slice
             o, cache = _append_and_attend(cache, i, q, k, v, pos,
-                                          write_mask, block_k, kv_quant)
+                                          write_mask, block_k, trips,
+                                          kv_quant)
             out_b = blk["attn_out"]["bias"].astype(dt)
             if sync == "exact":
                 # concatenate the heads across ranks, then the FULL

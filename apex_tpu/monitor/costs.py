@@ -117,6 +117,11 @@ _CONTRACT_RE = re.compile(r'contracting_dims\s*=\s*\[([^\]]*)\]'
 _SCALAR_CONST_RE = re.compile(
     r'%(\S+)\s*=\s*stablehlo\.constant\s+dense<(\d+)>\s*:\s*tensor<[su]?i')
 _FUNC_RE = re.compile(r'^\s*func\.func\s+(?:[a-z]+\s+)?@([\w$.-]+)\s*\(')
+# ``%n = stablehlo.minimum %a, %b : tensor<i32>``: a scalar clipped to a
+# bound (a loop's data trip count to the most it can be)
+_SCALAR_MIN_RE = re.compile(
+    r'%(\S+)\s*=\s*stablehlo\.minimum\s+%(\S+?),\s*%(\S+?)\s*:\s*'
+    r'tensor<[su]?i\d+>')
 _CALL_RE = re.compile(r'(?<![\w.])(?:func\.)?call\s+@([\w$.-]+)')
 
 
@@ -256,12 +261,15 @@ def _while_spans(lines: List[str], i: int, end: int
 
 
 def _trip_count(lines: List[str], start: int, end: int,
-                consts: Dict[str, int]) -> Optional[int]:
+                consts: Dict[str, int], header: str) -> Optional[int]:
     """Trip count of a while loop from its cond region: the jax
     counted-loop pattern ``compare LT, %iterArg, %bound`` where
     ``%bound`` is a scalar integer constant (in the region or collected
-    earlier at module scope). ``None`` when the loop is not provably
-    counted (walked with multiplier 1 + a ledger note)."""
+    earlier at module scope), or a loop-carried value whose initial
+    value (named on the ``header`` line) is one: ``consts`` also holds
+    every scalar the program clipped to a constant with ``minimum``, at
+    that constant. ``None`` when the loop is not provably counted
+    (walked with multiplier 1 + a ledger note)."""
     local = dict(consts)
     cmp_line = None
     for k in range(start, end):
@@ -272,10 +280,13 @@ def _trip_count(lines: List[str], start: int, end: int,
             cmp_line = lines[k]
     if cmp_line is None:
         return None
-    for name in re.findall(r'%(\S+?)[,\s:]', cmp_line):
+    names = re.findall(r'%(\S+?)[,\s:]', cmp_line)
+    for name in names:
         if name in local and not name.startswith("iterArg"):
             return local[name]
-    return None
+    # a carried bound (the compare's last operand): its initial value
+    carried = dict(re.findall(r'%(iterArg\w*) = %([^\s,)]+)', header))
+    return consts.get(carried.get(names[-1]))
 
 
 def _flops_for(op: str, operands: List[Tuple[int, str, int]],
@@ -325,7 +336,14 @@ def walk_module(text: str) -> Dict[str, Any]:
     ``dot_general`` = 2·|out|·|contraction|, elementwise float = |out|,
     reduce = |in|; data movement (reshape/convert/slice/...) = 0.
     ``stablehlo.while`` bodies multiply by the parsed trip count, so a
-    prefill scan prices every scanned token. ``func.call`` sites walk
+    verify scan prices every scanned position. **A trip count that is
+    data** is priced at the WORST case where the program clips it to a
+    constant (``minimum(n, K)`` feeding the loop: the decode attention's
+    chunk loop, clipped to ``max_len // block_k``, is priced as every
+    key chunk of the slot, which is what the unrolled chunks it replaced
+    were priced as), and once, with a ledger note, where it does not
+    (the prefill's loop over a cached prompt head: no hit, no trip).
+    ``func.call`` sites walk
     the callee's body at the caller's multiplicity (jax outlines scan
     bodies into ``func.func private`` functions), so outlined loop
     bodies price once per trip, not once per module."""
@@ -348,6 +366,10 @@ def walk_module(text: str) -> Dict[str, Any]:
         m = _SCALAR_CONST_RE.search(body)
         if m:
             consts[m.group(1)] = int(m.group(2))
+        m = _SCALAR_MIN_RE.search(body)
+        if m and (m.group(2) in consts or m.group(3) in consts):
+            consts[m.group(1)] = min(consts[n] for n in m.groups()[1:]
+                                     if n in consts)
         sig = _signature(body)
         operands: List[Tuple[int, str, int]] = []
         results: List[Tuple[int, str, int]] = []
@@ -434,7 +456,7 @@ def walk_module(text: str) -> Dict[str, Any]:
                 spans = None
             if spans is not None:
                 c0, c1, b0, b1, nxt = spans
-                trip = _trip_count(lines, c0, c1, consts)
+                trip = _trip_count(lines, c0, c1, consts, line)
                 if trip is None:
                     trip = 1
                     notes.append(f"while@line{i}: trip count not "

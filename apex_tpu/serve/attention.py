@@ -5,20 +5,29 @@ at the end of this file). Every function here sees one layout: a pool
 ``positions``.
 
 **Decode.** One query token per slot against that slot's cached
-keys/values. The key axis is static (the cache's ``max_pages_per_slot *
-page_size`` virtual axis); reachability is a mask
-(``key_pos <= position``), never a shape — so the op compiles once and a
-slot's result depends only on that slot's bytes (reductions run within a
-slot; other slots' values cannot perturb the arithmetic, which is what
-makes mid-stream eviction bit-invisible to its neighbors).
+keys/values: a loop over the slot's virtual key axis (the cache's
+``max_pages_per_slot * page_size`` rows) in chunks of ``block_k``, with
+an online softmax's running max, denominator and weighted sum as its
+carry (:func:`_fold_cached_chunks`, which the batched prefill's cached
+head runs too).
 
-The softmax is computed in explicitly chunked form over the key axis:
-``block_k`` cached rows per partial reduction, partials combined in a
-static python loop. The chunk geometry is what :mod:`apex_tpu.tune` tunes
-(kernel name ``decode_attention``): on TPU the XLA fusion streams one
-``[block_k, head_dim]`` K/V tile at a time through VMEM, so the block size
-is a real tile-geometry knob, with
-:func:`~apex_tpu.ops.pallas.tiling.decode_attention_block` as the
+*What is static*: every shape, ``block_k``, the carry; so the op compiles
+once. *What is data*: the trip count, ``max over the slots the step
+writes of positions // block_k + 1`` (:func:`attended_chunks`, worked
+out once a forward and handed to every layer). A step pays for the
+chunks its longest active slot can reach, never for ``max_len``;
+reachability inside those chunks is a mask (``key_pos <= position``).
+A slot's result depends only on that slot's bytes (reductions run within
+a slot; a chunk wholly past a slot's position leaves its carry as it was
+to the bit), which is what makes mid-stream eviction, and a long
+neighbour's extra trips, bit-invisible to it; and the result does not
+depend on the trip count once it covers the slot.
+
+The chunk geometry is what :mod:`apex_tpu.tune` tunes (kernel name
+``decode_attention``): on TPU a trip streams one ``[block_k, head_dim]``
+K/V tile a slot and head through VMEM, so the block size is a real
+tile-geometry knob, and it is the granularity of the trip count too,
+with :func:`~apex_tpu.ops.pallas.tiling.decode_attention_block` as the
 committed heuristic. The decode step and the speculative verify scan's
 body call this function with the same geometry, so those two stay
 bit-identical. The batched prefill does not come through here: its
@@ -26,14 +35,19 @@ chunk's own keys never touch the cache's key axis, so it matches the
 decode step to float32 rounding and not to the bit (docs/serving.md).
 
 **A chunk lives inside one page** (``block_k`` divides ``page_size``),
-so its fetch is one page gather plus a static in-page slice; scores,
-masking, the max combine and the sum order do not depend on where the
-pages lie. Two engines with different page sizes are therefore bit-exact
-in fp32 on identical traces **at the same block_k** (tier-1 asserts:
-several pages a slot against one page a slot). The *default* chunk
-follows the page (the heuristic/tuner unit is ``page_size``), and a
-different ``block_k`` reorders the partial sums by design (±1 ulp); pin
-``block_k`` to compare page sizes bitwise.
+so its fetch is one page gather from the stacked pool plus an in-page
+slice; scores, masking, the max and the sum order do not depend on where
+the pages lie. Two engines with different page sizes are therefore
+bit-exact in fp32 on identical traces **at the same block_k** (tier-1
+asserts: several pages a slot against one page a slot), and so are the
+ranks of a tensor-parallel engine (positions are replicated: every rank
+runs the same trips). The *default* chunk follows the page (the
+heuristic/tuner unit is ``page_size``), and a different ``block_k``
+reorders the partial sums by design (±1 ulp); pin ``block_k`` to compare
+page sizes bitwise. *Not promised*: the sum order of a two-pass softmax
+(one max over the whole axis, then one sum). The running sums are
+rescaled by ``exp(m - m_new)`` once a chunk: float32 rounding against
+that form, not its bits.
 
 All math fp32 (max-subtracted softmax; the row's own token is always
 reachable, so the denominator is never empty); IO dtype preserved.
@@ -41,7 +55,7 @@ reachable, so the denominator is never empty); IO dtype preserved.
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -111,91 +125,111 @@ def resolve_block_k(max_len: int, heads: int, head_dim: int, dtype,
     return int(p["block_k"])
 
 
-def _combine_chunks(q: jax.Array, positions: jax.Array, L: int, bk: int,
-                    scale: jnp.float32,
-                    fetch: Callable[[int], Tuple[jax.Array, jax.Array]],
-                    ) -> jax.Array:
-    """The shared chunked-softmax core: ``fetch(i)`` returns chunk ``i``'s
-    ``(k_rows, v_rows)`` as ``[b, block_k, heads, head_dim]``, a page
-    gather. Everything numeric happens HERE, whatever the page size: each
-    score's reduction runs over ``d`` (not ``L``), the global row max
-    equals the max over chunk maxima bit-for-bit, and only the SUM order
-    depends on ``block_k`` — identically in decode and verify.
-    """
+def attended_chunks(positions, mask, block_k: int, key_chunks: int,
+                    xp=jnp):
+    """The decode attention's trip count for one step: the chunks
+    ``0 .. max(positions under mask) // block_k`` hold every key a slot
+    the step writes can reach, and ``key_chunks`` (``max_len //
+    block_k``) is all there are. A slot the mask leaves out does not
+    lengthen the loop, whatever its position says (its output is
+    discarded); with no slot at all the loop runs its one first chunk.
+    ``xp=numpy`` is the engine's host mirror of what the program runs
+    (``apex.decode_step``'s ``attended_chunks``): one spelling for both."""
+    longest = xp.max(xp.where(mask, positions, 0))
+    return xp.minimum(longest // block_k + 1, key_chunks)
+
+
+def _fold_cached_chunks(q32: jax.Array, cache, layer: int, bk: int,
+                        limit: jax.Array, trips: jax.Array,
+                        carry: Tuple[jax.Array, jax.Array, jax.Array],
+                        scale: jnp.float32, precision=None):
+    """Fold the cached key chunks ``0 .. trips - 1`` of every slot into
+    an online softmax's running ``(max, denominator, weighted sum)``:
+    THE loop over cached keys, which the decode step (one query a slot)
+    and the batched prefill's cached head (a chunk of queries a slot)
+    both run.
+
+    ``q32`` ``[b, t, heads, head_dim]`` float32; ``limit`` ``[b]``
+    int32: slot ``b``'s queries reach the cached positions ``<
+    limit[b]``; ``carry`` ``(m [b, h, t], den [b, h, t], num [b, h, t,
+    d])`` float32; ``trips`` an int32 scalar, DATA. A trip fetches
+    ``block_k`` rows a slot from layer ``layer`` of the STACKED pool in
+    one indexing op (``buf[layer, pages]``: slicing the layer out first
+    would be loop-invariant, hoisted, and paid by every call), scale
+    planes the same way under ``kv_quant``, and widens them to float32.
+    A chunk that lies wholly past ``limit[b]`` leaves slot ``b``'s carry
+    as it was to the bit (``m_new == m``, ``keep == 1``, ``e == 0``), so
+    the result does not depend on ``trips`` once it covers the slot, nor
+    on what any other slot holds."""
+    ps = cache.page_size
+    scales = cache.k_scale is not None
+
+    def fetch(buf, r0):
+        pages = jax.lax.dynamic_index_in_dim(
+            cache.page_table, r0 // ps, axis=1, keepdims=False)
+        return jax.lax.dynamic_slice_in_dim(
+            buf[layer, pages], r0 % ps, bk, axis=1)
+
+    def body(i, carry):
+        m, den, num = carry
+        r0 = i * bk
+        ks, vs = fetch(cache.k, r0), fetch(cache.v, r0)
+        if scales:
+            ks = ks.astype(_f32) * fetch(cache.k_scale, r0)[..., None]
+            vs = vs.astype(_f32) * fetch(cache.v_scale, r0)[..., None]
+        kpos = r0 + jnp.arange(bk, dtype=jnp.int32)
+        reach = (kpos[None, :] < limit[:, None])[:, None, None, :]
+        sc = jnp.where(reach, jnp.einsum(
+            "bqhd,bkhd->bhqk", q32, ks.astype(_f32),
+            precision=precision) * scale, NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        keep = jnp.exp(m - m_new)
+        e = jnp.where(reach, jnp.exp(sc - m_new[..., None]), 0.0)
+        d_den = jnp.sum(e, axis=-1)
+        d_num = jnp.einsum("bhqk,bkhd->bhqd", e, vs.astype(_f32),
+                           precision=precision)
+        return (m_new, den * keep + d_den, num * keep[..., None] + d_num)
+
+    return jax.lax.fori_loop(0, trips, body, carry)
+
+
+def paged_attention(q: jax.Array, cache, layer: int, positions: jax.Array,
+                    trips: Optional[jax.Array] = None, *,
+                    block_k: Optional[int] = None) -> jax.Array:
+    """Single-token attention through the page table: slot ``b``'s one
+    query over that slot's cached positions ``0 .. positions[b]``.
+
+    ``q``: ``[num_slots, heads, head_dim]`` (heads as the pool's head
+    axis has them: :func:`~apex_tpu.serve.kv_cache.pad_heads`);
+    ``cache``: a :class:`~apex_tpu.serve.kv_cache.PagedKVCache`, read at
+    layer ``layer``; ``positions``: ``[num_slots]`` int32 over each
+    slot's VIRTUAL key axis (page-table row laid flat). Chunk ``i`` of
+    that axis lives inside page ``page_table[:, (i * block_k) //
+    page_size]`` (``block_k`` divides ``page_size``), so a trip's fetch
+    is one page gather plus an in-page slice and its working set one
+    ``[block_k, head_dim]`` tile a slot and head (what the
+    ``decode_attention`` autotuner times).
+
+    ``trips`` (int32 scalar, data): how many chunks the loop visits,
+    worked out once a forward by :func:`attended_chunks` from the slots
+    the step writes; ``None`` counts every slot. Unmapped table entries
+    point at the null page; its rows sit past every live position, behind
+    the reachability mask. Under ``kv_quant`` each fetched tile is
+    dequantized to float32 as it is read, through the same page gather
+    as the payload, so the scales ride the page table. Returns
+    ``[num_slots, heads, head_dim]`` in ``q.dtype``."""
     b, h, d = q.shape
-    q32 = q.astype(_f32)
-    pos = positions.astype(jnp.int32)[:, None, None]
-    nchunk = L // bk
-
-    def chunk_scores(i):
-        ks, vs = fetch(i)                 # ONE fetch per chunk: a second
-        # call would trace the K and V gathers twice (and execute them
-        # twice under interpret=True) just to rely on XLA CSE
-        sc = jnp.einsum("bhd,bkhd->bhk", q32, ks.astype(_f32)) * scale
-        kpos = jnp.arange(i * bk, (i + 1) * bk, dtype=jnp.int32)
-        reach = kpos[None, None, :] <= pos
-        return jnp.where(reach, sc, NEG_INF), reach, vs
-
-    chunks = [chunk_scores(i) for i in range(nchunk)]      # static unroll
-    m = chunks[0][0].max(axis=-1, keepdims=True)
-    for sc, _, _ in chunks[1:]:
-        m = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-
-    num = jnp.zeros((b, h, d), _f32)
-    den = jnp.zeros((b, h), _f32)
-    for sc, reach, vs in chunks:
-        e = jnp.where(reach, jnp.exp(sc - m), 0.0)         # [b, h, bk]
-        den = den + jnp.sum(e, axis=-1)
-        num = num + jnp.einsum("bhk,bkhd->bhd", e, vs.astype(_f32))
-    return (num / den[..., None]).astype(q.dtype)
-
-
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    page_table: jax.Array, positions: jax.Array, *,
-                    scale: Optional[float] = None,
-                    block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None,
-                    k_scale: Optional[jax.Array] = None,
-                    v_scale: Optional[jax.Array] = None) -> jax.Array:
-    """Single-token attention through the page table.
-
-    ``q``: ``[num_slots, heads, head_dim]``; ``k_pool``/``v_pool``:
-    ``[num_pages, page_size, heads, head_dim]`` (one layer of the paged
-    pool); ``page_table``: ``[num_slots, max_pages_per_slot]`` int32;
-    ``positions``: ``[num_slots]`` int32 over each slot's VIRTUAL key
-    axis (page-table row laid flat). Chunk ``i`` of the virtual axis
-    lives inside page ``page_table[:, (i * block_k) // page_size]``
-    (``block_k`` divides ``page_size``), so the fetch is one page gather
-    plus a static in-page slice — the working set per partial reduction
-    is one ``[block_k, head_dim]`` tile (the premise the
-    ``decode_attention`` autotuner times). Unmapped table entries point
-    at the null page; its rows sit past every live position, so the
-    reachability mask discards them.
-
-    ``k_scale``/``v_scale`` (``[num_pages, page_size, heads]`` fp32, one
-    layer of a ``kv_quant`` pool's scale planes) dequantize each fetched
-    ``[block_k]`` tile to fp32 as it is read, through the SAME page
-    gather as the payload — the scores/combine arithmetic never changes,
-    and the scales ride the page table, so sharing/COW/eviction need no
-    quant-aware code.
-    """
-    P, ps, h, d = k_pool.shape
-    L = int(page_table.shape[1]) * ps
-    bk = resolve_block_k(L, h, d, q.dtype, block_k, interpret,
-                         page_size=ps)
-    s = jnp.float32(scale if scale is not None else 1.0 / (d ** 0.5))
-
-    def fetch(i):
-        start = i * bk
-        pages = page_table[:, start // ps]                 # [b]
-        sl = slice(start % ps, start % ps + bk)            # static in-page
-        ks, vs = k_pool[pages, sl], v_pool[pages, sl]
-        if k_scale is not None:
-            ks = ks.astype(_f32) * k_scale[pages, sl][..., None]
-            vs = vs.astype(_f32) * v_scale[pages, sl][..., None]
-        return ks, vs
-
-    return _combine_chunks(q, positions, L, bk, s, fetch)
+    L = cache.max_len
+    bk = resolve_block_k(L, h, d, q.dtype, block_k,
+                         page_size=cache.page_size)
+    pos = positions.astype(jnp.int32)
+    if trips is None:
+        trips = attended_chunks(pos, True, bk, L // bk)
+    _, den, num = _fold_cached_chunks(
+        q.astype(_f32)[:, None], cache, layer, bk, pos + 1, trips,
+        (jnp.full((b, h, 1), NEG_INF), jnp.zeros((b, h, 1), _f32),
+         jnp.zeros((b, h, 1, d), _f32)), jnp.float32(1.0 / (d ** 0.5)))
+    return (num / den[..., None])[:, :, 0].astype(q.dtype)
 
 
 def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
@@ -234,62 +268,25 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
     heads, head_dim]`` in ``q.dtype``.
     """
     b, t, h, d = q.shape
-    ps = cache.page_size
     bk = resolve_block_k(cache.max_len, h, d, q.dtype, block_k,
-                         page_size=ps)
+                         page_size=cache.page_size)
     s = jnp.float32(1.0 / (d ** 0.5))
     hi = jax.lax.Precision.HIGHEST
     q32 = q.astype(_f32)
 
-    def scores(ks, reach):
-        """Every query against ``ks`` ``[b, k, h, d]``, unreachable keys
-        at ``NEG_INF`` (``reach`` broadcasts to ``[b, h, t, k]``)."""
-        sc = jnp.einsum("bqhd,bkhd->bhqk", q32, ks.astype(_f32),
-                        precision=hi) * s
-        return jnp.where(reach, sc, NEG_INF)
-
-    def weigh(sc, vs, reach, m):
-        """The softmax's sum and weighted values about the max ``m``."""
-        e = jnp.where(reach, jnp.exp(sc - m[..., None]), 0.0)
-        return (jnp.sum(e, axis=-1),
-                jnp.einsum("bhqk,bkhd->bhqd", e, vs.astype(_f32),
-                           precision=hi))
-
     idx = jnp.arange(t, dtype=jnp.int32)
     causal = (idx[None, :] <= idx[:, None])[None, None]    # [1, 1, q, k]
-    sc = scores(k, causal)
+    sc = jnp.where(causal, jnp.einsum(
+        "bqhd,bkhd->bhqk", q32, k.astype(_f32), precision=hi) * s, NEG_INF)
     m = sc.max(axis=-1)                                    # [b, h, t]
-    den, num = weigh(sc, v, causal, m)
+    e = jnp.where(causal, jnp.exp(sc - m[..., None]), 0.0)
+    den = jnp.sum(e, axis=-1)
+    num = jnp.einsum("bhqk,bkhd->bhqd", e, v.astype(_f32), precision=hi)
 
     start = start.astype(jnp.int32)
-    scales = cache.k_scale is not None
-
-    def fetch(buf, r0):
-        """Rows ``r0 .. r0 + block_k`` of every slot's key axis out of
-        the STACKED buffer in one indexing op: slicing the layer out
-        first would be loop-invariant, hoisted, and paid by every call."""
-        pages = jax.lax.dynamic_index_in_dim(
-            cache.page_table, r0 // ps, axis=1, keepdims=False)
-        return jax.lax.dynamic_slice_in_dim(
-            buf[layer, pages], r0 % ps, bk, axis=1)
-
-    def body(i, carry):
-        m, den, num = carry
-        r0 = i * bk
-        ks, vs = fetch(cache.k, r0), fetch(cache.v, r0)
-        if scales:
-            ks = ks.astype(_f32) * fetch(cache.k_scale, r0)[..., None]
-            vs = vs.astype(_f32) * fetch(cache.v_scale, r0)[..., None]
-        kpos = r0 + jnp.arange(bk, dtype=jnp.int32)
-        reach = (kpos[None, :] < start[:, None])[:, None, None, :]
-        sc = scores(ks, reach)
-        m_new = jnp.maximum(m, sc.max(axis=-1))
-        keep = jnp.exp(m - m_new)
-        d_den, d_num = weigh(sc, vs, reach, m_new)
-        return (m_new, den * keep + d_den, num * keep[..., None] + d_num)
-
-    m, den, num = jax.lax.fori_loop(
-        0, (jnp.max(start) + bk - 1) // bk, body, (m, den, num))
+    _, den, num = _fold_cached_chunks(
+        q32, cache, layer, bk, start, (jnp.max(start) + bk - 1) // bk,
+        (m, den, num), s, precision=hi)
     o = num / den[..., None]                               # [b, h, t, d]
     return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)
 

@@ -875,14 +875,21 @@ class Engine:
         resident before the step, pool pages out of the free
         list, and what the compiled ``program`` says of the pool
         (``pool_aliased_bytes``, ``pool_copies``: there once
-        :meth:`aot_compile` holds the executable). Host ints the engine
+        :meth:`aot_compile` holds the executable), and of the slot's
+        ``key_chunks`` chunks of ``block_k`` keys how many the step's
+        attention visits (``attended_chunks``: the model's own count, a
+        verify step's at its first position). Host ints the engine
         already keeps; nothing is read from the device."""
+        key_chunks = self.max_len // self.block_k
         return {"active": int(act_np.sum()),
                 "slots": self.config.num_slots,
                 "resident": self.resident_tokens,
                 **self._pool_facts.get(program, {}),
                 "pages_in_use": self.pool.capacity - self.pool.free_count,
-                "pages": self.pool.capacity}
+                "pages": self.pool.capacity,
+                "attended_chunks": self.model.attended_chunks(
+                    self._host_lengths, act_np, self.block_k, key_chunks),
+                "key_chunks": key_chunks}
 
     def _note_counters(self, span: str, counters, real_rows: int) -> None:
         """Where the model's forward returned counters (an expert

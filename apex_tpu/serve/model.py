@@ -11,6 +11,10 @@ model gives it, through one object:
 - ``block_k(max_len, page_size, config, tp)``: the decode-attention
   chunk, resolved once at build (``page_size`` is the engine's resolved
   one: ``max_len`` where the config names none);
+- ``attended_chunks(lengths, active, block_k, key_chunks)``: of a
+  slot's ``key_chunks`` chunks of ``block_k`` keys, how many the decode
+  attention will visit in a step over these host lengths and this active
+  mask (the span ``apex.decode_step`` carries both);
 - ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant, tp)``:
   its cache pytree, a paged pool with ``lengths`` and ``page_table`` as
   ``serve/kv_cache.py`` and ``serve/paging.py`` expect them, allocated
@@ -34,9 +38,11 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+import numpy as np
+
 from apex_tpu.models.gpt2 import GPT2Config, gpt2_token_forward
 from apex_tpu.serve import kv_cache
-from apex_tpu.serve.attention import resolve_block_k
+from apex_tpu.serve.attention import attended_chunks, resolve_block_k
 
 
 class GPT2Serving:
@@ -58,6 +64,12 @@ class GPT2Serving:
         return resolve_block_k(max_len, self.heads // tp, self.head_dim,
                                self.compute_dtype, config.block_k,
                                page_size=page_size, tp_shards=tp)
+
+    def attended_chunks(self, lengths, active, block_k: int,
+                        key_chunks: int) -> int:
+        # the decode attention's loop runs to the longest active slot
+        return int(attended_chunks(lengths, active, block_k, key_chunks,
+                                   xp=np))
 
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):
@@ -118,6 +130,11 @@ class DeepseekV3Serving:
                 f"block_k={config.block_k}: deepseek_v3's latent attention "
                 f"reads a page at a time (page_size={page_size})")
         return int(page_size)
+
+    def attended_chunks(self, lengths, active, block_k: int,
+                        key_chunks: int) -> int:
+        # latent_decode_attention gathers the slot's whole key axis
+        return key_chunks
 
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):

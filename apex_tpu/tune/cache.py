@@ -53,7 +53,11 @@ CODE_VERSIONS = {
     # v4: the slot-contiguous layout (page_size key 0) went; one page a
     # slot is keyed page_size == max_len — v3's slot-layout winners were
     # timed on another fetch and must not apply
-    "decode_attention": 4,
+    # v5: the chunks became a loop whose trip count follows the longest
+    # slot, timed over slots of spread lengths — v4's winners were timed
+    # on 16 unrolled chunks of full slots, where a smaller chunk cost no
+    # trips
+    "decode_attention": 5,
     "fused_adam": 1,
     "fused_sgd": 1,
     "fused_lamb": 1,

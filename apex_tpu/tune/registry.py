@@ -384,25 +384,31 @@ def _da_build(shape, dtype, params, interpret=None):
                int(shape["d"]))
     ps = _da_unit(shape)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, h, d), dtype) * 0.2
-    positions = jnp.full((b,), L - 1, jnp.int32)  # worst case: full cache
     bk = params["block_k"]
 
-    # time the page-table gather path at full residency (every slot's
-    # table maps distinct live pages, like a busy pool)
+    # time the page-table gather path of a busy pool (every slot's table
+    # maps distinct live pages) at contexts a serving step sees, not at
+    # full slots alone: the loop's trip count follows the longest slot,
+    # so the slots are spread from an eighth of max_len to half of it (a
+    # smaller chunk then saves masked rows and pays trips, as in serving)
     from apex_tpu.serve.attention import paged_attention
+    from apex_tpu.serve.kv_cache import init_paged_cache
 
+    positions = jnp.linspace(L // 8, L // 2, b).astype(jnp.int32)
     mp = L // ps
     P = b * mp + 1                             # +1: the reserved null page
-    kc = jax.random.normal(ks[1], (P, ps, h, d), dtype) * 0.2
-    vc = jax.random.normal(ks[2], (P, ps, h, d), dtype) * 0.2
-    table = jnp.arange(1, P, dtype=jnp.int32).reshape(b, mp)
+    pool = init_paged_cache(1, b, L, ps, P, h, d, dtype)
+    cache = pool.replace(
+        k=jax.random.normal(ks[1], pool.k.shape, dtype) * 0.2,
+        v=jax.random.normal(ks[2], pool.v.shape, dtype) * 0.2,
+        page_table=jnp.arange(1, P, dtype=jnp.int32).reshape(b, mp))
+    # the queries meet the pool's head axis, allocated in whole tiles
+    q = jax.random.normal(ks[0], (b, pool.k.shape[-2], d), dtype) * 0.2
 
-    def step(i, q, kc, vc):
-        return paged_attention(q, kc, vc, table, positions,
-                               block_k=bk, interpret=interpret)
+    def step(i, q, cache):
+        return paged_attention(q, cache, 0, positions, block_k=bk)
 
-    return step, q, (kc, vc)
+    return step, q, (cache,)
 
 
 # ------------------------------------------------------ flat optimizers

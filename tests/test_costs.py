@@ -113,6 +113,23 @@ def test_walk_multiplies_while_bodies_by_trip_count():
     assert "notes" not in ten     # trip count statically resolved
 
 
+def test_walk_prices_a_data_trip_count_at_the_bound_it_is_clipped_to():
+    """The decode attention's convention: a loop whose trip count is
+    data is priced at its worst case where the program clips the count
+    to a constant, and once, with a note, where it does not."""
+    def f(x, n, clip):
+        trips = jnp.minimum(n, 7) if clip else n
+        return jax.lax.fori_loop(0, trips, lambda i, c: c * 1.5, x)
+
+    x, n = jnp.ones((16,), jnp.float32), jnp.int32(3)
+    clipped, free = (costs.walk_module(costs.stablehlo_debug_text(
+        jax.jit(f, static_argnums=2).lower(x, n, clip)))
+        for clip in (True, False))
+    assert clipped["total"]["flops"] == 7 * free["total"]["flops"] == 7 * 16
+    assert "notes" not in clipped
+    assert any("counted once" in note for note in free["notes"])
+
+
 def test_walk_ignores_phase_named_source_paths(tmp_path):
     """MLIR loc bodies quote source FILE paths alongside named_scope
     paths — code traced from a directory that happens to be named after

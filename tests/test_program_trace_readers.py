@@ -29,7 +29,8 @@ TICKS = [
     ("apex.sched.step", 0.0, 24.0, {"queued": 4}),
     ("apex.decode_step", 0.1, 23.8, {"active": 4, "slots": 4,
                                      "resident": 400, "pages_in_use": 12,
-                                     "pages": 16}),
+                                     "pages": 16, "attended_chunks": 2,
+                                     "key_chunks": 16}),
     ("apex.decode_step.launch", 0.2, 1.2, {}),
     ("apex.decode_step.fetch", 1.25, 22.0, {}),
     ("apex.sched.accept", 23.85, 23.95, {}),
@@ -37,7 +38,8 @@ TICKS = [
     ("apex.sched.step", 24.5, 49.0, {"queued": 4}),
     ("apex.decode_step", 24.6, 48.7, {"active": 3, "slots": 4,
                                       "resident": 404, "pages_in_use": 13,
-                                      "pages": 16}),
+                                      "pages": 16, "attended_chunks": 2,
+                                      "key_chunks": 16}),
     ("apex.decode_step.launch", 24.7, 25.5, {}),
     ("apex.decode_step.fetch", 25.6, 45.7, {}),
     ("apex.sched.accept", 48.75, 48.9, {}),
@@ -53,7 +55,8 @@ TICKS = [
     ("apex.prefill.index", 104.0, 104.1, {}),
     ("apex.decode_step", 105.5, 129.5, {"active": 4, "slots": 4,
                                         "resident": 450, "pages_in_use": 16,
-                                        "pages": 16}),
+                                        "pages": 16, "attended_chunks": 3,
+                                        "key_chunks": 16}),
     ("apex.decode_step.launch", 105.6, 106.6, {}),
     ("apex.decode_step.fetch", 106.7, 127.9, {}),
     ("apex.sched.accept", 129.6, 129.9, {}),
@@ -66,7 +69,8 @@ CUT_TICK = [
     ("apex.sched.step", 130.6, 160.0, {"queued": 4}),
     ("apex.decode_step", 130.7, 159.5, {"active": 1, "slots": 4,
                                         "resident": 454, "pages_in_use": 4,
-                                        "pages": 16}),
+                                        "pages": 16, "attended_chunks": 16,
+                                        "key_chunks": 16}),
     ("apex.decode_step.launch", 130.8, 131.5, {}),
     ("apex.decode_step.fetch", 131.6, 159.0, {}),
 ]
@@ -281,6 +285,31 @@ def test_a_run_the_trace_cuts_and_its_spans_are_left_out(name, cut):
     assert _read(name, cut) == pytest.approx(EXPECTED[name], rel=1e-9)
 
 
+def test_attended_chunk_share_reads_the_steps_trip_counts(whole, cut,
+                                                          tmp_path):
+    """PR 33's one metric, on the ``share`` reader PR 28 brought: the
+    trips the three whole steps ran (2, 2, 3) over the 16 chunks a slot
+    has; the cut step's 16 of 16 is left out with its run; and spans that
+    carry no such attribute (a program from before the loop) give nothing
+    to read, never 0."""
+    name = "decode_attended_chunk_share"
+    assert _read(name, whole) == pytest.approx(100 * (2 + 2 + 3) / 48)
+    assert _read(name, cut) == pytest.approx(100 * (2 + 2 + 3) / 48)
+    before = [(n, a, b, {k: v for k, v in st.items()
+                         if k not in ("attended_chunks", "key_chunks")})
+              for n, a, b, st in TICKS]
+    assert _read(name, _write(tmp_path, before)) is None
+    assert _read("pool_pages_in_use_share", _write(tmp_path / "again",
+                                                   before)) \
+        == pytest.approx(EXPECTED["pool_pages_in_use_share"])
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    spec = _spec(name)
+    assert (spec["layer"], spec["unit"], spec["moves"], entry["better"],
+            entry["source"], entry["workloads"]) == (
+        entry["layer"], entry["unit"], entry["moves"], "lower",
+        "program_counter", ["gpt2-xl.chat-short"])
+
+
 def test_the_parts_sum_to_the_run_and_the_trace_is_read_once(whole,
                                                              monkeypatch):
     for program, per, run_ms in (("decode", "step", 20.0),
@@ -398,8 +427,10 @@ def test_the_programs_own_spans_reach_the_reader_and_no_chip_reads_nothing(
     for s in tr["spans"]:
         if s[0] == "apex.decode_step":
             assert set(s[3]) == {"active", "slots", "resident",
-                                 "pages_in_use", "pages"}
+                                 "pages_in_use", "pages",
+                                 "attended_chunks", "key_chunks"}
             assert 0 < s[3]["active"] <= s[3]["slots"] == 2
+            assert 0 < s[3]["attended_chunks"] <= s[3]["key_chunks"]
         elif s[0] == "apex.prefill.launch":
             assert s[3] == {"bucket": 8, "slots": 2, "real_positions": 7,
                             "hit_tokens": 0, "new_pages": 2}
@@ -453,8 +484,8 @@ def test_every_new_metric_has_its_file_its_entry_and_its_reader():
     entries = {m["name"]: m for m in bench["per_layer"]}
     files = sorted(f[:-5] for f in os.listdir(
         os.path.join(BENCH, "layer_metrics")) if f.endswith(".json"))
-    # PR 24's 8, PR 28's 17, then PR 30's 9 for the deepseek_v3 cell
-    assert files == sorted(entries) and len(files) == 34
+    # PR 24's 8, PR 28's 17, PR 30's 9 for the deepseek_v3 cell, PR 33's 1
+    assert files == sorted(entries) and len(files) == 35
     assert list(entries)[8:25] == [
         "sched_self_ms_per_tick", "decode_launch_lag_ms_per_step",
         "decode_device_lag_ms_per_step", "decode_fetch_lag_ms_per_step",

@@ -1260,43 +1260,6 @@ def test_stall_window_closes_when_stalled_head_leaves_queue(params):
     assert eng.decode_traces == 1
 
 
-def test_combine_chunks_fetches_each_chunk_once():
-    """Review perf regression: ``_combine_chunks`` materializes each
-    chunk's (K, V) exactly once — a second ``fetch(i)`` per chunk traced
-    four page-table gathers where two suffice (and actually executed
-    them under interpret=True)."""
-    from apex_tpu.serve.attention import _combine_chunks, paged_attention
-
-    rng = np.random.RandomState(0)
-    k = rng.randn(2, 16, 2, 4).astype(np.float32)
-    v = rng.randn(2, 16, 2, 4).astype(np.float32)
-    q = jnp.asarray(rng.randn(2, 2, 4).astype(np.float32))
-    pos = jnp.asarray([5, 9], dtype=jnp.int32)
-    calls = []
-
-    def fetch(i):
-        calls.append(i)
-        sl = slice(i * 4, (i + 1) * 4)
-        return jnp.asarray(k[:, sl]), jnp.asarray(v[:, sl])
-
-    out = _combine_chunks(q, pos, 16, 4, jnp.float32(0.5), fetch)
-    assert sorted(calls) == [0, 1, 2, 3], calls    # once per chunk
-    # and the single-fetch path is the SAME numbers the public entry
-    # point produces at the same block_k, whether a slot's 16 rows lie
-    # in one page (pages 1, 2 under a null page) or in four of 4 rows
-    def pool(x, ps):
-        return jnp.concatenate(
-            [jnp.zeros((1, ps) + x.shape[2:], x.dtype),
-             jnp.asarray(x).reshape((-1, ps) + x.shape[2:])])
-
-    for ps in (16, 4):
-        table = jnp.arange(1, 2 * 16 // ps + 1,
-                           dtype=jnp.int32).reshape(2, -1)
-        ref = paged_attention(q, pool(k, ps), pool(v, ps), table, pos,
-                              scale=0.5, block_k=4)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
 def test_plan_admission_empty_prompt():
     """Review regression: an empty prompt (legal for the planner — only
     ``ServeScheduler.submit`` rejects it) must plan zero shared tokens

@@ -8,7 +8,9 @@ Two halves:
   ``deepseek_v3`` engine at the second cell's, hold no ``copy`` of a
   pool's shape and alias every pool array, with every argument in the
   layout the runtime gives it. This is the guard that keeps the
-  whole-pool copies from coming back with a later kernel.
+  whole-pool copies from coming back with a later kernel. The GPT-2
+  programs also hold one ``while`` a layer under ``attention`` and no
+  slice of one layer's whole pool out of the stacked array.
 - **The donation contract**, on the CPU: a full admit / complete / evict /
   backfill / prefix-hit trace through an engine whose calls donate the
   cache gives the token streams of a twin that donates nothing; no call
@@ -21,6 +23,7 @@ Two halves:
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -137,6 +140,18 @@ def test_compiled_programs_copy_no_pool_and_alias_every_pool_array(
     # the v5e's tiles pad a pool array, so the aliased bytes are at least
     # the logical ones
     assert facts["pool_aliased_bytes"] >= eng.kv_cache_bytes, facts
+    if model != "gpt2-xl":
+        return
+    # attention over cached keys is one loop a layer (decode and the
+    # verify scan's body: the key chunks a slot can reach; prefill: a
+    # cached prompt head), and its body fetches from the STACKED pool:
+    # no program slices one layer's whole pool out first
+    text = compiled.as_text()
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert len([n for n in loops if "/attention/" in n]) \
+        == eng.model.n_layer, loops
+    layer = ",".join(map(str, eng.cache.k.shape[1:]))
+    assert f"[{layer}]" not in text, f"a whole layer's pool [{layer}]"
 
 
 def test_with_a_head_axis_the_pool_is_relaid_and_the_counter_sees_it(
