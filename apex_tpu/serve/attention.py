@@ -139,7 +139,7 @@ def attended_chunks(positions, mask, block_k: int, key_chunks: int,
     return xp.minimum(longest // block_k + 1, key_chunks)
 
 
-def _fold_cached_chunks(q32: jax.Array, cache, layer: int, bk: int,
+def _fold_cached_chunks(q32: jax.Array, cache, layer, bk: int,
                         limit: jax.Array, trips: jax.Array,
                         carry: Tuple[jax.Array, jax.Array, jax.Array],
                         scale: jnp.float32, precision=None):
@@ -152,7 +152,10 @@ def _fold_cached_chunks(q32: jax.Array, cache, layer: int, bk: int,
     ``q32`` ``[b, t, heads, head_dim]`` float32; ``limit`` ``[b]``
     int32: slot ``b``'s queries reach the cached positions ``<
     limit[b]``; ``carry`` ``(m [b, h, t], den [b, h, t], num [b, h, t,
-    d])`` float32; ``trips`` an int32 scalar, DATA. A trip fetches
+    d])`` float32; ``trips`` an int32 scalar, DATA; ``layer`` the pool's
+    plane, a python int or, where the model loops over its layers, a
+    traced int32 scalar (DATA too: one more coordinate of the fetch's
+    gather). A trip fetches
     ``block_k`` rows a slot from layer ``layer`` of the STACKED pool in
     one indexing op (``buf[layer, pages]``: slicing the layer out first
     would be loop-invariant, hoisted, and paid by every call), scale
@@ -193,7 +196,7 @@ def _fold_cached_chunks(q32: jax.Array, cache, layer: int, bk: int,
     return jax.lax.fori_loop(0, trips, body, carry)
 
 
-def paged_attention(q: jax.Array, cache, layer: int, positions: jax.Array,
+def paged_attention(q: jax.Array, cache, layer, positions: jax.Array,
                     trips: Optional[jax.Array] = None, *,
                     block_k: Optional[int] = None) -> jax.Array:
     """Single-token attention through the page table: slot ``b``'s one
@@ -202,9 +205,9 @@ def paged_attention(q: jax.Array, cache, layer: int, positions: jax.Array,
     ``q``: ``[num_slots, heads, head_dim]`` (heads as the pool's head
     axis has them: :func:`~apex_tpu.serve.kv_cache.pad_heads`);
     ``cache``: a :class:`~apex_tpu.serve.kv_cache.PagedKVCache`, read at
-    layer ``layer``; ``positions``: ``[num_slots]`` int32 over each
-    slot's VIRTUAL key axis (page-table row laid flat). Chunk ``i`` of
-    that axis lives inside page ``page_table[:, (i * block_k) //
+    plane ``layer`` (static or traced); ``positions``: ``[num_slots]``
+    int32 over each slot's VIRTUAL key axis (page-table row laid flat).
+    Chunk ``i`` of that axis lives inside page ``page_table[:, (i * block_k) //
     page_size]`` (``block_k`` divides ``page_size``), so a trip's fetch
     is one page gather plus an in-page slice and its working set one
     ``[block_k, head_dim]`` tile a slot and head (what the
@@ -233,7 +236,7 @@ def paged_attention(q: jax.Array, cache, layer: int, positions: jax.Array,
 
 
 def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
-                    layer: int, start: jax.Array, *,
+                    layer, start: jax.Array, *,
                     block_k: int) -> jax.Array:
     """Attention of a chunk of ``T`` consecutive tokens per slot — the
     batched prefill's attention.
@@ -251,7 +254,8 @@ def chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
         the chunk alone; and
     (b) over the cached positions ``< start[b]`` (a prompt's head that
         the prefix index served), fetched ``block_k`` rows at a time from
-        layer ``layer`` of ``cache`` through the page table and folded
+        plane ``layer`` of ``cache`` (static or traced) through the page
+        table and folded
         into (a)'s running max, sum and weighted sum.
 
     (b) is a loop whose trip count is DATA: ``ceil(max(start) /

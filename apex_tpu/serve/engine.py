@@ -893,12 +893,14 @@ class Engine:
 
     def _note_counters(self, span: str, counters, real_rows: int) -> None:
         """Where the model's forward returned counters (an expert
-        model's routing), fetch them, now that the call's tokens are
-        here, and leave them as the attributes of ``<span>.routing``,
-        inside the call's own span."""
+        model's routing, a looped model's passes), fetch them, now that
+        the call's tokens are here, and leave them as the attributes of
+        ``<span>.<the model's counters_span>``, inside the call's own
+        span."""
         if counters:
-            with annotate(span + ".routing", **self.model.call_counters(
-                    np.asarray(counters[0]), real_rows)):
+            with annotate(f"{span}.{self.model.counters_span}",
+                          **self.model.call_counters(
+                              np.asarray(counters[0]), real_rows)):
                 pass
 
     def prefill(self, prompts: Dict[int, Sequence[int]], *,
@@ -1219,7 +1221,7 @@ class Engine:
         """Snapshot the indexed prefix pages of ``tokens`` for streaming
         into another replica's pool: ``[{chain_hash, k, v, digest}, ...]``
         in chain order, one entry per consecutive indexed full chunk.
-        Payload arrays are host copies ``[n_layer, page_size, heads,
+        Payload arrays are host copies ``[cache_planes, page_size, heads,
         head_dim]`` with the pool's padded head axis (under tensor
         parallelism ``device_get`` gathers the head shards — page indices are rank-invariant, payloads are
         whole pages). The digest is stamped here, over the exact bytes
@@ -1281,7 +1283,7 @@ class Engine:
                 "prefix_cache=True (page migration lands in the prefix "
                 "index)")
         self._refuse_page_migration()
-        shape = (self.model.n_layer, self.page_size) \
+        shape = (self.model.cache_planes, self.page_size) \
             + tuple(self.cache.k.shape[3:])
         stats = {"installed": 0, "duplicate": 0, "no_capacity": 0}
         for p in payloads:

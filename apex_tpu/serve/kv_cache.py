@@ -214,7 +214,7 @@ def init_paged_cache(n_layer: int, num_slots: int, max_len: int,
 
 
 @jax.named_scope("kv_write")
-def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
+def paged_write_token(cache: PagedKVCache, layer, k_tok: jax.Array,
                       v_tok: jax.Array, positions: jax.Array,
                       mask: jax.Array,
                       codec: Optional[str] = None) -> PagedKVCache:
@@ -224,9 +224,12 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
     decode step's write.
 
     ``k_tok``/``v_tok``: ``[num_slots, heads, head_dim]``; ``positions``:
-    ``[num_slots]`` int32; ``mask``: ``[num_slots]`` bool. ``layer`` is a
-    python int (the model unrolls its layers), so the layer index is
-    static; shapes never change, so this is recompile-free under jit.
+    ``[num_slots]`` int32; ``mask``: ``[num_slots]`` bool. ``layer`` is
+    the pool's plane: a python int where the model unrolls its layers (the
+    index is then static), a traced int32 scalar where it loops over them
+    (``models/ouro.py``: the plane is then DATA, one more coordinate of the
+    same gather and scatter). Shapes never change either way, so this is
+    recompile-free under jit.
 
     Masked-off slots are routed to the null page (page 0) and write back
     its current row bit-for-bit: a stale page-table entry on an inactive
@@ -272,7 +275,7 @@ def paged_write_token(cache: PagedKVCache, layer: int, k_tok: jax.Array,
 
 
 @jax.named_scope("kv_write")
-def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
+def write_rows(cache, layer, k_rows: jax.Array, v_rows: jax.Array,
                positions: jax.Array, mask: jax.Array,
                codec: Optional[str] = None):
     """Append a chunk of tokens' K/V per slot in one masked scatter —
@@ -280,7 +283,8 @@ def write_rows(cache, layer: int, k_rows: jax.Array, v_rows: jax.Array,
 
     ``k_rows``/``v_rows``: ``[num_slots, T, heads, head_dim]``;
     ``positions``/``mask``: ``[num_slots, T]`` (int32 absolute position,
-    bool). Row ``(b, t)`` lands at ``positions[b, t]`` of slot ``b``,
+    bool; ``layer`` as :func:`paged_write_token` takes it: static or
+    traced). Row ``(b, t)`` lands at ``positions[b, t]`` of slot ``b``,
     through the page table, where ``mask[b, t]``. A
     masked-off row (a slot this call does not admit, or a prompt's
     padding) is given an out-of-range page and DROPPED by the scatter:

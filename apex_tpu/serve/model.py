@@ -6,6 +6,10 @@ model gives it, through one object:
 
 - ``name``, ``cfg``, ``max_positions``, ``n_layer``, ``vocab_size``,
   ``compute_dtype``, and ``workload()``: what a cost ledger says of it;
+- ``cache_planes``: the planes of its cache, the leading axis of every
+  pool array and of a migrated page's payload. ``n_layer`` counts layers
+  of WEIGHTS; the two are equal unless the model runs its layers more
+  than once a token, each pass with planes of its own (``ouro``);
 - ``refuse(config)``: a build-time ``ValueError`` for every engine
   mode the model has no mechanism for, naming the mechanism;
 - ``block_k(max_len, page_size, config, tp)``: the decode-attention
@@ -25,8 +29,9 @@ model gives it, through one object:
   ``logits_at``: prefill). Returns ``(logits, cache)``, or ``(logits,
   cache, counters)`` with ``counters`` a small int32 array the programs
   hand back beside their results; a model that returns them also gives
-  ``call_counters(counters, real_rows)``, the attributes of the span
-  ``apex.<call>.routing`` the engine leaves them on.
+  ``counters_span`` and ``call_counters(counters, real_rows)``: the name
+  and the attributes of the span ``apex.<call>.<counters_span>`` the
+  engine leaves them on (``routing``, ``loop``).
 
 ``serving_model(cfg)`` finds the object: a ``GPT2Config`` gets
 :class:`GPT2Serving`; any other config provides ``cfg.serving_model()``.
@@ -54,6 +59,7 @@ class GPT2Serving:
         self.cfg = cfg
         self.max_positions = cfg.n_positions
         self.n_layer, self.vocab_size = cfg.n_layer, cfg.vocab_size
+        self.cache_planes = cfg.n_layer
         self.compute_dtype = cfg.compute_dtype
         self.heads, self.head_dim = cfg.n_head, cfg.n_embd // cfg.n_head
 
@@ -74,7 +80,7 @@ class GPT2Serving:
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):
         return kv_cache.init_paged_cache(
-            self.n_layer, num_slots, max_len, page_size, num_pages,
+            self.cache_planes, num_slots, max_len, page_size, num_pages,
             self.heads, self.head_dim, self.compute_dtype, kv_quant=kv_quant,
             shards=tp)
 
@@ -93,11 +99,13 @@ class DeepseekV3Serving:
     expert-parallel deployment: the paged latent cache, one chip."""
 
     name = "deepseek_v3"
+    counters_span = "routing"
 
     def __init__(self, cfg):
         self.cfg = cfg
         self.max_positions = cfg.max_position_embeddings
         self.n_layer, self.vocab_size = cfg.num_hidden_layers, cfg.vocab
+        self.cache_planes = cfg.num_hidden_layers
         self.compute_dtype = cfg.compute_dtype
         self.heads, self.head_dim = (cfg.num_attention_heads,
                                      cfg.latent_width)
@@ -139,7 +147,7 @@ class DeepseekV3Serving:
     def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
                    tp=1):
         return kv_cache.init_paged_latent_cache(
-            self.n_layer, num_slots, max_len, page_size, num_pages,
+            self.cache_planes, num_slots, max_len, page_size, num_pages,
             self.cfg.latent_width, self.compute_dtype)
 
     def forward(self, weights, cache, *data, block_k=None, kv_quant=None,
@@ -169,6 +177,64 @@ class DeepseekV3Serving:
                 "n_routed_experts": int(c.n_routed_experts)}
 
 
+class OuroServing(GPT2Serving):
+    """``ouro`` (``models/ouro.py``), whole on one chip: GPT-2's paged
+    key-value pool, chunk and trip count, with a plane for every layer of
+    every pass."""
+
+    name = "ouro"
+    counters_span = "loop"
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.max_positions = cfg.max_position_embeddings
+        self.n_layer, self.vocab_size = cfg.num_hidden_layers, cfg.vocab_size
+        self.cache_planes = cfg.cache_planes
+        self.compute_dtype = cfg.compute_dtype
+        self.heads, self.head_dim = cfg.num_attention_heads, cfg.head_dim
+
+    def refuse(self, config) -> None:
+        missing = []
+        if config.tp > 1:
+            missing.append(f"tp={config.tp}: there is no per-rank forward "
+                           f"for it (serve/tp.py is GPT-2's)")
+        if config.spec_draft_len:
+            missing.append(f"spec_draft_len={config.spec_draft_len}: the "
+                           f"verify scan's rollback of rejected rows is "
+                           f"unproven over planes that every pass of a "
+                           f"later token reads")
+        if config.kv_quant is not None:
+            missing.append(f"kv_quant={config.kv_quant!r}: the codec's "
+                           f"tolerance is calibrated for one trip through "
+                           f"the layers, and no test holds it over "
+                           f"{self.cfg.total_ut_steps} passes that each "
+                           f"read what the one before wrote")
+        if missing:
+            raise ValueError("ouro is not served with " + "; ".join(missing))
+
+    def forward(self, weights, cache, *data, **kw):
+        from apex_tpu.models.ouro import ouro_token_forward
+
+        return ouro_token_forward(self.cfg, weights, cache, *data, **kw)
+
+    def call_counters(self, counters, real_rows: int) -> Dict[str, int]:
+        """What an engine call's span says of its pass loop: the two
+        counters the program returned, beside the rows they are over and
+        the planes the call wrote."""
+        passes, early_exits = (int(v) for v in counters)
+        return {"passes": passes, "rows": real_rows,
+                "planes": self.cache_planes, "early_exits": early_exits}
+
+    def workload(self) -> Dict[str, Any]:
+        c = self.cfg
+        return {"n_layer": int(c.num_hidden_layers),
+                "n_embd": int(c.hidden_size),
+                "n_head": int(c.num_attention_heads),
+                "vocab_size": int(c.vocab_size),
+                "total_ut_steps": int(c.total_ut_steps),
+                "cache_planes": int(c.cache_planes)}
+
+
 def serving_model(model_cfg):
     """The seam object for a model config (see the module docstring)."""
     if isinstance(model_cfg, GPT2Config):
@@ -180,4 +246,5 @@ def serving_model(model_cfg):
         f"neither a GPT2Config nor provides serving_model()")
 
 
-__all__ = ["GPT2Serving", "DeepseekV3Serving", "serving_model"]
+__all__ = ["GPT2Serving", "DeepseekV3Serving", "OuroServing",
+           "serving_model"]

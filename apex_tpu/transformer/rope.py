@@ -210,3 +210,16 @@ def rope_interleaved(x: jax.Array, positions: jax.Array,
     even, odd = x32[..., 0], x32[..., 1]
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], -1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_rotate_half(x: jax.Array, positions: jax.Array,
+                     inv_freq) -> jax.Array:
+    """Rotate the pairs ``(i, i + d / 2)`` of ``x``'s last axis by
+    ``positions * inv_freq[i]`` radians: the NeoX rotate-half pairing of
+    :func:`fused_rope` over the whole of ``d == 2 * len(inv_freq)``, each
+    row at its own position (``positions`` has ``x``'s leading shape up to
+    broadcasting, as in :func:`rope_interleaved`). Float32 inside, ``x``'s
+    dtype out."""
+    angle = positions.astype(_f32)[..., None] * jnp.asarray(inv_freq, _f32)
+    angle = jnp.concatenate([angle, angle], axis=-1)
+    return _apply_rope(x, jnp.cos(angle), jnp.sin(angle))

@@ -5,7 +5,9 @@ Two halves:
 - **The compiled text**, for a described v5e (no chip): decode,
   ``prefill_64`` and verify of a 2-layer engine at GPT-2 XL's widths and
   the first cell's geometry, and decode and ``prefill_64`` of a 2-layer
-  ``deepseek_v3`` engine at the second cell's, hold no ``copy`` of a
+  ``deepseek_v3`` engine at the second cell's, and decode and
+  ``prefill_64`` of a 2-layer ``ouro`` engine at the third cell's (8
+  planes, the pass loop a loop, the plane data), hold no ``copy`` of a
   pool's shape and alias every pool array, with every argument in the
   layout the runtime gives it. This is the guard that keeps the
   whole-pool copies from coming back with a later kernel. The GPT-2
@@ -87,6 +89,14 @@ def _cell_engine(model: str, program: str):
             compute_dtype=getattr(jnp, cfg["compute_dtype"]))
         weights = jax.eval_shape(GPT2(model_cfg).init, jax.random.PRNGKey(0),
                                  jnp.zeros((1, 8), jnp.int32))
+    elif model == "ouro":
+        from apex_tpu.models.ouro import OuroConfig
+        from reference import ouro as reference
+
+        cfg = dict(_cell_config("ouro-2.6b"), num_hidden_layers=2)
+        geo = cfg["serve"]
+        model_cfg = OuroConfig.from_dict(cfg)
+        weights = jax.eval_shape(lambda: reference.make_params(cfg, 0))
     else:
         from reference import deepseek_v3 as reference
 
@@ -127,7 +137,8 @@ def _compile_for(eng, weights, program: str, chip):
 
 COMPILED = [("gpt2-xl", "decode"), ("gpt2-xl", "prefill_64"),
             ("gpt2-xl", "verify"), ("deepseek_v3", "decode"),
-            ("deepseek_v3", "prefill_64")]
+            ("deepseek_v3", "prefill_64"), ("ouro", "decode"),
+            ("ouro", "prefill_64")]
 
 
 @pytest.mark.parametrize("model,program", COMPILED)
@@ -140,6 +151,20 @@ def test_compiled_programs_copy_no_pool_and_alias_every_pool_array(
     # the v5e's tiles pad a pool array, so the aliased bytes are at least
     # the logical ones
     assert facts["pool_aliased_bytes"] >= eng.kv_cache_bytes, facts
+    if model == "ouro":
+        # the pool rides the pass loop and the layer scan in place: no
+        # asynchronous copy of a pool's shape either (`pool_copies` does
+        # not count those), the loops are loops (passes, layers, and the
+        # cached keys' chunks inside), and a layer's weights are read
+        # where they lie in the stacked array
+        text = compiled.as_text()
+        pool = ",".join(map(str, eng.cache.k.shape))
+        assert eng.cache.k.shape[:2] == (8, 65)
+        assert not re.search(r"\[" + pool + r"\][^ ]* copy-(start|done)\(",
+                             text)
+        assert len(re.findall(r" while\(", text)) == 3
+        assert "bf16[2,2048,5632]" in text      # stacked, never unstacked
+        assert not re.search(r"bf16\[2048,5632\][^ ]* copy\(", text)
     if model != "gpt2-xl":
         return
     # attention over cached keys is one loop a layer (decode and the
