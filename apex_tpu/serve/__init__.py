@@ -15,8 +15,9 @@ one invariant:
   batch membership changes (a request finishes, another backfills its
   slot) never change a shape and therefore never trigger a recompile.
 - :mod:`~apex_tpu.serve.engine` — the AOT-lowered batched ``prefill``
-  (one ``[num_slots, bucket]`` forward a call) and the ONE jitted
-  ``decode_step``; incremental decode matches prefill to fp32 rounding,
+  (one forward a call over the rows it admits: ``[rows, bucket]``
+  where the batch fits the bucket's small program, else ``[num_slots,
+  bucket]``) and the ONE jitted ``decode_step``; incremental decode matches prefill to fp32 rounding,
   and slots are arithmetically isolated from each other.
 - :mod:`~apex_tpu.serve.scheduler` — continuous batching: an admission
   queue, slot assignment, per-request EOS/max-token termination, eviction

@@ -16,15 +16,19 @@ the pool"):
   backfill all happen by changing *values* (masks, lengths, page-table
   rows), so the jit cache holds exactly one entry for the life of the
   engine — asserted by tier-1 (``Engine.decode_traces``).
-- ``prefill`` — ONE batched ``[num_slots, bucket]`` forward (the same
-  ``gpt2_token_forward``, in its chunk form): the prompts' positions go
+- ``prefill`` — ONE batched forward over the rows a call admits (the
+  same ``gpt2_token_forward``, in its chunk form): ``[num_slots,
+  bucket]``, or the bucket's small ``[rows, bucket]`` program over a row
+  view of the cache where the batch fits it (:func:`prefill_rows`: the
+  row count follows from the engine; an engine whose full program is
+  small already keeps that one). The prompts' positions go
   through the layers together, so a call reads every weight once; their
   K/V take one masked scatter a layer (rows of non-admitted slots and
   of padding are dropped); a row attends causally over its own chunk
   and, after a prefix hit, over the cached head in a loop whose trip
   count is data; the logits are those of each admitted slot's last real
-  position. One compile per pow2 prompt-length bucket, one device run a
-  call. Prefill and decode are the same mathematics in another order of
+  position. At most two compiles per pow2 prompt-length bucket, one
+  device run a call. Prefill and decode are the same mathematics in another order of
   float32 sums (a batched product against a one-row product, a softmax
   over the chunk against ``block_k`` chunks of the cache), so an
   incrementally decoded token's logits match the same token's logits
@@ -197,6 +201,30 @@ class EngineConfig:
     # acceptance oracle is bit-exact; quant is tolerance-based — the
     # combination is refused until proven, the repo's standing policy).
     kv_quant: Optional[str] = None
+
+
+# The rows of a bucket's SMALL prefill program (``prefill_rows``). Two
+# numbers, neither a knob:
+# - a call over fewer positions than PREFILL_WEIGHT_BOUND_POSITIONS is
+#   bound by one read of the weights, so fewer rows would buy nothing: a
+#   v5e does 197e12 FLOP/s over 819e9 B/s = 240 FLOP a byte, and bf16
+#   weights give one FLOP a byte a position (PERF.md §6, PR 36);
+# - a steady tick admits a few slots of many (a mean of 1.1 of 16 and 2.7
+#   of 64, never over 3 and 5, in the benchmark's closed loops): an eighth
+#   of the slots holds all but the ramp's first admission.
+PREFILL_WEIGHT_BOUND_POSITIONS = 256
+PREFILL_SLOT_SHARE = 8
+
+
+def prefill_rows(num_slots: int, bucket: int) -> int:
+    """Rows of the small ``[rows, bucket]`` prefill program an engine of
+    ``num_slots`` slots compiles beside ``[num_slots, bucket]``, worked
+    out from what the engine is and never set: ``num_slots`` itself where
+    the full program is already within the weight-bound range (that
+    engine has ONE program a bucket)."""
+    return min(num_slots,
+               max(-(-PREFILL_WEIGHT_BOUND_POSITIONS // bucket),
+                   num_slots // PREFILL_SLOT_SHARE))
 
 
 class PoolLost(RuntimeError):
@@ -477,17 +505,30 @@ class Engine:
         cache = kv_cache.advance(cache, active)
         return next_tokens, logits, cache, rng, tuple(counters)
 
-    def _make_prefill(self, bucket: int):
-        """The ``prefill_<bucket>`` program: ONE ``[num_slots, bucket]``
-        forward. Every admitted prompt's positions go through the layers
-        together, their K/V take one masked write a layer at ``start +
-        t``, and each admitted slot's first token is drawn in-program
-        from the logits of its last real position. The cache is donated."""
-        keep = self.config.keep_prefill_logits
+    def _make_prefill(self, bucket: int, rows: Optional[int] = None):
+        """A ``prefill_<bucket>`` program: ONE ``[rows, bucket]`` forward.
+        Every admitted prompt's positions go through the layers together,
+        their K/V take one masked write a layer at ``start + t``, and
+        each admitted slot's first token is drawn in-program from the
+        logits of its last real position. The cache is donated.
 
-        def prefill_fn(weights, cache, tokens, admit, start, tail_lens,
-                       rng, pol=None):
-            self.prefill_traces += 1
+        ``rows`` of ``None`` or ``num_slots`` is the program over EVERY
+        slot (row ``i`` is slot ``i``). With fewer
+        (:func:`prefill_rows`) the program takes
+        one more argument, ``slots [rows] int32``: row ``i`` is slot
+        ``slots[i]`` (a padding row carries ``num_slots`` and ``admit ==
+        False``). It runs the same forward over a row view of the cache
+        (``kv_cache.slot_view``: those slots' ``lengths`` and page-table
+        rows beside the same donated pools; a forward reaches a slot
+        only through the two), then closes the view
+        (``kv_cache.close_view``) and scatters its rows' logits into
+        ``[num_slots, vocab]``, so both programs sample over, and
+        return, arrays a slot id indexes, and a sampled stream draws a
+        slot's token from the same bits whichever program ran."""
+        keep = self.config.keep_prefill_logits
+        b = self.config.num_slots
+
+        def chunk(weights, cache, tokens, admit, start, tail_lens):
             t = jnp.arange(bucket, dtype=jnp.int32)[None, :]
             # absolute position = start + chunk index: with a prefix hit
             # the call covers only the tail, attending back over the
@@ -503,12 +544,46 @@ class Engine:
                     logits, last[:, None, None], axis=1)[:, 0]
             else:
                 all_logits, last_logits = None, logits
-            cache = kv_cache.set_lengths(cache, admit, start + tail_lens)
+            return cache, last_logits, all_logits, tuple(counters)
+
+        def draw(last_logits, rng, pol):
             with jax.named_scope("sampling"):
                 rng, sub = jax.random.split(rng)
-                first_tokens = self._sample(last_logits, sub, pol)
+                return self._sample(last_logits, sub, pol), rng
+
+        if rows in (None, b):
+            def prefill_fn(weights, cache, tokens, admit, start, tail_lens,
+                           rng, pol=None):
+                self.prefill_traces += 1
+                cache, last_logits, all_logits, counters = chunk(
+                    weights, cache, tokens, admit, start, tail_lens)
+                cache = kv_cache.set_lengths(cache, admit,
+                                             start + tail_lens)
+                first_tokens, rng = draw(last_logits, rng, pol)
+                return (cache, first_tokens, last_logits, all_logits, rng,
+                        counters)
+
+            return _donating_jit(prefill_fn, 1)
+
+        def prefill_fn(weights, cache, slots, tokens, admit, start,
+                       tail_lens, rng, pol=None):
+            self.prefill_traces += 1
+            view, last_logits, all_logits, counters = chunk(
+                weights, kv_cache.slot_view(cache, slots), tokens, admit,
+                start, tail_lens)
+            cache = kv_cache.close_view(cache, view, slots, admit,
+                                        start + tail_lens)
+            # a padding row's slot id is out of range: dropped
+            last_logits = jnp.zeros(
+                (b,) + last_logits.shape[1:], last_logits.dtype
+            ).at[slots].set(last_logits, mode="drop")
+            if keep:
+                all_logits = jnp.zeros(
+                    (bucket, b) + all_logits.shape[2:], all_logits.dtype
+                ).at[:, slots].set(all_logits, mode="drop")
+            first_tokens, rng = draw(last_logits, rng, pol)
             return (cache, first_tokens, last_logits, all_logits, rng,
-                    tuple(counters))
+                    counters)
 
         return _donating_jit(prefill_fn, 1)
 
@@ -596,14 +671,31 @@ class Engine:
         return args + ((self._policy_args(),)
                        if self._policy is not None else ())
 
-    def _prefill_args(self, bucket: int):
+    def _prefill_args(self, bucket: int, rows: Optional[int] = None):
         b = self.config.num_slots
-        args = (self._weights, self.cache,
-                jnp.zeros((b, bucket), jnp.int32),
-                jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b,), jnp.int32), self.rng)
+        r = rows or b
+        args = (self._weights, self.cache) \
+            + (() if r == b else (jnp.zeros((r,), jnp.int32),)) \
+            + (jnp.zeros((r, bucket), jnp.int32),
+               jnp.zeros((r,), bool), jnp.zeros((r,), jnp.int32),
+               jnp.zeros((r,), jnp.int32), self.rng)
         return args + ((self._policy_args(),)
                        if self._policy is not None else ())
+
+    def _prefill_key(self, bucket: int, rows: int):
+        """Where a bucket's program of ``rows`` rows lives in
+        ``_prefill_jits``, ``_prefill_aot``, ``_prefill_lowered`` and
+        ``_pool_facts``: under the bucket itself for the one over every
+        slot, under ``(bucket, rows)`` for the small one."""
+        return bucket if rows == self.config.num_slots else (bucket, rows)
+
+    def _prefill_programs(self, bucket: int) -> Dict[Any, int]:
+        """``{key: rows}`` of a bucket's programs: the one over every
+        slot and, where :func:`prefill_rows` gives fewer rows than
+        slots, the small one."""
+        b = self.config.num_slots
+        return {self._prefill_key(bucket, rows): rows
+                for rows in (b, prefill_rows(b, bucket))}
 
     def _verify_args(self):
         b = self.config.num_slots
@@ -645,21 +737,23 @@ class Engine:
                 **module_facts(self._decode_lowered.as_text()))
         for bucket in prompt_buckets:
             bucket = pow2_ceil(int(bucket))
-            if bucket not in self._prefill_aot:
+            for key, rows in self._prefill_programs(bucket).items():
+                if key in self._prefill_aot:
+                    continue
                 fn = self._prefill_jits.setdefault(
-                    bucket, self._make_prefill(bucket))
+                    key, self._make_prefill(bucket, rows))
                 # retained like _decode_lowered: cost_ledger() prices
                 # prefill buckets from the saved lowering — after a
                 # reset()/warm restart there is nothing to re-trace
-                lowered = fn.lower(*self._prefill_args(bucket))
-                self._prefill_lowered[bucket] = lowered
-                self._prefill_aot[bucket] = lowered.compile()
-                self._pool_facts[bucket] = kv_cache.pool_facts(
-                    self._prefill_aot[bucket], self.cache)
+                lowered = fn.lower(*self._prefill_args(bucket, rows))
+                self._prefill_lowered[key] = lowered
+                self._prefill_aot[key] = lowered.compile()
+                self._pool_facts[key] = kv_cache.pool_facts(
+                    self._prefill_aot[key], self.cache)
                 publish_compiled_memory(
-                    "serve_prefill", self._prefill_aot[bucket],
+                    "serve_prefill", self._prefill_aot[key],
                     bucket=bucket, num_slots=self.config.num_slots,
-                    max_len=self.max_len,
+                    rows=rows, max_len=self.max_len,
                     **module_facts(lowered.as_text()))
         if self._spec_k and self._verify_aot is None:
             # retained like _decode_lowered: cost_ledger() prices the
@@ -908,12 +1002,18 @@ class Engine:
                 cacheable: Optional[Dict[int, int]] = None):
         """Insert ``{slot: prompt token ids}`` in one compiled call.
 
-        Pads every prompt to the shared pow2 bucket, runs the batched
-        ``[num_slots, bucket]`` forward (rows of non-target slots and of
-        padding are computed, discarded, and written nowhere), and
-        samples each admitted slot's first generated token. Returns
-        ``(first_tokens [B], last_logits [B, vocab], all_logits [P, B,
-        vocab] | None)``; only the admitted slots' rows are meaningful.
+        Pads every prompt to the shared pow2 bucket and runs ONE batched
+        forward over the rows it admits: the bucket's ``[rows, bucket]``
+        program where the batch fits its rows (:func:`prefill_rows`; an
+        admission then costs no forward over the slots that go on
+        decoding), else ``[num_slots, bucket]`` (rows of non-target
+        slots and of padding are computed, discarded, and written
+        nowhere in either), and samples each admitted slot's first
+        generated token. An engine whose full program is small already
+        has only that one. Returns ``(first_tokens [B], last_logits [B,
+        vocab], all_logits [P, B, vocab] | None)`` with ``B ==
+        num_slots`` whichever program ran, indexed by slot id; only the
+        admitted slots' rows are meaningful.
 
         ``budgets[slot]`` (default: worst case ``max_len -
         len(prompt)``) sizes the page reservation — pages for the whole
@@ -946,25 +1046,42 @@ class Engine:
                 starts, tails, new_pages = self._plan_prefill(prompts,
                                                               budgets)
             bucket = pow2_ceil(max(len(t) for t in tails.values()))
-            with annotate("apex.prefill.launch", bucket=bucket, slots=b,
+            # the smaller of the bucket's programs that holds the batch
+            # (an engine with one program has rows == num_slots)
+            rows = prefill_rows(b, bucket)
+            if len(prompts) > rows:
+                rows = b
+            key = self._prefill_key(bucket, rows)
+            with annotate("apex.prefill.launch", bucket=bucket, slots=rows,
                           real_positions=sum(len(t) for t in tails.values()),
                           hit_tokens=int(starts.sum()), new_pages=new_pages,
-                          **self._pool_facts.get(bucket, {})):
-                tokens = np.zeros((b, bucket), np.int32)
-                admit = np.zeros((b,), bool)
-                lens = np.zeros((b,), np.int32)
-                for slot, toks in tails.items():
-                    tokens[slot, :len(toks)] = np.asarray(toks, np.int32)
-                    admit[slot] = True
-                    lens[slot] = len(toks)
+                          **self._pool_facts.get(key, {})):
+                # row i of the call is slot slots[i]: every slot in the
+                # program over all of them, the admitted ones alone in
+                # the small one, whose padding rows name no slot
+                order = range(b) if rows == b else sorted(tails)
+                slots = np.full((rows,), b, np.int32)
+                slots[:len(order)] = order
+                tokens = np.zeros((rows, bucket), np.int32)
+                admit = np.zeros((rows,), bool)
+                start = np.zeros((rows,), np.int32)
+                lens = np.zeros((rows,), np.int32)
+                for row, slot in enumerate(order):
+                    toks = tails.get(slot)
+                    if toks is not None:
+                        tokens[row, :len(toks)] = np.asarray(toks, np.int32)
+                        admit[row] = True
+                        start[row] = starts[slot]
+                        lens[row] = len(toks)
 
-                fn = self._prefill_aot.get(bucket)
+                fn = self._prefill_aot.get(key)
                 if fn is None:
                     fn = self._prefill_jits.setdefault(
-                        bucket, self._make_prefill(bucket))
-                args = (self._weights, self.cache, jnp.asarray(tokens),
-                        jnp.asarray(admit), jnp.asarray(starts),
-                        jnp.asarray(lens), self.rng)
+                        key, self._make_prefill(bucket, rows))
+                args = (self._weights, self.cache) \
+                    + (() if rows == b else (jnp.asarray(slots),)) \
+                    + (jnp.asarray(tokens), jnp.asarray(admit),
+                       jnp.asarray(start), jnp.asarray(lens), self.rng)
                 if self._policy is not None:
                     args += (self._policy_args(),)
                 (self.cache, first, last_logits, all_logits, self.rng,
@@ -975,10 +1092,10 @@ class Engine:
             with annotate("apex.prefill.fetch"):
                 first_np = np.asarray(first)
             self._note_counters("apex.prefill", counters, int(lens.sum()))
-            self.last_tokens = np.where(admit, first_np, self.last_tokens)
-            full_lens = starts + lens
-            self._host_lengths = np.where(admit, full_lens,
-                                          self._host_lengths)
+            live = slots[admit]
+            last, lengths = self.last_tokens.copy(), self._host_lengths.copy()
+            last[live], lengths[live] = first_np[live], (start + lens)[admit]
+            self.last_tokens, self._host_lengths = last, lengths
             if self.prefix is not None:
                 ps = self.page_size
                 with annotate("apex.prefill.index"):
@@ -1379,7 +1496,9 @@ class Engine:
         :meth:`decode_collectives` — producing them first if needed,
         never re-tracing (``decode_traces`` stays at 1), and surviving
         ``reset()``/warm restarts, which keep the compiled artifacts.
-        Entries: ``decode`` plus ``prefill_<bucket>`` for every bucket
+        Entries: ``decode`` plus ``prefill_<bucket>`` (and the small
+        program's ``prefill_<bucket>_rows<rows>`` where the engine has
+        one) for every bucket
         already compiled or requested via ``prompt_buckets``, plus
         ``verify`` when speculation is armed (``spec_draft_len >= 1``;
         a one-token engine's ledger is byte-identical to PR 17's —
@@ -1395,10 +1514,13 @@ class Engine:
             self.aot_compile(prompt_buckets)
         execs = {"decode": costs.executable_record(
             self._decode_lowered, self._decode_aot)}
-        for bucket in sorted(self._prefill_lowered):
-            execs[f"prefill_{bucket}"] = costs.executable_record(
-                self._prefill_lowered[bucket],
-                self._prefill_aot.get(bucket))
+        for bucket in sorted(k for k in self._prefill_lowered
+                             if isinstance(k, int)):
+            for key, rows in self._prefill_programs(bucket).items():
+                name = f"prefill_{bucket}" + (
+                    "" if key == bucket else f"_rows{rows}")
+                execs[name] = costs.executable_record(
+                    self._prefill_lowered[key], self._prefill_aot.get(key))
         if self._spec_k:
             execs["verify"] = costs.executable_record(
                 self._verify_lowered, self._verify_aot)

@@ -116,6 +116,40 @@ def set_lengths(cache, mask: jax.Array, new_lengths: jax.Array):
                           cache.lengths).astype(jnp.int32))
 
 
+# A row view: the cache as a call over ``rows`` of its slots sees it.
+# A forward reaches a slot only through ``lengths`` and ``page_table``,
+# each indexed by the call's row (``write_rows``, ``write_latent``,
+# ``attention.chunk_attention``, ``latent_chunk_attention``,
+# ``_fold_cached_chunks``), so the two gathered at the slots' ids, beside
+# the SAME pool arrays, are a cache of ``rows`` slots to it: the small
+# prefill program runs the unchanged forward over the slots it admits.
+
+
+def slot_view(cache, slots: jax.Array):
+    """``cache`` with the ``lengths`` and page-table rows of ``slots``
+    (``[rows]`` int32) in the slots' place. An id out of range (a padding
+    row's) reads the last slot's; that row must write nothing."""
+    at = jnp.clip(slots.astype(jnp.int32), 0, cache.num_slots - 1)
+    return cache.replace(lengths=cache.lengths[at],
+                         page_table=cache.page_table[at])
+
+
+def close_view(cache, view, slots: jax.Array, mask: jax.Array,
+               new_lengths: jax.Array):
+    """The whole cache after a call over ``view = slot_view(cache,
+    slots)``: the view's pool arrays (what the call wrote), ``cache``'s
+    page table as it came, and ``cache``'s ``lengths`` with row ``i``'s
+    ``new_lengths[i]`` at slot ``slots[i]`` where ``mask[i]``
+    (:func:`set_lengths` through the view). The other rows are sent out
+    of range and dropped, so no slot is written twice; the masked rows'
+    ids must be distinct."""
+    at = jnp.where(mask, slots.astype(jnp.int32), cache.num_slots)
+    return view.replace(
+        page_table=cache.page_table,
+        lengths=cache.lengths.at[at].set(
+            new_lengths.astype(jnp.int32), mode="drop"))
+
+
 # host-callable eviction: ``lengths`` alone goes through a (mask-shaped)
 # op, compiled once per slot count — freeing a slot between decode steps
 # cannot recompile anything, and the pool's arrays never enter a program

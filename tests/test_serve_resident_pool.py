@@ -7,7 +7,9 @@ Two halves:
   the first cell's geometry, and decode and ``prefill_64`` of a 2-layer
   ``deepseek_v3`` engine at the second cell's, and decode and
   ``prefill_64`` of a 2-layer ``ouro`` engine at the third cell's (8
-  planes, the pass loop a loop, the plane data), hold no ``copy`` of a
+  planes, the pass loop a loop, the plane data), and the small
+  ``[rows, 64]`` prefill program of those two (8 rows of 64 slots, 4 of
+  16), hold no ``copy`` of a
   pool's shape and alias every pool array, with every argument in the
   layout the runtime gives it. This is the guard that keeps the
   whole-pool copies from coming back with a later kernel. The GPT-2
@@ -129,16 +131,27 @@ def _compile_for(eng, weights, program: str, chip):
         "decode": (lambda: eng._decode, eng._decode_args),
         "prefill_64": (lambda: eng._make_prefill(64),
                        lambda: eng._prefill_args(64)),
+        # the bucket's small program, over the rows a tick admits
+        "prefill_64_rows": (
+            lambda: eng._make_prefill(64, _small_rows(eng)),
+            lambda: eng._prefill_args(64, _small_rows(eng))),
         "verify": (lambda: eng._verify, eng._verify_args)}[program]
     _, _, *data = args()
     return jitted().lower(on_chip(weights), on_chip(eng.cache),
                           *on_chip(data)).compile()
 
 
+def _small_rows(eng) -> int:
+    rows = engine_mod.prefill_rows(eng.config.num_slots, 64)
+    assert rows < eng.config.num_slots      # the cell HAS a small program
+    return rows
+
+
 COMPILED = [("gpt2-xl", "decode"), ("gpt2-xl", "prefill_64"),
             ("gpt2-xl", "verify"), ("deepseek_v3", "decode"),
             ("deepseek_v3", "prefill_64"), ("ouro", "decode"),
-            ("ouro", "prefill_64")]
+            ("ouro", "prefill_64"), ("deepseek_v3", "prefill_64_rows"),
+            ("ouro", "prefill_64_rows")]
 
 
 @pytest.mark.parametrize("model,program", COMPILED)
@@ -151,6 +164,22 @@ def test_compiled_programs_copy_no_pool_and_alias_every_pool_array(
     # the v5e's tiles pad a pool array, so the aliased bytes are at least
     # the logical ones
     assert facts["pool_aliased_bytes"] >= eng.kv_cache_bytes, facts
+    if program == "prefill_64_rows":
+        # the row view gathers two small int32 arrays and hands the
+        # forward the SAME pools: the second cell's latent pool (1 089
+        # pages) and the third's planes (65) ride it in place, with no
+        # asynchronous copy of a pool's shape either, and the program
+        # computes `rows` slots, not all of them
+        text = compiled.as_text()
+        rows = _small_rows(eng)
+        assert (model, rows, eng.cache.num_pages) in (
+            ("deepseek_v3", 8, 1089), ("ouro", 4, 65))
+        for name in kv_cache._token_arrays(eng.cache):
+            pool = ",".join(map(str, getattr(eng.cache, name).shape))
+            assert not re.search(
+                r"\[" + pool + r"\][^ ]* copy-(start|done)\(", text), name
+        chunks = set(re.findall(r"s32\[(\d+),64\][^ ]* parameter\(", text))
+        assert chunks == {str(rows)}, chunks    # the tokens: `rows` chunks
     if model == "ouro":
         # the pool rides the pass loop and the layer scan in place: no
         # asynchronous copy of a pool's shape either (`pool_copies` does
@@ -162,7 +191,10 @@ def test_compiled_programs_copy_no_pool_and_alias_every_pool_array(
         assert eng.cache.k.shape[:2] == (8, 65)
         assert not re.search(r"\[" + pool + r"\][^ ]* copy-(start|done)\(",
                              text)
-        assert len(re.findall(r" while\(", text)) == 3
+        # (a fourth in the small program: its rows' logits go into
+        # `[num_slots, vocab]` by a scatter of `rows` trips)
+        assert len(re.findall(r" while\(", text)) \
+            == 3 + (program == "prefill_64_rows")
         assert "bf16[2,2048,5632]" in text      # stacked, never unstacked
         assert not re.search(r"bf16\[2048,5632\][^ ]* copy\(", text)
     if model != "gpt2-xl":
