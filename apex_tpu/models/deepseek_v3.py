@@ -174,6 +174,16 @@ def _dot(x, w):
                    preferred_element_type=_f32).astype(w.dtype)
 
 
+def _dot32(x, w):
+    """A product in the weights' dtype, its float32 accumulator kept."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_f32)
+
+
+def head_gate(u, w):
+    """``sigmoid(u W)`` a row and head, float32 ``[rows, heads, 1]``."""
+    return jax.nn.sigmoid(_dot32(u, w))[..., None]
+
+
 def expert_layer(cfg: DeepseekV3Config, blk, u, row_mask):
     """``shared(u) + sum over the chosen experts held here`` for the
     normalised rows ``u``; float32 ``[rows, hidden]`` and the routing
@@ -201,7 +211,11 @@ def _mla(cfg: DeepseekV3Config, blk, x, cache, layer, pos, write_mask,
     """One layer's latent attention for the rows ``x [rows, hidden]``
     whose positions are ``pos`` (``[slots]`` or ``[slots, T]``): the
     attention output before the residual, and the cache with the rows'
-    latents appended."""
+    latents appended. ``layer`` is the cache's plane. What the block
+    holds says which variant it is: with no ``q_a`` the query is ``u W_q``
+    directly (``q_lora_rank`` null), and with a ``head_gate`` every
+    head's output is scaled by ``sigmoid(u W_gate)`` before ``o``
+    (``models/ling_hybrid.py``)."""
     from apex_tpu.serve.attention import (latent_chunk_attention,
                                           latent_decode_attention)
     from apex_tpu.serve.kv_cache import write_latent
@@ -214,7 +228,8 @@ def _mla(cfg: DeepseekV3Config, blk, x, cache, layer, pos, write_mask,
     with jax.named_scope("ln_qkv"):
         u = rms_norm(x, blk["attn_norm"], eps)
         q = _dot(rms_norm(_dot(u, blk["q_a"]), blk["q_norm"], eps),
-                 blk["q_b"]).reshape(-1, h, nope + rope)
+                 blk["q_b"]) if "q_a" in blk else _dot(u, blk["q"])
+        q = q.reshape(-1, h, nope + rope)
         q_nope = q[..., :nope]
         q_rope = rope_interleaved(q[..., nope:], flat_pos[:, None], inv_freq)
         kv = _dot(u, blk["kv_a"])
@@ -249,6 +264,9 @@ def _mla(cfg: DeepseekV3Config, blk, x, cache, layer, pos, write_mask,
                 row[:, rank:].reshape(b, t, rope),
                 kvx[..., nope:].reshape(b, t, h, vd), w_kb, w_vb, cache,
                 layer, pos[:, 0], scale=c.softmax_scale)
+        if "head_gate" in blk:
+            o = (o.reshape(-1, h, vd) * head_gate(u, blk["head_gate"])
+                 ).astype(q.dtype)
         with jax.named_scope("attn_proj"):
             out = _dot(o.reshape(-1, h * vd), blk["o"])
     return out, cache

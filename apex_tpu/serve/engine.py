@@ -985,16 +985,19 @@ class Engine:
                     self._host_lengths, act_np, self.block_k, key_chunks),
                 "key_chunks": key_chunks}
 
-    def _note_counters(self, span: str, counters, real_rows: int) -> None:
+    def _note_counters(self, span: str, counters, real_rows: int,
+                       slots: int) -> None:
         """Where the model's forward returned counters (an expert
         model's routing, a looped model's passes), fetch them, now that
         the call's tokens are here, and leave them as the attributes of
         ``<span>.<the model's counters_span>``, inside the call's own
-        span."""
+        span: a call over ``real_rows`` real positions of ``slots``
+        slots."""
         if counters:
             with annotate(f"{span}.{self.model.counters_span}",
                           **self.model.call_counters(
-                              np.asarray(counters[0]), real_rows)):
+                              np.asarray(counters[0]), real_rows, slots,
+                              span == "apex.prefill")):
                 pass
 
     def prefill(self, prompts: Dict[int, Sequence[int]], *,
@@ -1091,7 +1094,8 @@ class Engine:
             self.prefill_scanned_tokens += int(bucket)
             with annotate("apex.prefill.fetch"):
                 first_np = np.asarray(first)
-            self._note_counters("apex.prefill", counters, int(lens.sum()))
+            self._note_counters("apex.prefill", counters, int(lens.sum()),
+                                len(prompts))
             live = slots[admit]
             last, lengths = self.last_tokens.copy(), self._host_lengths.copy()
             last[live], lengths[live] = first_np[live], (start + lens)[admit]
@@ -1217,7 +1221,7 @@ class Engine:
             with annotate("apex.decode_step.fetch"):
                 next_np = np.asarray(next_tokens)
             self._note_counters("apex.decode_step", counters,
-                                int(act_np.sum()))
+                                int(act_np.sum()), int(act_np.sum()))
             self.last_tokens = np.where(act_np, next_np, self.last_tokens)
             self._host_lengths = self._host_lengths + act_np
             return next_np, logits
@@ -1347,9 +1351,9 @@ class Engine:
         export is a read, not a use — it must not reorder the donor's
         LRU. Empty when there is no prefix index / no indexed prefix.
         """
+        self._refuse_page_migration()
         if self.prefix is None:
             return []
-        self._refuse_page_migration()
         out = []
         for h, page in self.prefix.lookup(tokens, touch=False):
             k_np = np.asarray(jax.device_get(self.cache.k[:, page]))
@@ -1394,12 +1398,12 @@ class Engine:
         prefix pages, and the next admission of the migrated prompt
         shares them read-only exactly as a local prefix hit.
         """
+        self._refuse_page_migration()
         if self.prefix is None:
             raise ValueError(
                 "import_prefix_pages needs an engine with page_size and "
                 "prefix_cache=True (page migration lands in the prefix "
                 "index)")
-        self._refuse_page_migration()
         shape = (self.model.cache_planes, self.page_size) \
             + tuple(self.cache.k.shape[3:])
         stats = {"installed": 0, "duplicate": 0, "no_capacity": 0}
@@ -1446,6 +1450,11 @@ class Engine:
         return stats
 
     def _refuse_page_migration(self) -> None:
+        if isinstance(self.cache, kv_cache.HybridCache):
+            raise ValueError(
+                f"{self.model.name} pages do not migrate: a page is of no "
+                f"use without the recurrent state at its boundary, and "
+                f"export/import move pages alone")
         if not hasattr(self.cache, "k"):
             raise ValueError(
                 f"{self.model.name} pages do not migrate: export/import, "

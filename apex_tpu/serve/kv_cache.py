@@ -27,7 +27,16 @@ that takes the pytree compiles exactly once:
   ``page_table`` and ``lengths``, so the allocator, the prefix index and
   every length mutator below serve it unchanged.
 
-**A token's row is whole tiles** in both: the ``heads`` axis of
+- :class:`HybridCache` — the cache of a model whose layers are of two
+  kinds: a latent pool with ONE PLANE A LATENT-ATTENTION LAYER (``rows``,
+  as :class:`PagedLatentCache` has them) and, for every layer that keeps
+  a recurrent state instead of rows a token, a FIXED-SIZE STATE A SLOT
+  (``state`` float32 ``[state layers, num_slots, heads, key_dim,
+  value_dim]`` and ``conv``, the short convolution's tail). The state has
+  no page axis: it is indexed by slot, so a row view of this cache
+  carries the slots' ids too (``slots``; :func:`slot_view`).
+
+**A token's row is whole tiles** in both pools: the ``heads`` axis of
 ``k``/``v`` (and of their scale planes) is allocated as whole groups of
 8 sublanes (:func:`padded_heads`: 25 heads lie in 32, the rest zeros:
 :func:`pad_heads`; attention runs over all of them and drops the
@@ -117,12 +126,16 @@ def set_lengths(cache, mask: jax.Array, new_lengths: jax.Array):
 
 
 # A row view: the cache as a call over ``rows`` of its slots sees it.
-# A forward reaches a slot only through ``lengths`` and ``page_table``,
-# each indexed by the call's row (``write_rows``, ``write_latent``,
-# ``attention.chunk_attention``, ``latent_chunk_attention``,
-# ``_fold_cached_chunks``), so the two gathered at the slots' ids, beside
-# the SAME pool arrays, are a cache of ``rows`` slots to it: the small
-# prefill program runs the unchanged forward over the slots it admits.
+# A forward reaches a slot's TOKENS only through ``lengths`` and
+# ``page_table``, each indexed by the call's row (``write_rows``,
+# ``write_latent``, ``attention.chunk_attention``,
+# ``latent_chunk_attention``, ``_fold_cached_chunks``), so the two gathered
+# at the slots' ids, beside the SAME pool arrays, are a cache of ``rows``
+# slots to it: the small prefill program runs the unchanged forward over
+# the slots it admits. A slot's recurrent STATE (:class:`HybridCache`) is
+# indexed by the slot itself, so a view of that cache also carries the
+# ids (``slots``), which :func:`read_state` and :func:`write_state` index
+# by; the whole cache carries none (row ``i`` is slot ``i``).
 
 
 def slot_view(cache, slots: jax.Array):
@@ -130,8 +143,17 @@ def slot_view(cache, slots: jax.Array):
     (``[rows]`` int32) in the slots' place. An id out of range (a padding
     row's) reads the last slot's; that row must write nothing."""
     at = jnp.clip(slots.astype(jnp.int32), 0, cache.num_slots - 1)
-    return cache.replace(lengths=cache.lengths[at],
+    view = cache.replace(lengths=cache.lengths[at],
                          page_table=cache.page_table[at])
+    if isinstance(cache, HybridCache):
+        # a view of a view: the ids of the rows' ids; a row out of range
+        # stays out of range of the state's slot axis
+        inside = (slots >= 0) & (slots < cache.num_slots)
+        ids = slots.astype(jnp.int32) if cache.slots is None \
+            else cache.slots[at]
+        view = view.replace(
+            slots=jnp.where(inside, ids, cache.state.shape[1]))
+    return view
 
 
 def close_view(cache, view, slots: jax.Array, mask: jax.Array,
@@ -144,10 +166,13 @@ def close_view(cache, view, slots: jax.Array, mask: jax.Array,
     of range and dropped, so no slot is written twice; the masked rows'
     ids must be distinct."""
     at = jnp.where(mask, slots.astype(jnp.int32), cache.num_slots)
-    return view.replace(
+    closed = view.replace(
         page_table=cache.page_table,
         lengths=cache.lengths.at[at].set(
             new_lengths.astype(jnp.int32), mode="drop"))
+    if isinstance(cache, HybridCache):
+        closed = closed.replace(slots=cache.slots)
+    return closed
 
 
 # host-callable eviction: ``lengths`` alone goes through a (mask-shaped)
@@ -411,11 +436,18 @@ def _token_arrays(cache) -> tuple:
                          if cache.k_scale is not None else ())
 
 
+def _slot_arrays(cache) -> tuple:
+    """Names of the cache's arrays that hold a fixed-size state a slot
+    (axis 1 is the slot; no page axis): none but a
+    :class:`HybridCache`'s."""
+    return ("state", "conv") if isinstance(cache, HybridCache) else ()
+
+
 def cache_bytes(cache) -> int:
-    """Resident bytes of the cache's token storage (scale planes
-    included)."""
+    """Resident bytes of the cache's storage: the tokens' (scale planes
+    included) and the per-slot states'."""
     return sum(int(getattr(cache, name).nbytes)
-               for name in _token_arrays(cache))
+               for name in _token_arrays(cache) + _slot_arrays(cache))
 
 
 # ------------------------------------------------------ the resident pool
@@ -456,9 +488,10 @@ def pool_facts(compiled, cache) -> dict:
     ``pool_aliased_bytes``, the bytes of its arguments that alias a
     result (at least :func:`cache_bytes` once every pool array is written
     in place), and ``pool_copies``, the ``copy`` instructions whose shape
-    is a whole pool array's, or a rank's whole shard of one (none, where
-    the pool is resident)."""
-    arrays = [getattr(cache, name) for name in _token_arrays(cache)]
+    is a whole pool array's (a per-slot state array counts as one), or a
+    rank's whole shard of one (none, where the pool is resident)."""
+    arrays = [getattr(cache, name)
+              for name in _token_arrays(cache) + _slot_arrays(cache)]
     # a program over the serving mesh names a rank's shard of the array
     dims = {",".join(map(str, a.sharding.shard_shape(a.shape)))
             for a in arrays}
@@ -479,6 +512,11 @@ def copy_page(cache, src, dst):
     paged array of the cache (K and V with their scale planes, or the
     latent rows) — the copy-on-write that gives a slot its own writable
     copy of a shared prefix page whose tail it must append into."""
+    if _slot_arrays(cache):
+        raise ValueError(
+            "copy_page: a page of this cache is shared with nothing, "
+            "because the slot's recurrent state at the page's boundary "
+            "is not kept (the engine refuses prefix_cache for the model)")
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     return cache.replace(**{
@@ -598,3 +636,77 @@ def write_latent(cache: PagedLatentCache, layer: int, rows: jax.Array,
     return cache.replace(rows=cache.rows.at[index].set(
         pad_last(rows.astype(cache.rows.dtype), cache.rows.shape[-1]),
         mode="drop"))
+
+
+# ------------------------- pages beside a state a slot (hybrid models)
+
+
+@flax.struct.dataclass
+class HybridCache(PagedLatentCache):
+    """Pytree of the cache of a model that mixes latent-attention layers
+    with recurrent (linear-attention) ones. ``rows`` has one plane a
+    LATENT layer; ``lengths`` and ``page_table`` mean what they mean in
+    :class:`PagedLatentCache`, so the allocator and every length mutator
+    serve it unchanged. Each recurrent layer keeps, a slot, a float32
+    state and the last ``taps - 1`` inputs of its short convolution; both
+    are overwritten in place a call and never grow with the context.
+
+    ``slots`` is ``None`` on the whole cache (row ``i`` of a call is slot
+    ``i``) and the rows' slot ids on a row view (:func:`slot_view`), where
+    an id out of range names no slot: it reads the last one's state and
+    its write is dropped."""
+
+    # [state layers, num_slots, heads, key_dim, value_dim] float32
+    state: jax.Array
+    # [state layers, num_slots, (taps - 1) * channels]: step t - taps + 1
+    # first, each step's channels together
+    conv: jax.Array
+    slots: Optional[jax.Array] = None
+
+
+def init_hybrid_cache(planes: int, state_layers: int, num_slots: int,
+                      max_len: int, page_size: int, num_pages: int,
+                      width: int, heads: int, key_dim: int, value_dim: int,
+                      conv_width: int,
+                      dtype: Any = jnp.float32) -> HybridCache:
+    """Allocate an empty hybrid cache: :func:`init_paged_latent_cache`'s
+    pool with ``planes`` planes, and ``state_layers`` zero states and
+    convolution tails (``conv_width = (taps - 1) * channels``) a slot."""
+    pool = init_paged_latent_cache(planes, num_slots, max_len, page_size,
+                                   num_pages, width, dtype)
+    return HybridCache(
+        rows=pool.rows, lengths=pool.lengths, page_table=pool.page_table,
+        state=jnp.zeros((state_layers, num_slots, heads, key_dim,
+                         value_dim), jnp.float32),
+        conv=jnp.zeros((state_layers, num_slots, conv_width), dtype))
+
+
+def read_state(cache: HybridCache, layer: int):
+    """``(state [rows, heads, key_dim, value_dim], conv [rows,
+    conv_width])`` of recurrent layer ``layer`` for the call's rows: the
+    layer's whole slice where the cache is whole, the rows' slots
+    gathered where it is a view."""
+    if cache.slots is None:
+        return cache.state[layer], cache.conv[layer]
+    at = jnp.clip(cache.slots, 0, cache.state.shape[1] - 1)
+    return cache.state[layer, at], cache.conv[layer, at]
+
+
+def write_state(cache: HybridCache, layer: int, state: jax.Array,
+                conv: jax.Array, mask: jax.Array) -> HybridCache:
+    """Recurrent layer ``layer``'s state and convolution tail after the
+    call, for the rows under ``mask [rows]``; every other slot's stay
+    bit for bit (a masked-off row of the whole cache writes back what
+    was there; one of a view is sent out of range and dropped, as
+    :func:`write_latent` drops a row)."""
+    conv = conv.astype(cache.conv.dtype)
+    if cache.slots is None:
+        return cache.replace(
+            state=cache.state.at[layer].set(jnp.where(
+                mask[:, None, None, None], state, cache.state[layer])),
+            conv=cache.conv.at[layer].set(jnp.where(
+                mask[:, None], conv, cache.conv[layer])))
+    at = jnp.where(mask, cache.slots, cache.state.shape[1])
+    return cache.replace(
+        state=cache.state.at[layer, at].set(state, mode="drop"),
+        conv=cache.conv.at[layer, at].set(conv, mode="drop"))

@@ -9,7 +9,9 @@ model gives it, through one object:
 - ``cache_planes``: the planes of its cache, the leading axis of every
   pool array and of a migrated page's payload. ``n_layer`` counts layers
   of WEIGHTS; the two are equal unless the model runs its layers more
-  than once a token, each pass with planes of its own (``ouro``);
+  than once a token, each pass with planes of its own (``ouro``: more
+  planes than layers), or keeps rows a token in only some of its layers
+  (``ling_hybrid``: fewer, one a latent-attention layer);
 - ``refuse(config)``: a build-time ``ValueError`` for every engine
   mode the model has no mechanism for, naming the mechanism;
 - ``block_k(max_len, page_size, config, tp)``: the decode-attention
@@ -22,16 +24,23 @@ model gives it, through one object:
 - ``init_cache(num_slots, max_len, page_size, num_pages, kv_quant, tp)``:
   its cache pytree, a paged pool with ``lengths`` and ``page_table`` as
   ``serve/kv_cache.py`` and ``serve/paging.py`` expect them, allocated
-  for ``tp`` ranks;
+  for ``tp`` ranks. A cache may hold, beside its pages, a fixed-size
+  state a slot for layers that keep no rows a token
+  (``kv_cache.HybridCache``): the engine's row view then carries the
+  slots' ids, every call that returns the cache updates the state in
+  place, and admission starts a slot from a zero state inside the
+  prefill program (eviction moves ``lengths`` alone);
 - ``forward(weights, cache, tokens, positions, mask, logits_at=None, *,
   block_k, kv_quant, final_scope)``: its token forward in the two shapes
   (``[slots]``: decode and the verify scan's body; ``[slots, T]`` with
   ``logits_at``: prefill). Returns ``(logits, cache)``, or ``(logits,
   cache, counters)`` with ``counters`` a small int32 array the programs
   hand back beside their results; a model that returns them also gives
-  ``counters_span`` and ``call_counters(counters, real_rows)``: the name
-  and the attributes of the span ``apex.<call>.<counters_span>`` the
-  engine leaves them on (``routing``, ``loop``).
+  ``counters_span`` and ``call_counters(counters, real_rows, slots,
+  prefill)``: the name and the attributes of the span
+  ``apex.<call>.<counters_span>`` the engine leaves them on (``routing``,
+  ``loop``), for a call over ``real_rows`` real positions of ``slots``
+  slots (a decode step's two are equal), ``prefill`` or decode.
 
 ``serving_model(cfg)`` finds the object: a ``GPT2Config`` gets
 :class:`GPT2Serving`; any other config provides ``cfg.serving_model()``.
@@ -157,7 +166,8 @@ class DeepseekV3Serving:
         return deepseek_v3_token_forward(self.cfg, weights, cache, *data,
                                          final_scope=final_scope)
 
-    def call_counters(self, counters, real_rows: int) -> Dict[str, int]:
+    def call_counters(self, counters, real_rows: int, slots: int,
+                      prefill: bool) -> Dict[str, int]:
         """What an engine call's span says of its routing: the two
         counters the program returned, and what they are shares of."""
         c = self.cfg
@@ -217,7 +227,8 @@ class OuroServing(GPT2Serving):
 
         return ouro_token_forward(self.cfg, weights, cache, *data, **kw)
 
-    def call_counters(self, counters, real_rows: int) -> Dict[str, int]:
+    def call_counters(self, counters, real_rows: int, slots: int,
+                      prefill: bool) -> Dict[str, int]:
         """What an engine call's span says of its pass loop: the two
         counters the program returned, beside the rows they are over and
         the planes the call wrote."""
@@ -235,6 +246,84 @@ class OuroServing(GPT2Serving):
                 "cache_planes": int(c.cache_planes)}
 
 
+class LingHybridServing(DeepseekV3Serving):
+    """``ling_hybrid`` (``models/ling_hybrid.py``) as one rank of an
+    expert-parallel deployment, one chip: latent pages for its
+    latent-attention layers alone, and a recurrent state a slot for every
+    other layer (``kv_cache.HybridCache``)."""
+
+    name = "ling_hybrid"
+
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        self.cache_planes = cfg.mla_layers
+
+    def refuse(self, config) -> None:
+        missing = []
+        if config.prefix_cache:
+            missing.append("prefix_cache=True: a shared page is of no use "
+                           "without the recurrent state at its boundary, "
+                           "and no state is kept but a slot's newest")
+        if config.tp > 1:
+            missing.append(f"tp={config.tp}: there is no per-rank forward "
+                           f"that shards the recurrent state's heads "
+                           f"(serve/tp.py is GPT-2's)")
+        if config.spec_draft_len:
+            missing.append(f"spec_draft_len={config.spec_draft_len}: a "
+                           f"rejected draft is rolled back by set_lengths, "
+                           f"and a token folded into a recurrent state "
+                           f"cannot be taken out of it")
+        if config.kv_quant is not None:
+            missing.append(f"kv_quant={config.kv_quant!r}: the block-scale "
+                           f"codec scales one (token, head) vector; a "
+                           f"latent row has no head axis and a recurrent "
+                           f"state is no token")
+        if missing:
+            raise ValueError(
+                "ling_hybrid is not served with " + "; ".join(missing))
+
+    def init_cache(self, num_slots, max_len, page_size, num_pages, kv_quant,
+                   tp=1):
+        c = self.cfg
+        return kv_cache.init_hybrid_cache(
+            self.cache_planes, c.kda_layers, num_slots, max_len, page_size,
+            num_pages, c.latent_width, c.num_attention_heads, c.head_dim,
+            c.head_dim, (c.short_conv_kernel_size - 1) * c.conv_channels,
+            self.compute_dtype)
+
+    def forward(self, weights, cache, *data, block_k=None, kv_quant=None,
+                final_scope="sampling"):
+        from apex_tpu.models.ling_hybrid import ling_hybrid_token_forward
+
+        return ling_hybrid_token_forward(self.cfg, weights, cache, *data,
+                                         final_scope=final_scope)
+
+    def call_counters(self, counters, real_rows: int, slots: int,
+                      prefill: bool) -> Dict[str, int]:
+        """``DeepseekV3Serving``'s routing counters, and the recurrent
+        state the call moved: ``state_slots`` slots in each of the
+        recurrent layers, and the bytes of state and convolution tail it
+        read and wrote (a decode step reads and writes each once; a
+        prefill call starts from zero and only writes)."""
+        c = self.cfg
+        return dict(
+            super().call_counters(counters, real_rows, slots, prefill),
+            state_slots=slots,
+            state_bytes=(1 if prefill else 2) * slots * c.kda_layers
+            * c.state_bytes_per_slot)
+
+    def workload(self) -> Dict[str, Any]:
+        c = self.cfg
+        return {"n_layer": int(c.num_hidden_layers),
+                "n_embd": int(c.hidden_size),
+                "n_head": int(c.num_attention_heads),
+                "vocab_size": int(c.vocab),
+                "experts_held": int(c.held),
+                "num_experts": int(c.num_experts),
+                "kda_layers": int(c.kda_layers),
+                "mla_layers": int(c.mla_layers)}
+
+
 def serving_model(model_cfg):
     """The seam object for a model config (see the module docstring)."""
     if isinstance(model_cfg, GPT2Config):
@@ -247,4 +336,4 @@ def serving_model(model_cfg):
 
 
 __all__ = ["GPT2Serving", "DeepseekV3Serving", "OuroServing",
-           "serving_model"]
+           "LingHybridServing", "serving_model"]

@@ -307,7 +307,8 @@ def test_attended_chunk_share_reads_the_steps_trip_counts(whole, cut,
     assert (spec["layer"], spec["unit"], spec["moves"], entry["better"],
             entry["source"], entry["workloads"]) == (
         entry["layer"], entry["unit"], entry["moves"], "lower",
-        "program_counter", ["gpt2-xl.chat-short", "ouro-2.6b.chat-turns"])
+        "program_counter", ["gpt2-xl.chat-short", "ouro-2.6b.chat-turns",
+                            "ling-3.0-flash-vl-ep4.passage-chat"])
 
 
 def test_the_parts_sum_to_the_run_and_the_trace_is_read_once(whole,
@@ -485,8 +486,8 @@ def test_every_new_metric_has_its_file_its_entry_and_its_reader():
     files = sorted(f[:-5] for f in os.listdir(
         os.path.join(BENCH, "layer_metrics")) if f.endswith(".json"))
     # PR 24's 8, PR 28's 17, PR 30's 9 for the deepseek_v3 cell, PR 33's 1,
-    # PR 35's 5 for the ouro cell
-    assert files == sorted(entries) and len(files) == 40
+    # PR 35's 5 for the ouro cell, PR 37's 8 for the ling_hybrid cell
+    assert files == sorted(entries) and len(files) == 48
     assert list(entries)[8:25] == [
         "sched_self_ms_per_tick", "decode_launch_lag_ms_per_step",
         "decode_device_lag_ms_per_step", "decode_fetch_lag_ms_per_step",
@@ -508,7 +509,8 @@ def test_every_new_metric_has_its_file_its_entry_and_its_reader():
         # a later cell is appended where a reader needs no GPT-2 count
         assert entry["workloads"] == ["gpt2-xl.chat-short",
                                       "gigachat3.1-702b-ep16.think-long",
-                                      "ouro-2.6b.chat-turns"]
+                                      "ouro-2.6b.chat-turns",
+                                      "ling-3.0-flash-vl-ep4.passage-chat"]
         assert set(entry) == {"name", "unit", "better", "source", "layer",
                               "moves", "workloads"}
         sources[entry["source"], spec["reader"]] += 1
