@@ -449,6 +449,7 @@ def _serve_bench(steps: int, num_slots: int = 4,
 
     from apex_tpu.models.gpt2 import GPT2Config
     from apex_tpu.serve.engine import Engine, EngineConfig, init_gpt2_params
+    from apex_tpu.serve.model import serving_model
     from apex_tpu.serve.scheduler import Request, ServeScheduler
 
     # resolve the baseline writer BEFORE benching: a wheel-only install
@@ -681,10 +682,11 @@ def _serve_bench(steps: int, num_slots: int = 4,
         raise SystemExit(
             f"apex-tpu-bench: --tp {tp} must divide the bench model's "
             f"n_head={cfg.n_head} (the serving mesh shards whole heads)")
-    params = init_gpt2_params(cfg)
+    # one param pytree shared by every replica (read-only), held as the
+    # engines hold it: the fleet bit-exactness story needs identical
+    # weights everywhere, and one copy of them
+    params = serving_model(cfg).serving_weights(init_gpt2_params(cfg))
     try:
-        # one param pytree shared by every replica (read-only): the
-        # fleet bit-exactness story needs identical weights everywhere
         engines = [Engine(cfg, params,
                           EngineConfig(num_slots=num_slots,
                                        max_len=max_len,

@@ -137,7 +137,10 @@ def lm_loss(model: GPT2, params, tokens):
 # [num_slots * bucket] rows through the same dense code, every weight read
 # once a call). Learned positional embeddings are indexed by each row's
 # absolute position. It is a pure function over the SAME param pytree
-# GPT2.init/flax produce (no separate serving weights), with every array
+# GPT2.init/flax produce, as it is or with its matrices already in
+# compute_dtype (the serve engine holds them so: serve/model.py,
+# serving_weights; a matrix is only ever read as .astype(compute_dtype),
+# which lowers to nothing on a cast one), with every array
 # shape fixed by the engine's geometry — the serve engine's
 # one-compile-per-program invariant rests on that. Only the cache append
 # and the attention differ between the two forms (_append_and_attend):

@@ -169,6 +169,7 @@ def _run_fleet(args, cfg, max_len: int, prompts, slo) -> int:
                                        init_gpt2_params)
     from apex_tpu.serve.fleet import (EngineReplica, FleetController,
                                       FleetTraceHarness)
+    from apex_tpu.serve.model import serving_model
     from apex_tpu.serve.scheduler import Request
     from apex_tpu.utils.env import device_block
 
@@ -216,7 +217,10 @@ def _run_fleet(args, cfg, max_len: int, prompts, slo) -> int:
             else args.trace_sample,
             sample_seed=args.seed)
 
-    params = init_gpt2_params(cfg, seed=args.seed)
+    # held as every replica's engine holds it, so the replicas (and a
+    # cold spawn) share one copy
+    params = serving_model(cfg).serving_weights(
+        init_gpt2_params(cfg, seed=args.seed))
     # fleet of meshes: with --tp >= 2 EVERY replica shards its own
     # engine over its own NamedSharding mesh (one compile per mesh
     # shape; per-rank metrics fold through the same snapshot merge)

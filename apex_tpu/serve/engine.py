@@ -249,9 +249,10 @@ class Engine:
     ``model_cfg`` names the model through the seam of
     :mod:`apex_tpu.serve.model`: a ``GPT2Config`` (``params`` is then the
     standard flax param pytree of ``models.gpt2.GPT2``, ``model.init(...)``
-    or a training checkpoint; serving casts to the model config's
-    ``compute_dtype`` on the fly; use fp32 configs for bit-exactness
-    claims), or any config that provides ``serving_model()``, such as
+    or a training checkpoint; the engine holds its matrices cast once to
+    the model config's ``compute_dtype``, the seam's ``serving_weights``;
+    use fp32 configs for bit-exactness claims), or any config that
+    provides ``serving_model()``, such as
     ``models.deepseek_v3.DeepseekV3Config`` (its weights are held in
     ``compute_dtype``). A model refuses, at build, the engine modes it has
     no mechanism for.
@@ -266,7 +267,6 @@ class Engine:
         self.model_cfg = model_cfg
         self.model = serving_model(model_cfg)
         self.config = config
-        self.params = params
         self.max_len = int(config.max_len or self.model.max_positions)
         if self.max_len > self.model.max_positions:
             raise ValueError(
@@ -322,8 +322,9 @@ class Engine:
         if self._tp > 1:
             self.mesh: Optional[Any] = serve_tp.serving_mesh(self._tp)
             self._tp_params, self._tp_param_specs = \
-                serve_tp.build_tp_params(model_cfg, params, self._tp,
-                                         config.tp_sync, self.mesh)
+                serve_tp.build_tp_params(
+                    model_cfg, self.model.serving_weights(params),
+                    self._tp, config.tp_sync, self.mesh)
             # the sharded tree is the ONLY param copy the compiled
             # paths read; keeping the caller's full replicated tree
             # alive too would pin a second whole-model copy for the
@@ -333,11 +334,11 @@ class Engine:
         else:
             self.mesh = None
             self._tp_params = self._tp_param_specs = None
-            # the weights ride every compiled call as an argument: a
-            # restored checkpoint's numpy leaves would be re-uploaded
-            # per step, so place them once (device arrays pass through
-            # untouched — fleet replicas keep sharing one pytree)
-            self.params = jax.tree_util.tree_map(jnp.asarray, params)
+            self.params = params
+        # the bytes of the weights every call reads as its argument, as
+        # they are held: the spans of the engine calls carry them
+        self.weight_bytes = int(sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(self._weights)))
         # resolve the tuned geometry ONCE at engine build (cache lookups
         # at trace time inside scan would re-announce per position);
         # block_k is validated against page_size here — a tuned
@@ -449,6 +450,22 @@ class Engine:
             kth = jax.lax.top_k(scaled, k)[0][..., -1:]
             scaled = jnp.where(scaled < kth, jnp.float32(-1e30), scaled)
         return jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
+
+    @property
+    def params(self):
+        """The weights a single-chip engine's programs take, as they
+        take them (``None`` under tensor parallelism: the sharded tree is
+        then the only copy). Assigning a tree casts it with the seam's
+        ``serving_weights`` and places it on the device, once: a restored
+        checkpoint's numpy leaves would otherwise be re-uploaded every
+        call (device arrays in ``compute_dtype`` pass through untouched,
+        so fleet replicas given one such tree keep sharing it)."""
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        self._params = None if params is None else jax.tree_util.tree_map(
+            jnp.asarray, self.model.serving_weights(params))
 
     @property
     def _weights(self):
@@ -965,7 +982,8 @@ class Engine:
     # ------------------------------------------------------------- calls
     def _occupancy(self, act_np: np.ndarray,
                    program: str = "decode") -> Dict[str, int]:
-        """What a decode step's span carries: slots fed and held, tokens
+        """What a decode step's span carries: slots fed and held, the
+        bytes of the weights the step reads (``weight_bytes``), tokens
         resident before the step, pool pages out of the free
         list, and what the compiled ``program`` says of the pool
         (``pool_aliased_bytes``, ``pool_copies``: there once
@@ -977,6 +995,7 @@ class Engine:
         key_chunks = self.max_len // self.block_k
         return {"active": int(act_np.sum()),
                 "slots": self.config.num_slots,
+                "weight_bytes": self.weight_bytes,
                 "resident": self.resident_tokens,
                 **self._pool_facts.get(program, {}),
                 "pages_in_use": self.pool.capacity - self.pool.free_count,
@@ -1044,7 +1063,8 @@ class Engine:
                     f"prompt of {len(toks)} tokens exceeds max_len="
                     f"{self.max_len}")
 
-        with annotate("apex.prefill", admitted=len(prompts), slots=b):
+        with annotate("apex.prefill", admitted=len(prompts), slots=b,
+                      weight_bytes=self.weight_bytes):
             with annotate("apex.prefill.plan"):
                 starts, tails, new_pages = self._plan_prefill(prompts,
                                                               budgets)

@@ -6,6 +6,10 @@ model gives it, through one object:
 
 - ``name``, ``cfg``, ``max_positions``, ``n_layer``, ``vocab_size``,
   ``compute_dtype``, and ``workload()``: what a cost ledger says of it;
+- ``serving_weights(params)``: the tree the engine holds and hands its
+  programs, in the form the forward consumes it, made once at build
+  (GPT-2's matrices cast to ``compute_dtype``; a model whose weights are
+  made in ``compute_dtype`` returns its tree itself);
 - ``cache_planes``: the planes of its cache, the leading axis of every
   pool array and of a migrated page's payload. ``n_layer`` counts layers
   of WEIGHTS; the two are equal unless the model runs its layers more
@@ -50,13 +54,21 @@ GPT-2's (``serve/tp.py``), and a model without one refuses ``tp > 1``.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
+import jax
 import numpy as np
 
 from apex_tpu.models.gpt2 import GPT2Config, gpt2_token_forward
 from apex_tpu.serve import kv_cache
 from apex_tpu.serve.attention import attended_chunks, resolve_block_k
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    """Every leaf to ``dtype``, in one jitted call."""
+    return [x.astype(dtype) for x in leaves]
 
 
 class GPT2Serving:
@@ -74,6 +86,27 @@ class GPT2Serving:
 
     def refuse(self, config) -> None:
         return None
+
+    def serving_weights(self, params):
+        """``params`` with every matrix (the 2-D leaves: ``wte``,
+        ``wpe``, each block's ``attn_qkv`` / ``attn_out`` kernels,
+        ``mlp_fc_w``, ``mlp_proj_w``) in ``compute_dtype``: the forward
+        only ever reads a matrix as ``.astype(compute_dtype)``, so the
+        products take the same bits, and a call streams the weights at
+        the width it computes in. Biases and layer-norm leaves keep their
+        dtype (the tensor-parallel MLP adds its biases in float32). A
+        leaf already in ``compute_dtype`` is kept, not copied; where none
+        needs the cast (a float32 engine) ``params`` itself is
+        returned."""
+        dt = np.dtype(self.compute_dtype)
+        leaves, tree = jax.tree_util.tree_flatten(params)
+        todo = [i for i, x in enumerate(leaves)
+                if np.ndim(x) == 2 and x.dtype != dt]
+        if not todo:
+            return params
+        for i, x in zip(todo, _cast([leaves[i] for i in todo], dt)):
+            leaves[i] = x
+        return jax.tree_util.tree_unflatten(tree, leaves)
 
     def block_k(self, max_len: int, page_size: int, config, tp: int) -> int:
         return resolve_block_k(max_len, self.heads // tp, self.head_dim,
@@ -138,6 +171,11 @@ class DeepseekV3Serving:
         if missing:
             raise ValueError(
                 "deepseek_v3 is not served with " + "; ".join(missing))
+
+    def serving_weights(self, params):
+        """``params`` itself: the weights are made in
+        ``compute_dtype``."""
+        return params
 
     def block_k(self, max_len: int, page_size: int, config, tp: int) -> int:
         # latent attention reads whole pages; the knob is accepted where
@@ -221,6 +259,8 @@ class OuroServing(GPT2Serving):
                            f"read what the one before wrote")
         if missing:
             raise ValueError("ouro is not served with " + "; ".join(missing))
+
+    serving_weights = DeepseekV3Serving.serving_weights
 
     def forward(self, weights, cache, *data, **kw):
         from apex_tpu.models.ouro import ouro_token_forward

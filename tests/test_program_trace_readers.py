@@ -427,9 +427,10 @@ def test_the_programs_own_spans_reach_the_reader_and_no_chip_reads_nothing(
         == named["apex.prefill.index"] == named["apex.sched.admit"] == calls
     for s in tr["spans"]:
         if s[0] == "apex.decode_step":
-            assert set(s[3]) == {"active", "slots", "resident",
-                                 "pages_in_use", "pages",
+            assert set(s[3]) == {"active", "slots", "weight_bytes",
+                                 "resident", "pages_in_use", "pages",
                                  "attended_chunks", "key_chunks"}
+            assert s[3]["weight_bytes"] == engine.weight_bytes
             assert 0 < s[3]["active"] <= s[3]["slots"] == 2
             assert 0 < s[3]["attended_chunks"] <= s[3]["key_chunks"]
         elif s[0] == "apex.prefill.launch":

@@ -294,8 +294,10 @@ def test_the_launch_span_says_the_rows_computed_and_the_call_the_admitted(
     eng.prefill(five)
     calls = [a for n, a in seen if n == "apex.prefill"]
     launches = [a for n, a in seen if n == "apex.prefill.launch"]
-    assert calls == [{"admitted": 2, "slots": SLOTS},
-                     {"admitted": 5, "slots": SLOTS}]
+    assert calls == [{"admitted": 2, "slots": SLOTS,
+                      "weight_bytes": eng.weight_bytes},
+                     {"admitted": 5, "slots": SLOTS,
+                      "weight_bytes": eng.weight_bytes}]
     for launch, rows, prompts in zip(launches, (ROWS, SLOTS), (two, five)):
         assert launch["slots"] == rows and launch["bucket"] == BUCKET
         assert launch["real_positions"] == sum(map(len, prompts.values()))
